@@ -5,7 +5,7 @@ BigDL's operability came from catching config mistakes at submit time,
 before a cluster burned hours (PAPER §BigDL). The TPU analogue: trace a
 model's full train step with ``jax.make_jaxpr`` under **abstract**
 inputs (no compilation, no device, seconds on CPU), walk every nested
-pjit/custom_vjp/pallas_call sub-jaxpr, and evaluate a rule registry over
+jit/custom_vjp/pallas_call sub-jaxpr, and evaluate a rule registry over
 the jaxpr plus the kernel/block/layout metadata PRs 1–3 already record.
 The same pass is the CI gate that keeps those PRs' wins from regressing.
 
@@ -63,7 +63,7 @@ __all__ = ["Finding", "Report", "SEVERITIES", "CATALOG", "SHARD_CATALOG",
 def lint_fn(fn, *args, report: Optional[Report] = None, **kwargs) -> Report:
     """Trace ``fn(*args, **kwargs)`` abstractly (args may be arrays or
     ``jax.ShapeDtypeStruct``) and run every jaxpr rule. Pass an already-
-    jitted ``fn`` to get donation analysis of its pjit boundary."""
+    jitted ``fn`` to get donation analysis of its jit boundary."""
     import jax
 
     closed = jax.make_jaxpr(fn)(*args, **kwargs)
@@ -147,7 +147,7 @@ def trace_sharded_train_step(model, in_shape, batch, *, mesh_axes,
                                                     replicated_specs)
 
     axes = {str(k): int(v) for k, v in dict(mesh_axes).items()}
-    mesh = AbstractMesh(tuple(axes.items()))
+    mesh = AbstractMesh(tuple(axes.values()), tuple(axes))
     dtype = jnp.bfloat16 if dtype is None else dtype
     crit = (nn.TimeDistributedCriterion(nn.ClassNLLCriterion()) if is_lm
             else nn.ClassNLLCriterion())

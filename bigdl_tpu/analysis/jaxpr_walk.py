@@ -1,11 +1,11 @@
 """Jaxpr traversal for the tpulint pass — provenance-preserving iteration
-over a ClosedJaxpr including every nested sub-jaxpr (``pjit`` bodies,
+over a ClosedJaxpr including every nested sub-jaxpr (``jit`` bodies,
 ``custom_vjp``/``custom_jvp`` rules, scan/while/cond branches, and
 ``pallas_call`` kernel bodies).
 
 Unlike ``utils/flops.py`` (which only needs a FLOP sum), rules need to
 know *where* an equation lives — so each visited jaxpr level carries a
-path string like ``pjit:train_step/custom_vjp_call_jaxpr/pallas_call:
+path string like ``jit:train_step/custom_vjp_call_jaxpr/pallas_call:
 _fba_fwd_kernel`` — and *who consumes* each value, so the dtype rules can
 tell a stats-reduction upcast from an fp32-softmax one. Everything here
 is read-only over trace-time metadata: no compilation, no execution, no
@@ -46,7 +46,7 @@ def aval_bytes(aval) -> int:
 
 def eqn_label(eqn) -> str:
     """Short label for one equation: primitive plus its best name hint
-    (pjit ``name``, pallas kernel name) when one exists."""
+    (jit ``name``, pallas kernel name) when one exists."""
     name = eqn.params.get("name") if eqn.params else None
     if name is None and eqn.primitive.name == "pallas_call":
         name = pallas_kernel_name(eqn)
@@ -55,15 +55,11 @@ def eqn_label(eqn) -> str:
 
 
 def pallas_kernel_name(eqn) -> Optional[str]:
-    """Kernel function name of a ``pallas_call`` eqn (from
-    ``name_and_src_info``), or None."""
-    nsi = eqn.params.get("name_and_src_info")
-    name = getattr(nsi, "name", None)
-    if name:
-        return str(name)
-    if nsi is not None:  # str form is "name at file:line"
-        return str(nsi).split(" ")[0] or None
-    return None
+    """Kernel FUNCTION name of a ``pallas_call`` eqn (the kernel jaxpr's
+    debug info — ``functools.partial`` wrappers resolve to the wrapped
+    function), or None."""
+    info = getattr(eqn.params.get("jaxpr"), "debug_info", None)
+    return getattr(info, "func_name", None) or None
 
 
 def _sub_jaxprs(eqn) -> Iterator[Tuple[object, str]]:
@@ -126,7 +122,7 @@ def consumers_map(jaxpr) -> Dict[object, List[object]]:
 # The shardlint walk (ISSUE 19). jit-SPMD traces carry no collective
 # eqns — the partitioner inserts them after tracing — so everything a
 # static pass can know about the multichip plan lives in ANNOTATIONS:
-# ``pjit`` eqn params (``in_shardings``/``out_shardings`` zip with the
+# ``jit`` eqn params (``in_shardings``/``out_shardings`` zip with the
 # body's invars/outvars), ``sharding_constraint`` eqns (the
 # ``with_sharding_constraint`` steering points, e.g. grad_comm's
 # compressed buckets), and — in shard_map/pmap graphs only — explicit
@@ -136,8 +132,8 @@ def consumers_map(jaxpr) -> Dict[object, List[object]]:
 
 # explicit collective primitives (shard_map/pmap graphs only; jit-SPMD
 # traces never contain these — mirrored by rules._COLLECTIVE_PRIMS).
-# psum2 is what shard_map's check_rep rewrite lowers psum to.
-COLLECTIVE_PRIMS = ("psum", "psum2", "ppermute", "all_gather",
+# psum_invariant is what psum binds to under shard_map's check_vma.
+COLLECTIVE_PRIMS = ("psum", "psum_invariant", "ppermute", "all_gather",
                     "all_to_all", "reduce_scatter", "psum_scatter",
                     "pmax", "pmin")
 
@@ -151,7 +147,7 @@ _SHARDING_TRANSPARENT = ("convert_element_type", "copy", "device_put",
 
 def named_sharding(s) -> Optional[object]:
     """``s`` if it is a usable NamedSharding-like annotation (has a spec
-    and a mesh), else None — filters pjit's UnspecifiedValue entries."""
+    and a mesh), else None — filters jit's UnspecifiedValue entries."""
     if s is None:
         return None
     if getattr(s, "spec", None) is None or getattr(s, "mesh", None) is None:
@@ -176,7 +172,7 @@ def spec_axes(spec) -> List[str]:
 class ShardedLevel:
     """One jaxpr level plus its sharding environment: ``shardings`` maps
     this level's vars to the NamedSharding annotations that reach them
-    (pjit boundary zips, constraint eqns, transparent-op propagation)."""
+    (jit boundary zips, constraint eqns, transparent-op propagation)."""
     jaxpr: object
     path: str
     depth: int
@@ -210,7 +206,7 @@ def _walk_sharded(jaxpr, path: str, depth: int,
         if name == "sharding_constraint":
             _bind(env, eqn.outvars[0],
                   named_sharding(eqn.params.get("sharding")))
-        elif name == "pjit" and depth < max_depth:
+        elif name == "jit" and depth < max_depth:
             closed = eqn.params.get("jaxpr")
             sub = closed.jaxpr if isinstance(
                 closed, jex_core.ClosedJaxpr) else closed
@@ -219,7 +215,7 @@ def _walk_sharded(jaxpr, path: str, depth: int,
             for v, s in zip(sub.invars, in_sh):
                 _bind(sub_env, v, named_sharding(s))
             # caller knowledge flows in where the boundary left the
-            # sharding unspecified (nested pjit under an annotated one)
+            # sharding unspecified (nested jit under an annotated one)
             for v_sub, v_call in zip(sub.invars, eqn.invars):
                 if v_sub not in sub_env:
                     _bind(sub_env, v_sub, _lookup(env, v_call))
@@ -261,7 +257,7 @@ def sharded_levels(jaxpr, max_depth: int = 24) -> List[ShardedLevel]:
 
 def observed_mesh_axes(levels: List[ShardedLevel]) -> Dict[str, int]:
     """Merged axis -> size of every mesh named by any annotation in the
-    walk (constraint shardings, pjit boundary shardings)."""
+    walk (constraint shardings, jit boundary shardings)."""
     axes: Dict[str, int] = {}
     for lv in levels:
         for s in lv.shardings.values():
@@ -320,20 +316,18 @@ def collect_collectives(levels: List[ShardedLevel]) -> List[tuple]:
 def pallas_block_views(eqn) -> List[Tuple[Tuple, Tuple, object, bool]]:
     """(block_shape, array_shape, dtype, is_output) for every block
     mapping of a ``pallas_call`` eqn — the raw material of the tiling,
-    padding and VMEM rules. Best-effort across jax versions: mappings
-    without the expected fields are skipped rather than crashed on."""
-    gm = eqn.params.get("grid_mapping")
-    bms = getattr(gm, "block_mappings", None) or ()
-    n_in = getattr(gm, "num_inputs", None)
+    padding and VMEM rules (jax 0.9.0 field names: ``array_aval``, and
+    block dims wrapped as ``Blocked(block_size=n)``; squeezed dims,
+    which carry no ``block_size``, count as 1)."""
+    gm = eqn.params["grid_mapping"]
     views = []
-    for idx, bm in enumerate(bms):
-        bs = getattr(bm, "block_shape", None)
-        sds = getattr(bm, "array_shape_dtype", None)
-        if bs is None or sds is None:
-            continue
-        is_out = n_in is not None and idx >= n_in
-        views.append((tuple(bs), tuple(sds.shape),
-                      np.dtype(sds.dtype), is_out))
+    for idx, bm in enumerate(gm.block_mappings):
+        bs = tuple(d if isinstance(d, (int, np.integer))
+                   else int(getattr(d, "block_size", 1))
+                   for d in bm.block_shape)
+        aval = bm.array_aval
+        views.append((bs, tuple(aval.shape), np.dtype(aval.dtype),
+                      idx >= gm.num_inputs))
     return views
 
 

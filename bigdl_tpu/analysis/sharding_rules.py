@@ -4,7 +4,7 @@ multichip graphs (ISSUE 19 tentpole).
 The single-device tpulint rules (``rules.py``) read equations; these
 rules read the SPMD *plan*: the ``NamedSharding``/``PartitionSpec``
 annotations that :mod:`bigdl_tpu.analysis.jaxpr_walk.sharded_levels`
-threads through nested pjit levels, plus the abstract param/KV spec
+threads through nested jit levels, plus the abstract param/KV spec
 trees the strategies expose. jit-SPMD traces carry no collective eqns
 (the partitioner inserts them after tracing), so what a static pass can
 check is exactly what the annotations promise — and that is enough for

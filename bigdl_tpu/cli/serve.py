@@ -522,9 +522,30 @@ def build_app(args):
         engine, batcher, decoder, watchdog = _build_stack(
             mesh0, metrics, first=True)
 
+    import jax
     prov = engine.provenance()
     if lint_prov:
         prov.update(lint_prov)
+
+    def device_ids(params):
+        """Ids of the devices a weight tree really lives on (read off
+        the committed arrays, not the placement plan)."""
+        return ",".join(str(i) for i in sorted(
+            {d.id for leaf in jax.tree_util.tree_leaves(params)
+             if isinstance(leaf, jax.Array) for d in leaf.devices()}))
+
+    # every scrape names the device it measured and where each engine's
+    # weights are
+    engines = ([r.engine for r in replica_set.replicas]
+               if replica_set is not None else [engine])
+    prov.update({
+        "backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
+        "device_count": len(jax.devices()),
+        "jax": jax.__version__,
+        "param_devices": "|".join(f"r{i}:{device_ids(e.params)}"
+                                  for i, e in enumerate(engines)),
+    })
     prov.update({
         "model": name,
         "max_batch": args.maxBatch,
@@ -557,6 +578,11 @@ def build_app(args):
         prov["decode_slots"] = args.slots
         prov["prompt_buckets"] = ",".join(
             str(b) for b in decoder.prompt_buckets)
+        # which prefill buckets attend through a compiled Pallas kernel
+        # (none off-TPU, where the kernels would run interpreted)
+        prov["prefill_kernels"] = ";".join(
+            f"{k}@{','.join(map(str, bs))}" for k, bs in
+            sorted(decoder.prefill_mosaic_kernels().items())) or "none"
         prov["speculate"] = args.speculate
         prov["draft_dims"] = args.draftDims or (
             "self" if args.speculate > 0 else "none")

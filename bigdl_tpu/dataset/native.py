@@ -105,8 +105,20 @@ def _load_impl() -> Optional[ctypes.CDLL]:
                                capture_output=True, timeout=120)
             finally:
                 fcntl.flock(lk, fcntl.LOCK_UN)
-    except Exception:
-        if not os.path.exists(_LIB_PATH):
+    except (OSError, subprocess.SubprocessError) as e:
+        # report, never hide: a checkout ships no .so (git-ignored), so a
+        # failed build means the pure-python paths; a failed REbuild next
+        # to an older .so means that .so may predate the bindings
+        import logging
+        stale = os.path.exists(_LIB_PATH)
+        make_err = getattr(e, "stderr", None)  # CalledProcessError only
+        logging.getLogger(__name__).warning(
+            "native build failed in %s (%s); %s", _NATIVE_DIR,
+            make_err.decode(errors="replace").strip()[-300:]
+            if make_err else repr(e),
+            "loading the existing (possibly stale) .so" if stale
+            else "using the pure-python data paths")
+        if not stale:
             return None
     try:
         lib = ctypes.CDLL(_LIB_PATH)

@@ -58,9 +58,9 @@ __all__ = [
 
 # Per-chip HBM capacity (public figures), matched like perf._PEAK_FLOPS:
 # substring against the squashed device_kind, most specific first, match
-# label reported alongside the number so a fallback can never hide. The
-# CPU nominal keeps headroom DEFINED in CPU test runs (same contract as
-# the 1e12-FLOPs CPU nominal in the MFU table).
+# label reported alongside the number. An accelerator missing from the
+# table is an error; the CPU nominal keeps the static plan's headroom
+# DEFINED on the CPU test platform (a byte count, not a device metric).
 HBM_BYTES = (
     ("v6lite", 32e9), ("v6e", 32e9), ("trillium", 32e9),
     ("v5lite", 16e9), ("v5e", 16e9),
@@ -79,19 +79,20 @@ _MA_FIELDS = ("argument_size_in_bytes", "output_size_in_bytes",
 
 
 def device_hbm_bytes(device=None) -> Tuple[float, str]:
-    """Return ``(hbm_bytes, matched_label)`` for one chip."""
+    """Return ``(hbm_bytes, matched_label)`` for one chip (default: the
+    first visible device). A kind missing from :data:`HBM_BYTES` raises."""
     if device is None:
-        try:
-            import jax
-            device = jax.devices()[0]
-        except Exception:
-            return 8e9, "cpu"
-    kind = getattr(device, "device_kind", "cpu") or "cpu"
+        import jax
+        device = jax.devices()[0]
+    kind = device.device_kind
     squashed = kind.replace(" ", "").replace("-", "").lower()
     for k, v in HBM_BYTES:
         if k in squashed:
             return v, k
-    return 8e9, f"UNMATCHED({kind})->8e9-nominal"
+    raise ValueError(
+        f"device_kind {kind!r} is not in the HBM capacity table "
+        f"(obs/memory.py HBM_BYTES) — add its published capacity rather "
+        f"than plan headroom against a made-up one")
 
 
 def tree_bytes(tree) -> int:

@@ -673,6 +673,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
             pltpu_scratch((bq, d)),
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(*args)
     o = out[:, :s_q] if pad_q else out
     return o.reshape(b, h, s_q, d), lse[..., 0]
@@ -742,6 +743,7 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int,
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
         scratch_shapes=[pltpu_scratch((bq, d))],
         interpret=interpret,
+        name="flash_dq",
     )(*dq_args)
 
     dkv_specs = [
@@ -779,6 +781,7 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int,
         ],
         scratch_shapes=[pltpu_scratch((bk, d)), pltpu_scratch((bk, d))],
         interpret=interpret,
+        name="flash_dkv",
     )(*dkv_args)
 
     dq = (dq[:, :s_q] if pad_q else dq).reshape(b, h, s_q, d)
@@ -859,6 +862,9 @@ def flash_attention(q, k, v, *, causal: bool = False,
     before. Either way both dims clamp to a standard tiling that divides
     the sequence (no padded q blocks for mid sequences like 768).
     """
+    # lazy: parallel/ imports this module (ring attention's softmax step)
+    from bigdl_tpu.parallel.hints import shard_over_batch
+
     s_q, s_k = q.shape[-2], k.shape[-2]
     block_q, block_k = _resolve_blocks(s_q, s_k, q.shape[-1], causal,
                                        q.dtype, block_q, block_k)
@@ -877,7 +883,10 @@ def flash_attention(q, k, v, *, causal: bool = False,
             return _dense.dot_product_attention(
                 q, k, v, causal=causal,
                 mask=_dense.make_segment_mask(segments))
-        out = _flash_seg(q, k, v, segments, causal, block_q, block_k)
+        out = shard_over_batch(
+            lambda q, k, v, segments: _flash_seg(
+                q, k, v, segments, causal, block_q, block_k))(
+                    q, k, v, segments)
         # in-kernel, id-0 padding rows attend id-0 keys (keeps softmax
         # rows live for a finite backward); the dense fallback above
         # fully masks them to 0 instead. Zero them here so the same call
@@ -894,4 +903,5 @@ def flash_attention(q, k, v, *, causal: bool = False,
     # 128-tileable length like 768 to the dense fallback, and q no
     # longer pads 768→1024); genuinely ragged lengths still fall back
     # inside the custom_vjp
-    return _flash(q, k, v, causal, block_q, block_k)
+    return shard_over_batch(
+        lambda q, k, v: _flash(q, k, v, causal, block_q, block_k))(q, k, v)

@@ -8,7 +8,7 @@ from bigdl_tpu.parallel.data_parallel import (
 from bigdl_tpu.parallel.grad_comm import (
     COMPRESS_MODES, DEFAULT_BUCKET_BYTES, GradCommConfig, BucketPlan,
     make_config as make_grad_comm_config, build_bucket_plan,
-    apply_grad_comm, compressed_psum, shard_map_available,
+    apply_grad_comm, compressed_psum,
 )
 from bigdl_tpu.parallel.tensor_parallel import (
     TensorParallel, megatron_specs, replicated_specs,

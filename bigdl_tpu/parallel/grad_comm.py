@@ -36,16 +36,13 @@ strategies compose through :meth:`DataParallel.reduce_grads`:
     value. Buckets carry no data dependencies on each other, so XLA's
     latency-hiding scheduler is free to overlap each bucket's reduce
     with backward compute that hasn't produced later buckets yet.
-    Whether a given XLA build honors the dtype steering is exactly what
-    ``scripts/tpu_capture_r14.sh`` measures (PERF.md §17 result slots:
-    ``collective_s`` compressed vs plain, same attribution columns).
-  - an explicit shard_map path (:func:`compressed_psum`) where a
-    shard_map API is importable (``jax.shard_map`` on current jax, the
-    ``jax.experimental.shard_map`` spelling on this container's
-    0.4.37): per-bucket ``lax.psum`` over the mesh axis on the
-    compressed value — the manual-collective building block for
-    strategies that hold per-device partial grads (and the autotuner's
-    measurement harness).
+    Whether a given XLA build honors the dtype steering has not been
+    measured on the chip (ROADMAP S7: ``collective_s`` compressed vs
+    plain, same attribution columns).
+  - an explicit ``jax.shard_map`` path (:func:`compressed_psum`):
+    per-bucket ``lax.psum`` over the mesh axis on the compressed value —
+    the manual-collective building block for strategies that hold
+    per-device partial grads (and the autotuner's measurement harness).
 
 Bucket size is autotuned per (param-bytes, n_devices, wire-dtype) under
 the ``grad_comm`` namespace of the persistent tuning cache
@@ -63,8 +60,7 @@ from typing import Dict, List, Optional, Tuple
 __all__ = ["COMPRESS_MODES", "DEFAULT_BUCKET_BYTES", "GradCommConfig",
            "parse_compress_spec", "make_config", "BucketPlan",
            "build_bucket_plan", "plan_wire_bytes", "apply_grad_comm",
-           "compress_bucket", "decompress_bucket", "shard_map_available",
-           "compressed_psum"]
+           "compress_bucket", "decompress_bucket", "compressed_psum"]
 
 # the flag surface: plain 16-bit truncation or truncation + local
 # error-compensation residual (see compress/decompress below)
@@ -257,42 +253,16 @@ def decompress_bucket(cbuf):
     return cbuf.astype(jnp.float32)
 
 
-def shard_map_available() -> bool:
-    """True when some shard_map spelling is importable — the explicit
-    per-bucket psum path (this container's jax 0.4.37 only ships the
-    experimental spelling; current jax promotes it to ``jax.shard_map``)."""
-    return _get_shard_map() is not None
-
-
-def _get_shard_map():
-    try:
-        import jax
-        if hasattr(jax, "shard_map"):
-            return jax.shard_map
-        from jax.experimental.shard_map import shard_map
-        return shard_map
-    except Exception:
-        return None
-
-
 def compressed_psum(stacked, mesh, axis: str, mode: str):
     """Explicit compressed all-reduce of per-device partial buckets:
     ``stacked`` is (n_devices, bucket_len) with row i holding device
     i's partial f32 bucket; returns the (bucket_len,) f32 sum, reduced
     over the wire in the 16-bit dtype via an explicit per-bucket
-    ``lax.psum`` inside shard_map. The building block for manual
+    ``lax.psum`` inside ``jax.shard_map``. The building block for manual
     strategies holding unreduced grads, and the autotuner's measurement
-    harness; raises RuntimeError where no shard_map API exists (callers
-    gate on :func:`shard_map_available`, the sp/pp refusal pattern)."""
+    harness."""
     import jax
     from jax.sharding import PartitionSpec as P
-
-    shard_map = _get_shard_map()
-    if shard_map is None:
-        raise RuntimeError(
-            "compressed_psum needs a shard_map API; this jax "
-            f"({jax.__version__}) ships neither jax.shard_map nor the "
-            "experimental spelling")
 
     def local_reduce(block):
         # block: (1, L) — this device's partial bucket. Compress BEFORE
@@ -301,8 +271,8 @@ def compressed_psum(stacked, mesh, axis: str, mode: str):
         s = jax.lax.psum(c, axis)
         return decompress_bucket(s)
 
-    return shard_map(local_reduce, mesh=mesh, in_specs=P(axis, None),
-                     out_specs=P(), check_rep=False)(stacked)
+    return jax.shard_map(local_reduce, mesh=mesh, in_specs=P(axis, None),
+                         out_specs=P(), check_vma=False)(stacked)
 
 
 # ------------------------------------------------------- the trace path

@@ -31,7 +31,7 @@ from typing import Optional, Tuple
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-__all__ = ["batch_sharding_hint", "constrain_batch"]
+__all__ = ["batch_sharding_hint", "constrain_batch", "shard_over_batch"]
 
 _BATCH_HINT: ContextVar[Optional[Tuple[Mesh, str]]] = ContextVar(
     "bigdl_tpu_batch_hint", default=None)
@@ -60,3 +60,20 @@ def constrain_batch(x):
         return x
     spec = P(axis, *([None] * (x.ndim - 1)))
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+
+def shard_over_batch(fn):
+    """``fn`` run per data-axis shard when a hint is active: every array
+    argument and result carries the batch on dim 0, and each device gets
+    its own slice through ``jax.shard_map``. For Pallas kernels — a
+    Mosaic call has no GSPMD partitioning rule, and jax refuses to lower
+    one inside a multi-device jit ("Mosaic kernels cannot be
+    automatically partitioned"), so the data-parallel step hands each
+    chip its batch slice explicitly. No hint (single device, or a
+    composed dp x sp / tp layout): ``fn`` unchanged."""
+    hint = _BATCH_HINT.get()
+    if hint is None or hint[0].shape[hint[1]] == 1:
+        return fn
+    mesh, axis = hint
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(axis), out_specs=P(axis),
+                         check_vma=False)

@@ -197,6 +197,10 @@ class DecodeEngine:
         self._work = threading.Condition(self._lock)
         self._reqs: list = [None] * self.slots
         self._waiting: collections.deque = collections.deque()
+        # submits that have entered but hold neither a slot nor a queue
+        # place yet (blocked on the engine lock, or prefilling under it)
+        self._admitting = 0
+        self._admit_lock = threading.Lock()
 
         # ---- KV backend: dense slab or page pools (ISSUE 14) -------------
         self.page_tokens = int(kv_page_tokens) if kv_page_tokens else None
@@ -382,10 +386,14 @@ class DecodeEngine:
 
     def queue_load(self) -> int:
         """Routing signal for dp replica selection: active slots plus
-        waiting requests (approximate read — no lock; routing only needs
-        a consistent ordering, not an exact census)."""
+        waiting requests plus submits still being admitted — a prefill
+        runs under the engine lock inside ``submit``, and for that long
+        (seconds, when the bucket compiles) its request is in neither
+        list; uncounted, every concurrent request piled onto one replica.
+        Approximate read — routing only needs a consistent ordering, not
+        an exact census."""
         return (sum(r is not None for r in self._reqs)
-                + len(self._waiting))
+                + len(self._waiting) + self._admitting)
 
     def _page_occupancy(self) -> float:
         live = int(sum(int(self._pos[i])
@@ -432,6 +440,7 @@ class DecodeEngine:
                                                  last)
             return logits[0].astype(jnp.float32), cache
 
+        self._prefill_fn = _prefill
         self._prefill_jit = jax.jit(  # one compile per bucket
             _prefill, **self._pin(self._repl_sh, self._cache1_sh))
 
@@ -677,6 +686,30 @@ class DecodeEngine:
                      jnp_u32]
         return jax.make_jaxpr(jax_fn)(*args)
 
+    def prefill_mosaic_kernels(self) -> dict:
+        """``{kernel name: [buckets]}`` for the Pallas kernels the traced
+        prefill program carries WITHOUT interpret mode — i.e. the ones
+        that lower through Mosaic. Empty where prefill attends densely
+        or the kernels run interpreted (off-TPU). Trace only: nothing is
+        compiled or placed."""
+        from bigdl_tpu.analysis.jaxpr_walk import iter_levels
+        jax, jnp = self._jax, self._jnp
+        abstract = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self.params)
+        out: dict = {}
+        for b in self.prompt_buckets:
+            closed = jax.make_jaxpr(self._prefill_fn)(
+                abstract, jax.ShapeDtypeStruct((1, b), jnp.int32),
+                jax.ShapeDtypeStruct((), jnp.int32))
+            for lv in iter_levels(closed):
+                for eqn in lv.jaxpr.eqns:
+                    if (eqn.primitive.name == "pallas_call"
+                            and not eqn.params.get("interpret")):
+                        name = eqn.params.get("name") or "pallas_call"
+                        if b not in out.setdefault(name, []):
+                            out[name].append(b)
+        return out
+
     # ------------------------------------------------------------ admission
     def prompt_bucket_for(self, n: int) -> int:
         for b in self.prompt_buckets:
@@ -718,7 +751,21 @@ class DecodeEngine:
         req = DecodeRequest(tokens, max_new_tokens, temperature,
                             stop_token, deadline, top_k, top_p, seed,
                             rid=rid, emit=emit)
+        with self._admit_lock:
+            self._admitting += 1
+        try:
+            self._admit(req, deadline, rid)
+        finally:
+            with self._admit_lock:
+                self._admitting -= 1
+        return req.future
+
+    def _admit(self, req, deadline, rid) -> None:
         with self._lock:
+            if not self.busy():
+                # work arrives at an idle engine: the stall clock starts
+                # NOW, not at the loop's last (arbitrarily old) idle beat
+                self._last_beat = self.clock()
             if self._closed:
                 raise RuntimeError("decode engine is closed")
             if self._worker_error is not None or (
@@ -748,7 +795,6 @@ class DecodeEngine:
                     if rt is not None:
                         rt.note_queued(rid)
             self._work.notify()
-        return req.future
 
     def _free_slot(self) -> Optional[int]:
         for i, r in enumerate(self._reqs):
@@ -808,6 +854,11 @@ class DecodeEngine:
             if self._pfx is not None:
                 self._maybe_insert_prefix(req, slot)
         self._logits = self._logits.at[slot].set(logits_vec)
+        # a completed prefill IS progress: installs run under the engine
+        # lock (on the submitter's thread), so while one compiles its
+        # bucket the loop cannot beat — without this the watchdog read a
+        # second install behind a busy slot as a wedged loop
+        self._last_beat = self.clock()
         self._pos[slot] = s
         self._temp[slot] = req.temperature
         self._topk[slot] = req.top_k
@@ -1373,7 +1424,7 @@ def abstract_decode_engine(model, *, slots: int = 4,
         from jax.sharding import AbstractMesh
 
         from bigdl_tpu.serving.sharding import ServingSharding
-        eng.mesh = AbstractMesh(((model_axis, int(tp)),))
+        eng.mesh = AbstractMesh((int(tp),), (model_axis,))
         eng._shard = ServingSharding(eng.mesh, axis=model_axis)
     else:
         eng.mesh = None

@@ -212,27 +212,24 @@ class InferenceEngine:
                 # the buffer-reuse win only exists on device backends
                 donate = ((2,) if self.donate_inputs
                           and self._jax.default_backend() != "cpu" else ())
-                fn = self._jax.jit(self._fwd, donate_argnums=donate)
-                try:
-                    # AOT-compile so the program's memory footprint is
-                    # known NOW (and served as-is); lazy-jit fallback if
-                    # the AOT path misbehaves on this backend
-                    x_abs = self._jax.ShapeDtypeStruct(
-                        (bucket,) + tuple(feat_shape), dtype)
-                    compiled = fn.lower(self.params, self.mod_state,
-                                        x_abs).compile()
-                    ma = compiled.memory_analysis()
-                    arg = int(getattr(ma, "argument_size_in_bytes", 0))
-                    out_b = int(getattr(ma, "output_size_in_bytes", 0))
-                    tmp = int(getattr(ma, "temp_size_in_bytes", 0))
-                    alias = int(getattr(ma, "alias_size_in_bytes", 0))
-                    self._bucket_mem[bucket] = {
-                        "argument_bytes": arg, "output_bytes": out_b,
-                        "temp_bytes": tmp,
-                        "total_bytes": arg + tmp + max(0, out_b - alias)}
-                    fn = compiled
-                except Exception:
-                    pass  # serve through the lazy jit; memory unknown
+                # AOT-compile so the program's memory footprint is
+                # known NOW and the compiled program is served as-is; a
+                # compiler refusal surfaces here, at startup, not on a
+                # later request through a second lazy compile
+                x_abs = self._jax.ShapeDtypeStruct(
+                    (bucket,) + tuple(feat_shape), dtype)
+                fn = self._jax.jit(
+                    self._fwd, donate_argnums=donate).lower(
+                        self.params, self.mod_state, x_abs).compile()
+                ma = fn.memory_analysis()
+                arg = int(getattr(ma, "argument_size_in_bytes", 0))
+                out_b = int(getattr(ma, "output_size_in_bytes", 0))
+                tmp = int(getattr(ma, "temp_size_in_bytes", 0))
+                alias = int(getattr(ma, "alias_size_in_bytes", 0))
+                self._bucket_mem[bucket] = {
+                    "argument_bytes": arg, "output_bytes": out_b,
+                    "temp_bytes": tmp,
+                    "total_bytes": arg + tmp + max(0, out_b - alias)}
                 self._compiled[key] = fn
                 if self._m_compiles is not None:
                     self._m_compiles.inc()

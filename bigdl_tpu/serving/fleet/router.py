@@ -67,6 +67,30 @@ class NoLiveWorker(RuntimeError):
     """Every worker is dead, unreachable, or draining."""
 
 
+def host_tpu_chips() -> int:
+    """TPU chips this host exposes, counted from its device nodes without
+    loading libtpu (the router must never claim a chip): ``/dev/accel<N>``
+    on older runtimes, one numbered ``/dev/vfio`` group per chip on
+    v5e-class hosts. 0 on a host with no TPU."""
+    import glob
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
+
+
+def chip_pin_env(index: int) -> dict:
+    """Environment that confines one worker process to chip ``index`` of
+    this host. A chip belongs to one process at a time: K workers sharing
+    one inherited environment would each claim every chip, and the second
+    could not start. libtpu reads these when it loads; every other
+    backend ignores them."""
+    return {"TPU_VISIBLE_CHIPS": str(index),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            # each single-chip process is its own one-host "slice"
+            "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{8476 + index}",
+            "TPU_MESH_CONTROLLER_PORT": str(8476 + index)}
+
+
 def worker_base_argv(argv: List[str]) -> List[str]:
     """The serve argv minus everything the router owns (fleet shape,
     bind address, weights source + version — re-attached per spawn so a
@@ -156,7 +180,8 @@ class FleetRouter:
                  proxy_timeout_s: float = 150.0,
                  start_timeout_s: float = 300.0,
                  miss_limit: int = 6, env: Optional[dict] = None,
-                 provenance: Optional[dict] = None):
+                 provenance: Optional[dict] = None,
+                 pin_chips: bool = False):
         if n_workers < 1:
             raise ValueError(f"fleet needs >= 1 worker, got {n_workers}")
         self.name = name
@@ -175,6 +200,7 @@ class FleetRouter:
         self._make_argv = make_argv
         self.base_argv = list(base_argv or [])
         self._env = env
+        self._pin_chips = bool(pin_chips)
         self._handles = [WorkerHandle(i) for i in range(n_workers)]
         self._lock = threading.RLock()
         self._reload_lock = threading.Lock()
@@ -230,6 +256,8 @@ class FleetRouter:
     def _spawn(self, h: WorkerHandle) -> None:
         env = dict(self._env if self._env is not None else os.environ)
         env["BIGDL_TPU_WORKER_RESTARTS"] = str(h.restarts)
+        if self._pin_chips:
+            env.update(chip_pin_env(h.index))
         argv = self.worker_argv(h.index)
         h.port = None
         h.status = None
@@ -719,6 +747,24 @@ def run_fleet(args, argv: List[str]) -> int:
             "checkpoint dir or file) or --randomInit for smoke/bench "
             "runs")
     cfg = common.resolve_serve_config(args)
+    # one process per chip: on a TPU host every worker is pinned to its
+    # own chip through the environment, so K is bounded by the chips
+    platform = (args.platform
+                or os.environ.get("JAX_PLATFORMS", "").split(",")[0])
+    chips = 0 if platform == "cpu" else host_tpu_chips()
+    if chips:
+        if k > chips:
+            raise SystemExit(
+                f"--fleet {k}: this host has {chips} TPU chip(s) and a "
+                "chip belongs to one process, so at most one worker per "
+                f"chip can start — use --fleet <= {chips}, or --strategy "
+                "dp for in-process replicas")
+        if args.strategy:
+            raise SystemExit(
+                f"--fleet {k} --strategy {args.strategy}: each fleet "
+                "worker is pinned to ONE chip, so a multi-chip strategy "
+                "inside a worker cannot be placed — run --strategy "
+                "without --fleet (one process drives every chip)")
     router = FleetRouter(
         name=args.model, n_workers=k,
         base_argv=worker_base_argv(argv),
@@ -734,5 +780,6 @@ def run_fleet(args, argv: List[str]) -> int:
                     "serving_replicas": cfg.serving_replicas,
                     "serving_tp": cfg.serving_tp,
                     "quantize": cfg.quantize or "off",
-                    "speculate": cfg.speculate})
+                    "speculate": cfg.speculate},
+        pin_chips=bool(chips))
     return router.serve(port=args.port)

@@ -1260,10 +1260,12 @@ def spawn_fleet(args, extra, k=2):
 def _make_lm_ckpt(path, seed=42):
     """A version-stamped smoke-LM checkpoint (same dims as _SMOKE_LM)
     for the rolling-swap leg — different seed, visibly different
-    weights."""
+    weights. Built on the CPU: this parent must never claim the chip its
+    worker children need (one process per chip)."""
     if REPO not in sys.path:
         sys.path.insert(0, REPO)
     import jax
+    jax.config.update("jax_platforms", "cpu")
 
     from bigdl_tpu import models
     from bigdl_tpu.utils.file import save_pytree

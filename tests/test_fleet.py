@@ -215,6 +215,31 @@ def test_resolve_serve_config_rejects_negative_fleet():
         common.resolve_serve_config(_serve_ns(fleet=-1))
 
 
+# ------------------------------------------- one worker process per chip
+def test_fleet_pins_each_worker_to_its_own_chip(monkeypatch):
+    """ISSUE 21: measured on the four-chip host — two unpinned processes
+    cannot share it (the first claims every chip; the second dies with
+    "TPU is already in use"), two pinned ones each see one chip."""
+    from bigdl_tpu.cli.serve import build_parser
+    from bigdl_tpu.serving.fleet import router as fr
+
+    e0, e1 = fr.chip_pin_env(0), fr.chip_pin_env(1)
+    assert e0["TPU_VISIBLE_CHIPS"] == "0" and e1["TPU_VISIBLE_CHIPS"] == "1"
+    assert e0["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    assert (e0["TPU_MESH_CONTROLLER_PORT"]
+            != e1["TPU_MESH_CONTROLLER_PORT"])
+
+    monkeypatch.setattr(fr, "host_tpu_chips", lambda: 4)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    argv = ["transformer_lm", "--randomInit", "--fleet", "5"]
+    with pytest.raises(SystemExit, match="4 TPU chip"):
+        fr.run_fleet(build_parser().parse_args(argv), argv)
+    argv = ["transformer_lm", "--randomInit", "--fleet", "2",
+            "--strategy", "tp:2"]
+    with pytest.raises(SystemExit, match="pinned to ONE chip"):
+        fr.run_fleet(build_parser().parse_args(argv), argv)
+
+
 # ------------------------------------------------ worker control plane
 class _FakeBatcher:
     def __init__(self, depth=0):

@@ -67,8 +67,8 @@ def test_device_hbm_matching():
     assert memory.device_hbm_bytes(Dev("TPU v4")) == (32e9, "v4")
     assert memory.device_hbm_bytes(Dev("TPU v5 lite")) == (16e9, "v5lite")
     assert memory.device_hbm_bytes(Dev("cpu")) == (8e9, "cpu")
-    hbm, label = memory.device_hbm_bytes(Dev("QuantumChip 9000"))
-    assert hbm == 8e9 and "UNMATCHED" in label
+    with pytest.raises(ValueError, match="QuantumChip 9000"):
+        memory.device_hbm_bytes(Dev("QuantumChip 9000"))
 
 
 # ------------------------------------------------- plan vs the compiler

@@ -190,6 +190,34 @@ def test_decode_admission_fast_reject(tiny_lm):
     assert reg._metrics["decode_rejected_total"].value == 1
 
 
+def test_decode_stall_clock_and_load_during_install(tiny_lm):
+    """ISSUE 21 (found on four chips): a prefill runs under the engine
+    lock inside submit(). While it does, the request must count towards
+    the dp routing load (or every concurrent request piles onto one
+    replica), and the watchdog's stall clock must start when work
+    arrives and restart when the prefill completes — not read the idle
+    loop's arbitrarily old beat as a wedged worker."""
+    model, params = tiny_lm
+    now = [0.0]
+    de = DecodeEngine(model, params, slots=2, clock=lambda: now[0])
+    seen = {}
+    real_install = de._install
+
+    def slow_install(req, slot):
+        seen["load"] = de.queue_load()
+        seen["age_at_start"] = de.heartbeat_age()
+        now[0] += 20.0          # a bucket compile
+        return real_install(req, slot)
+
+    de._install = slow_install
+    now[0] = 500.0              # idle for a long time, then work arrives
+    assert de.queue_load() == 0
+    de.submit([1, 2, 3], 2)
+    assert seen == {"load": 1, "age_at_start": 0.0}
+    assert de.busy() and de.heartbeat_age() == 0.0
+    assert de.queue_load() == 1  # the slot now; not double-counted
+
+
 def test_serving_prefill_buckets():
     from bigdl_tpu.ops.attention_kernel import serving_prefill_buckets
     b = serving_prefill_buckets(512, 64, True, jnp.float32)

@@ -18,7 +18,7 @@ from bigdl_tpu.analysis import (CATALOG, SHARD_CATALOG,
                                 trace_sharded_train_step)
 from bigdl_tpu.parallel.grad_comm import make_config
 
-AM = AbstractMesh((("data", 2), ("model", 4)))
+AM = AbstractMesh((2, 4), ("data", "model"))
 BIG = jax.ShapeDtypeStruct((1024, 1024), jnp.float32)  # 4 MiB
 
 
@@ -106,7 +106,7 @@ def test_grad_compress_with_bf16_bucket_is_clean():
 def test_explicit_collective_outside_strategy_is_extra():
     # shard_map graphs are the only place explicit collectives appear;
     # conftest pins 8 host devices so a real 2x4 mesh exists
-    from jax.experimental.shard_map import shard_map
+    shard_map = jax.shard_map
     mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4),
                 ("data", "model"))
     g = shard_map(lambda x: jax.lax.psum(x, "model"), mesh=mesh,

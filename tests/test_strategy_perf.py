@@ -63,18 +63,12 @@ def test_perf_strategy_ep_runs():
     assert out["step_gflops_analytic"] > 0  # MoE dots counted
 
 
-def test_perf_strategy_sp_runs_or_guards():
-    """sp rides jax.shard_map (ring attention). On a jax that ships it
-    the leg must run and stamp its seq mesh; on this container's older
-    jax the harness must refuse cleanly, not crash mid-build."""
-    if hasattr(jax, "shard_map"):
-        out = run("transformer_lm", 8, 1, "random", use_bf16=False,
-                  strategy="sp", seq_len=32)
-        assert out["mesh"] == {"data": 2, "seq": 4}
-    else:
-        with pytest.raises(SystemExit, match="shard_map"):
-            run("transformer_lm", 8, 1, "random", use_bf16=False,
-                strategy="sp", seq_len=32)
+def test_perf_strategy_sp_runs():
+    """sp rides jax.shard_map (ring attention): the leg runs and stamps
+    its seq mesh."""
+    out = run("transformer_lm", 8, 1, "random", use_bf16=False,
+              strategy="sp", seq_len=32)
+    assert out["mesh"] == {"data": 2, "seq": 4}
 
 
 def test_perf_strategy_sp_needs_lm():
