@@ -8,8 +8,10 @@ Optimizer, a serving-only metrics registry, an offline-only xplane
 reader. This package is the substrate built once:
 
 * :mod:`spans`   — structured step-phase tracing: ``span("data_wait")``
-  around the real phases of training and serving, thread-safe,
-  ring-buffered, near-zero cost disabled, Chrome-trace/Perfetto export;
+  around the real phases of training and serving: a profiler annotation
+  (``bigdl:data_wait`` in any open ``jax.profiler`` session, on the device
+  trace's clock) and, under ``--obs``, a thread-safe ring with
+  Chrome-trace/Perfetto export;
 * :mod:`metrics` — the shared process-global registry
   (Counter/Gauge/Histogram + Prometheus exposition + provenance
   stamping), promoted from ``serving/metrics.py`` and now fed by
@@ -44,9 +46,9 @@ from bigdl_tpu.obs.metrics import (Counter, DEFAULT_LATENCY_BUCKETS_MS,
                                    PHASE_BUCKETS_MS, TRAIN_PHASES,
                                    get_registry, phase_histograms,
                                    reset_registry, set_registry)
-from bigdl_tpu.obs.spans import (NOOP_SPAN, Tracer, counter, disable,
-                                 enable, enabled, get_tracer, instant,
-                                 set_tracer, span)
+from bigdl_tpu.obs.spans import (Tracer, counter, disable, enable,
+                                 enabled, get_tracer, instant, set_tracer,
+                                 span)
 
 __all__ = [
     "attrib", "ATTRIB_CATEGORIES", "attribute", "attribute_profile",
@@ -59,6 +61,6 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "DEFAULT_LATENCY_BUCKETS_MS", "PHASE_BUCKETS_MS", "TRAIN_PHASES",
     "get_registry", "phase_histograms", "reset_registry", "set_registry",
-    "NOOP_SPAN", "Tracer", "counter", "disable", "enable", "enabled",
-    "get_tracer", "instant", "set_tracer", "span",
+    "Tracer", "counter", "disable", "enable", "enabled", "get_tracer",
+    "instant", "set_tracer", "span",
 ]

@@ -11,13 +11,21 @@ wait, checkpoint) and the serving request path (queue wait, batch
 assembly, compute, decode step), ring-buffered and exportable as a
 Chrome-trace / Perfetto JSON for ``chrome://tracing`` or ``ui.perfetto.dev``.
 
+Every span has two sinks. It is always a ``jax.profiler.TraceAnnotation``
+named ``bigdl:<name>``: whoever has a profiler session open (the
+benchmark's ``--trace 1``, ``--traceSteps``, a SIGUSR2 capture) finds the
+program's spans in the session's own ``.xplane.pb``, on the device trace's
+clock, one line a thread; a span still open when the session starts or
+stops is not in it. And when ``--obs`` has installed a :class:`Tracer` it
+is also recorded into that ring, on the tracer's clock.
+
 Design constraints, in priority order:
 
-1. **Near-zero cost when disabled.** ``span(name)`` with no tracer
-   installed is one global load, one ``None`` check, and returns a
-   shared singleton no-op context manager — no allocation, no clock
-   read. Instrumented hot loops pay nothing until ``--obs`` turns the
-   tracer on (the same contract as ``resilience.faults.hook``).
+1. **Near-zero cost when nothing listens.** With no profiler session and
+   no tracer, ``span(name)`` allocates the annotation object and nothing
+   else: no clock read, nothing appended (entering an annotation outside
+   a session is one flag check in C++, about a microsecond with two
+   keyword arguments).
 2. **Thread-safe.** Spans from HTTP handler threads, the micro-batcher
    worker, and the training loop interleave; each thread keeps its own
    nesting stack (``threading.local``) and completed spans append into
@@ -27,6 +35,8 @@ Design constraints, in priority order:
    the tracer on and still export the most recent window.
 4. **Deterministic under test.** The clock is injectable; tests drive a
    fake clock and assert exact timestamps/durations.
+5. **Importing this module imports no jax** (the fleet router does): the
+   annotation class is resolved on the first ``span()``.
 """
 
 from __future__ import annotations
@@ -169,17 +179,20 @@ class Tracer:
 
 
 class _Span:
-    """Active span context manager (only allocated when a tracer is
-    installed)."""
+    """A span with both sinks (only allocated when a tracer is
+    installed): the profiler annotation and the tracer's ring."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_annotation")
 
-    def __init__(self, tracer: Tracer, name: str, args: Optional[dict]):
+    def __init__(self, tracer: Tracer, name: str, args: Optional[dict],
+                 annotation):
         self._tracer = tracer
         self._name = name
         self._args = args
+        self._annotation = annotation
 
     def __enter__(self) -> "_Span":
+        self._annotation.__enter__()
         self._tracer._stack().append(self._name)
         self._t0 = self._tracer.clock()
         return self
@@ -190,43 +203,39 @@ class _Span:
         st.pop()
         self._tracer.record(self._name, self._t0, t1, depth=len(st),
                             args=self._args)
+        self._annotation.__exit__(*exc)
 
 
-class _NoopSpan:
-    """Shared do-nothing context manager — what ``span()`` returns when
-    tracing is disabled. A singleton: the disabled path allocates
-    nothing."""
-
-    __slots__ = ()
-
-    def __enter__(self) -> "_NoopSpan":
-        return self
-
-    def __exit__(self, *exc) -> None:
-        pass
-
-
-NOOP_SPAN = _NoopSpan()
+TAG = "bigdl:"  # the program's spans in a profiler trace
 
 _TRACER: Optional[Tracer] = None
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, once a span was asked for
+
+
+def _annotation_class():
+    global _ANNOTATION
+    from jax.profiler import TraceAnnotation
+    _ANNOTATION = TraceAnnotation
+    return TraceAnnotation
 
 
 def span(name: str, **args):
     """``with span("data_wait"): ...`` — time a named phase.
 
-    Disabled (no tracer installed): one global load + ``None`` check,
-    returns the shared no-op singleton. Enabled: records a completed
-    span into the tracer's ring on exit, nested under any enclosing
-    spans of the same thread."""
+    Always a ``jax.profiler.TraceAnnotation("bigdl:<name>", **args)``,
+    which records only while a profiler session is open. With a tracer
+    installed the span is also recorded into the tracer's ring on exit,
+    nested under any enclosing spans of the same thread."""
+    annotation = (_ANNOTATION or _annotation_class())(TAG + name, **args)
     t = _TRACER
     if t is None:
-        return NOOP_SPAN
-    return _Span(t, name, args or None)
+        return annotation
+    return _Span(t, name, args or None, annotation)
 
 
 def instant(name: str, **args) -> None:
-    """Module-level instant marker — same disabled-cost contract as
-    :func:`span` (one global load + ``None`` check, then nothing)."""
+    """Module-level instant marker, into the tracer's ring alone: one
+    global load + ``None`` check, then nothing, when none is installed."""
     t = _TRACER
     if t is not None:
         t.instant(name, args or None)

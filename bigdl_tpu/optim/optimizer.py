@@ -528,11 +528,10 @@ class Optimizer:
         eval_fn = self._build_eval() if self._val_methods else None
 
         # --obs: per-step phase histograms flow into the shared registry
-        # (scraped live by the --metricsPort listener); the device-wait
-        # split needs a per-dispatch sync, so it only runs under obs —
-        # obs-off keeps the async dispatch pipeline untouched
-        obs_on = _obs_enabled()
-        if obs_on:
+        # (scraped live by the --metricsPort listener). Tracing adds no
+        # device sync: the device phase is the wait inside the loss fetch
+        # that the loop makes at every log point anyway
+        if _obs_enabled():
             from bigdl_tpu.obs.metrics import get_registry, phase_histograms
             self._obs_hists = phase_histograms(get_registry(), "train")
         capture = self._obs_capture
@@ -580,7 +579,10 @@ class Optimizer:
             # crossing-based (== modulo for n_iters=1): a chunk that jumps
             # the counter past a multiple of log_every still logs
             if driver["iteration"] // self.log_every != prev_it // self.log_every:
-                loss_f = float(loss)
+                t_w = time.perf_counter()
+                with _span("loss_fetch"):
+                    loss_f = float(loss)
+                self._obs_phase("device", time.perf_counter() - t_w)
                 driver["loss"] = loss_f
                 if self.nan_check and not math.isfinite(loss_f):
                     raise FloatingPointError(
@@ -643,127 +645,115 @@ class Optimizer:
             pending = None  # batch fetched but shape-incompatible w/ chunk
             epoch_done = False
             while not epoch_done:
-                # fetch one dispatch group: a single batch (K=1), or up to
-                # K same-shape batches to scan inside one program
-                t_fetch = time.time()
-                buf = []
-                with _span("data_wait"):
-                    while len(buf) < K:
-                        if pending is not None:
-                            b, pending = pending, None
-                        else:
-                            b = next(data_iter, _end)
-                            if b is not _end:
-                                _fault_hook("data")  # one visit per fetch
-                        if b is _end:
+                with _span("train_step", step=driver["iteration"]):
+                    # fetch one dispatch group: a single batch (K=1), or up
+                    # to K same-shape batches to scan inside one program
+                    t_fetch = time.time()
+                    buf = []
+                    with _span("data_wait"):
+                        while len(buf) < K:
+                            if pending is not None:
+                                b, pending = pending, None
+                            else:
+                                b = next(data_iter, _end)
+                                if b is not _end:
+                                    _fault_hook("data")  # one visit per fetch
+                            if b is _end:
+                                epoch_done = True
+                                break
+                            if buf and _shape_sig(b) != _shape_sig(buf[0]):
+                                pending = b  # ragged tail: flush, retry next
+                                break
+                            buf.append(b)
+                    dt_fetch = time.time() - t_fetch
+                    fetch_accum += dt_fetch
+                    self._obs_phase("data_wait", dt_fetch)
+                    if not buf:
+                        break
+                    if chunk_fn is not None and len(buf) == K:
+                        if capture is not None:
+                            capture.on_step(driver["iteration"])
+                        t0 = time.time()
+                        t_h = time.perf_counter()
+                        with _span("h2d", batches=K):
+                            xs = jnp.stack([jnp.asarray(bx) for bx, _ in buf])
+                            ys = jax.tree_util.tree_map(
+                                lambda *ls: jnp.stack(
+                                    [jnp.asarray(l) for l in ls]),
+                                *[by for _, by in buf])
+                        self._obs_phase("h2d", time.perf_counter() - t_h)
+                        # fault site BEFORE the dispatch and BEFORE the rng
+                        # splits: a preemption here loses the whole chunk,
+                        # exactly like a kill between dispatches would
+                        _fault_hook("step")
+                        # same host key sequence as K=1 (counted for resume)
+                        keys = [_next_key() for _ in range(K)]
+                        t_d = time.perf_counter()
+                        try:
+                            with _span("dispatch", steps=K):
+                                params, mod_state, opt_state, loss = chunk_fn(
+                                    params, mod_state, opt_state, xs, ys,
+                                    jnp.stack(keys))
+                        except Exception as e:
+                            # RESOURCE_EXHAUSTED autopsy (ISSUE 12): write
+                            # the MemoryReport to --traceDir + fault log,
+                            # then crash exactly as before
+                            from bigdl_tpu.obs import memory as _obs_mem
+                            _obs_mem.handle_oom(e, "train_dispatch")
+                            raise
+                        self._obs_phase("dispatch", time.perf_counter() - t_d)
+                        after_dispatch(sum(len(bx) for bx, _ in buf), K, t0,
+                                       loss)
+                        self._maybe_validate(eval_fn, params, mod_state,
+                                             driver)
+                        self._maybe_checkpoint(params, mod_state, opt_state,
+                                               driver)
+                        if self.end_when(driver):
+                            break
+                        continue
+                    for x, y in buf:  # K == 1, or a ragged/short group
+                        if capture is not None:
+                            capture.on_step(driver["iteration"])
+                        t0 = time.time()
+                        # fault site before the step's rng split + dispatch:
+                        # a preemption loses this step, as a real kill would
+                        _fault_hook("step")
+                        t_h = time.perf_counter()
+                        with _span("h2d"):
+                            if isinstance(x, jax.Array):
+                                # staged upstream (pipeline --stage device):
+                                # the batch is already committed to device
+                                # (and to the strategy's sharded layout) —
+                                # dispatch no longer pays the h2d copy
+                                pass
+                            elif self.strategy is not None:
+                                x, y = self.strategy.shard_batch(x, y)
+                            else:
+                                # target may be a pytree (Mixup's
+                                # (y_a, y_b, lam))
+                                x = jnp.asarray(x)
+                                y = jax.tree_util.tree_map(jnp.asarray, y)
+                        self._obs_phase("h2d", time.perf_counter() - t_h)
+                        k_step = _next_key()
+                        t_d = time.perf_counter()
+                        try:
+                            with _span("dispatch"):
+                                params, mod_state, opt_state, loss = step_fn(
+                                    params, mod_state, opt_state, x, y,
+                                    k_step)
+                        except Exception as e:
+                            from bigdl_tpu.obs import memory as _obs_mem
+                            _obs_mem.handle_oom(e, "train_dispatch")
+                            raise
+                        self._obs_phase("dispatch", time.perf_counter() - t_d)
+                        after_dispatch(len(x), 1, t0, loss)
+                        self._maybe_validate(eval_fn, params, mod_state,
+                                             driver)
+                        self._maybe_checkpoint(params, mod_state, opt_state,
+                                               driver)
+                        if self.end_when(driver):
                             epoch_done = True
                             break
-                        if buf and _shape_sig(b) != _shape_sig(buf[0]):
-                            pending = b  # ragged tail: flush, retry next
-                            break
-                        buf.append(b)
-                dt_fetch = time.time() - t_fetch
-                fetch_accum += dt_fetch
-                self._obs_phase("data_wait", dt_fetch)
-                if not buf:
-                    break
-                if chunk_fn is not None and len(buf) == K:
-                    if capture is not None:
-                        capture.on_step(driver["iteration"])
-                    t0 = time.time()
-                    t_h = time.perf_counter()
-                    with _span("h2d", batches=K):
-                        xs = jnp.stack([jnp.asarray(bx) for bx, _ in buf])
-                        ys = jax.tree_util.tree_map(
-                            lambda *ls: jnp.stack(
-                                [jnp.asarray(l) for l in ls]),
-                            *[by for _, by in buf])
-                    self._obs_phase("h2d", time.perf_counter() - t_h)
-                    # fault site BEFORE the dispatch and BEFORE the rng
-                    # splits: a preemption here loses the whole chunk,
-                    # exactly like a kill between dispatches would
-                    _fault_hook("step")
-                    # same host key sequence as K=1 (counted for resume)
-                    keys = [_next_key() for _ in range(K)]
-                    t_d = time.perf_counter()
-                    try:
-                        with _span("dispatch", steps=K):
-                            params, mod_state, opt_state, loss = chunk_fn(
-                                params, mod_state, opt_state, xs, ys,
-                                jnp.stack(keys))
-                    except Exception as e:
-                        # RESOURCE_EXHAUSTED autopsy (ISSUE 12): write
-                        # the MemoryReport to --traceDir + fault log,
-                        # then crash exactly as before
-                        from bigdl_tpu.obs import memory as _obs_mem
-                        _obs_mem.handle_oom(e, "train_dispatch")
-                        raise
-                    self._obs_phase("dispatch", time.perf_counter() - t_d)
-                    if obs_on:
-                        # true device wait: only metered under --obs (the
-                        # sync costs dispatch pipelining; that delta is
-                        # the obs overhead — not measured on the chip)
-                        t_w = time.perf_counter()
-                        with _span("device"):
-                            jax.block_until_ready(loss)
-                        self._obs_phase("device",
-                                        time.perf_counter() - t_w)
-                    after_dispatch(sum(len(bx) for bx, _ in buf), K, t0,
-                                   loss)
-                    self._maybe_validate(eval_fn, params, mod_state, driver)
-                    self._maybe_checkpoint(params, mod_state, opt_state,
-                                           driver)
-                    if self.end_when(driver):
-                        break
-                    continue
-                for x, y in buf:  # K == 1, or a ragged/short group
-                    if capture is not None:
-                        capture.on_step(driver["iteration"])
-                    t0 = time.time()
-                    # fault site before the step's rng split + dispatch:
-                    # a preemption loses this step, as a real kill would
-                    _fault_hook("step")
-                    t_h = time.perf_counter()
-                    with _span("h2d"):
-                        if isinstance(x, jax.Array):
-                            # staged upstream (pipeline --stage device):
-                            # the batch is already committed to device
-                            # (and to the strategy's sharded layout) —
-                            # dispatch no longer pays the h2d copy
-                            pass
-                        elif self.strategy is not None:
-                            x, y = self.strategy.shard_batch(x, y)
-                        else:
-                            # target may be a pytree (Mixup's
-                            # (y_a, y_b, lam))
-                            x = jnp.asarray(x)
-                            y = jax.tree_util.tree_map(jnp.asarray, y)
-                    self._obs_phase("h2d", time.perf_counter() - t_h)
-                    k_step = _next_key()
-                    t_d = time.perf_counter()
-                    try:
-                        with _span("dispatch"):
-                            params, mod_state, opt_state, loss = step_fn(
-                                params, mod_state, opt_state, x, y,
-                                k_step)
-                    except Exception as e:
-                        from bigdl_tpu.obs import memory as _obs_mem
-                        _obs_mem.handle_oom(e, "train_dispatch")
-                        raise
-                    self._obs_phase("dispatch", time.perf_counter() - t_d)
-                    if obs_on:
-                        t_w = time.perf_counter()
-                        with _span("device"):
-                            jax.block_until_ready(loss)
-                        self._obs_phase("device",
-                                        time.perf_counter() - t_w)
-                    after_dispatch(len(x), 1, t0, loss)
-                    self._maybe_validate(eval_fn, params, mod_state, driver)
-                    self._maybe_checkpoint(params, mod_state, opt_state,
-                                           driver)
-                    if self.end_when(driver):
-                        epoch_done = True
-                        break
             driver["epoch"] += 1
             driver["epoch_finished"] = True
             driver["epoch_records"] = 0  # next epoch starts at cursor 0
@@ -772,8 +762,8 @@ class Optimizer:
             # surface the phase split EVERY epoch (ISSUE 7 satellite: the
             # old fetch_accum was measured then dropped — the feed-stall
             # gap behind resnet50_pipe's 0.99% MFU, PERF.md §4, was
-            # invisible in normal runs). data_wait/dispatch meter in
-            # every run; h2d/device only split out under --obs.
+            # invisible in normal runs). Every phase meters in every run;
+            # device is the wait inside the loss fetch at each log point.
             d_wait = (self._phase_totals.get("data_wait", 0.0)
                       - ph_snap.get("data_wait", 0.0))
             d_disp = (self._phase_totals.get("dispatch", 0.0)

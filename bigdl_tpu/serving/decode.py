@@ -55,6 +55,7 @@ bit-identical to that in tests/test_spec_decode.py.
 from __future__ import annotations
 
 import collections
+import contextlib
 import logging
 import threading
 from typing import Optional, Sequence
@@ -77,7 +78,7 @@ __all__ = ["DecodeEngine", "DecodeRequest"]
 class DecodeRequest:
     __slots__ = ("tokens", "max_new_tokens", "temperature", "stop_token",
                  "top_k", "top_p", "seed", "future", "out", "deadline",
-                 "rid", "emit")
+                 "rid", "emit", "t_queued")
 
     def __init__(self, tokens, max_new_tokens, temperature=0.0,
                  stop_token=None, deadline=None, top_k=0, top_p=1.0,
@@ -98,6 +99,7 @@ class DecodeRequest:
         # reach it on the speculative path, so streamed output is
         # structurally identical to the buffered future result
         self.emit = emit
+        self.t_queued = None  # engine clock when it joined the queue
 
 
 class DecodeEngine:
@@ -280,6 +282,8 @@ class DecodeEngine:
         if metrics is None:
             self._m_tokens = self._m_steps = self._m_prefills = None
             self._m_prompt_tokens = self._m_rejected = None
+            self._m_bucket_tokens = None
+            self._m_queued = self._m_queue_wait = None
             self._m_expired = self._m_dead = self._m_cancelled = None
             self._m_spec_prop = self._m_spec_acc = None
             self._m_draft_steps = None
@@ -293,6 +297,16 @@ class DecodeEngine:
             "prefills_total", "prompt prefills executed")
         self._m_prompt_tokens = metrics.counter(
             "prompt_tokens_total", "prompt tokens prefilled")
+        self._m_bucket_tokens = metrics.counter(
+            "prefill_bucket_tokens_total",
+            "positions prefilled, padding included (the bucket length "
+            "of every prefill)")
+        self._m_queued = metrics.counter(
+            "decode_queued_total",
+            "generate requests installed after waiting for a slot")
+        self._m_queue_wait = metrics.counter(
+            "decode_queue_wait_seconds_total",
+            "seconds those requests waited, enqueue to install")
         self._m_rejected = metrics.counter(
             "decode_rejected_total",
             "generate requests fast-rejected (waiting queue full)")
@@ -760,8 +774,18 @@ class DecodeEngine:
                 self._admitting -= 1
         return req.future
 
+    @contextlib.contextmanager
+    def _locked(self, wait_span: str, **args):
+        """Hold the engine lock; the wait for it is the span."""
+        with _obs_span(wait_span, **args):
+            self._lock.acquire()
+        try:
+            yield
+        finally:
+            self._lock.release()
+
     def _admit(self, req, deadline, rid) -> None:
-        with self._lock:
+        with self._locked("submit_lock_wait", rid=rid):
             if not self.busy():
                 # work arrives at an idle engine: the stall clock starts
                 # NOW, not at the loop's last (arbitrarily old) idle beat
@@ -789,6 +813,7 @@ class DecodeEngine:
                 raise AdmissionError(
                     f"decode queue at capacity ({self.max_waiting} waiting)")
             else:
+                req.t_queued = self.clock()
                 self._waiting.append(req)
                 if rid is not None:
                     rt = _get_reqtracer()
@@ -816,6 +841,9 @@ class DecodeEngine:
         while self._waiting:
             req = self._waiting.popleft()
             if self._install(req, slot):
+                if self._m_queued is not None:
+                    self._m_queued.inc()
+                    self._m_queue_wait.inc(self.clock() - req.t_queued)
                 return
             self._waiting.appendleft(req)
             return
@@ -832,14 +860,18 @@ class DecodeEngine:
             return False
         rt = _get_reqtracer() if req.rid is not None else None
         t0_pf = rt.clock() if rt is not None else 0.0
-        with _obs_span("decode_prefill", prompt=s):
-            n_pfx, src_pages = (self._pfx.match(req.tokens)
-                                if self._pfx is not None else (0, []))
+        n_pfx, src_pages = (self._pfx.match(req.tokens)
+                            if self._pfx is not None else (0, []))
+        # a prefix hit prefills the suffix alone, at its own bucket
+        bucket = (min(self.prompt_bucket_for(s - n_pfx),
+                      self.max_len - n_pfx)
+                  if n_pfx else self.prompt_bucket_for(s))
+        with _obs_span("decode_prefill", prompt=s, rid=req.rid,
+                       bucket=bucket, slot=slot):
             if n_pfx:
                 logits_vec = self._prefill_from_prefix(
-                    req, slot, n_pfx, src_pages)
+                    req, slot, n_pfx, src_pages, bucket)
             else:
-                bucket = self.prompt_bucket_for(s)
                 padded = np.zeros((1, bucket), np.int32)
                 padded[0, :s] = req.tokens
                 logits_vec, cache1 = self._prefill_jit(
@@ -868,6 +900,7 @@ class DecodeEngine:
         if self._m_prefills is not None:
             self._m_prefills.inc()
             self._m_prompt_tokens.inc(s - n_pfx)
+            self._m_bucket_tokens.inc(bucket)
         if rt is not None:
             rt.note_prefill(
                 req.rid, t0_pf, rt.clock(), slot=slot,
@@ -887,13 +920,14 @@ class DecodeEngine:
             self._emit(req, slot, [tok0])
         return True
 
-    def _prefill_from_prefix(self, req, slot: int, n_pfx: int, src_pages):
+    def _prefill_from_prefix(self, req, slot: int, n_pfx: int, src_pages,
+                             mb: int):
         """Prefix-cache HIT: device-copy the entry's pages into the
-        slot, then chunk-prefill only the suffix at offset ``n_pfx`` —
-        bit-identical to the full prefill (the copied K/V came from the
-        identical graph; suffix rows compute the same per-row math)."""
+        slot, then chunk-prefill only the suffix at offset ``n_pfx``,
+        padded to ``mb`` — bit-identical to the full prefill (the copied
+        K/V came from the identical graph; suffix rows compute the same
+        per-row math)."""
         jnp = self._jnp
-        s = len(req.tokens)
         pt = self.page_tokens
         dst = self._kv.page_table[slot, :n_pfx // pt]
         with _obs_span("prefix_copy", pages=len(src_pages)):
@@ -901,8 +935,6 @@ class DecodeEngine:
                 self._kv.pools, jnp.asarray(src_pages, jnp.int32),
                 jnp.asarray(dst))
         suffix = req.tokens[n_pfx:]
-        mb = min(self.prompt_bucket_for(len(suffix)),
-                 self.max_len - n_pfx)
         padded = np.zeros((1, mb), np.int32)
         padded[0, :len(suffix)] = suffix
         logits_vec, self._kv.pools = self._get_suffix(mb)(
@@ -1087,16 +1119,17 @@ class DecodeEngine:
         Returns the number of active slots advanced (0 = idle). Finished
         requests resolve their futures and hand their slot to the next
         waiting request; expired ones are dropped before compute."""
-        with self._lock:
+        with self._locked("decode_lock_wait"):
             self._last_beat = self.clock()
             self._expire(self.clock())
             active = [i for i, r in enumerate(self._reqs)
                       if r is not None]
             if not active:
                 return 0
-            if self.speculate > 0:
-                return self._step_spec(active)
-            return self._step_plain(active)
+            with _obs_span("decode_round", active=len(active)):
+                if self.speculate > 0:
+                    return self._step_spec(active)
+                return self._step_plain(active)
 
     def _sampling_args(self):
         jnp = self._jnp
@@ -1110,8 +1143,9 @@ class DecodeEngine:
 
     def _step_plain(self, active) -> int:
         jnp = self._jnp
-        prog = self._get_step(self._needs_warp(active))
-        pos, temp, topk, topp, seed = self._sampling_args()
+        with _obs_span("decode_args"):
+            prog = self._get_step(self._needs_warp(active))
+            pos, temp, topk, topp, seed = self._sampling_args()
         with _obs_span("decode_step", active=len(active)):
             try:
                 if self.paged:
@@ -1130,13 +1164,15 @@ class DecodeEngine:
                 from bigdl_tpu.obs import memory as _obs_mem
                 _obs_mem.handle_oom(e, "decode_step")
                 raise
-            toks_host = np.asarray(toks)
+            with _obs_span("decode_host_read"):
+                toks_host = np.asarray(toks)
         if self._m_steps is not None:
             self._m_steps.inc()
-        for i in active:
-            req = self._reqs[i]
-            self._pos[i] += 1
-            self._emit(req, i, [int(toks_host[i])])
+        with _obs_span("decode_emit"):
+            for i in active:
+                req = self._reqs[i]
+                self._pos[i] += 1
+                self._emit(req, i, [int(toks_host[i])])
         return len(active)
 
     def _step_spec(self, active) -> int:
@@ -1151,9 +1187,10 @@ class DecodeEngine:
         # fed), so m >= 2 — at least one proposal per round.
         m = min(self.speculate + 1,
                 self.max_len - max(int(self._pos[i]) for i in active))
-        pos, temp, topk, topp, seed = self._sampling_args()
-        feed = jnp.asarray(self._pending)
-        draft_step = self._get_draft_step()
+        with _obs_span("decode_args"):
+            pos, temp, topk, topp, seed = self._sampling_args()
+            feed = jnp.asarray(self._pending)
+            draft_step = self._get_draft_step()
         props, qrows = [], []
         with _obs_span("spec_draft", active=len(active), feeds=m):
             for j in range(m):
@@ -1192,21 +1229,23 @@ class DecodeEngine:
                 raise
         emitted, n_emit, n_acc = self._get_accept(m)(
             T, qstack, pstack, temp, topk, topp, seed, pos)
-        emitted = np.asarray(emitted)
-        n_emit = np.asarray(n_emit)
-        n_acc = np.asarray(n_acc)
+        with _obs_span("decode_host_read"):
+            emitted = np.asarray(emitted)
+            n_emit = np.asarray(n_emit)
+            n_acc = np.asarray(n_acc)
         if self._m_steps is not None:
             self._m_steps.inc()
         if self._m_spec_prop is not None:
             self._m_spec_prop.inc((m - 1) * len(active))
             self._m_spec_acc.inc(int(sum(int(n_acc[i]) for i in active)))
-        for i in active:
-            req = self._reqs[i]
-            k = int(n_emit[i])
-            stream = [int(t) for t in emitted[i, :k]]
-            self._pos[i] += k
-            if not self._emit(req, i, stream, accepted=int(n_acc[i])):
-                self._pending[i] = stream[-1]
+        with _obs_span("decode_emit"):
+            for i in active:
+                req = self._reqs[i]
+                k = int(n_emit[i])
+                stream = [int(t) for t in emitted[i, :k]]
+                self._pos[i] += k
+                if not self._emit(req, i, stream, accepted=int(n_acc[i])):
+                    self._pending[i] = stream[-1]
         return len(active)
 
     def generate(self, tokens, max_new_tokens: int,
@@ -1318,12 +1357,13 @@ class DecodeEngine:
         def _loop():
             try:
                 while True:
-                    with self._lock:
+                    with self._locked("decode_lock_wait"):
                         self._last_beat = self.clock()
                         while (not self._closed
                                and not any(r is not None
                                            for r in self._reqs)):
-                            self._work.wait()
+                            with _obs_span("decode_idle"):
+                                self._work.wait()
                             self._last_beat = self.clock()
                         if self._closed:
                             return

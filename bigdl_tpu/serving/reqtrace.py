@@ -22,10 +22,10 @@ accepted, KV pages held, and sequence position; prefill notes the
 prefix-cache hit length and slot. Completed records land in a bounded
 ring (the flight recorder) with drop counting; derived latencies —
 TTFT, TPOT, per-token ITL, queue wait, prefill, decode — publish into
-the shared metrics registry as histograms with p50/p95/p99, and each
-record can be joined back onto the ``obs.spans`` Chrome-trace timeline
-as back-dated ``req:*`` phase spans (category ``request``) so one slow
-request renders next to the batcher/engine spans that served it.
+the shared metrics registry as histograms with p50/p95/p99. The
+timeline of a request is not kept here: its phases are live
+``obs.spans`` spans (``generate_request``, ``submit_lock_wait``,
+``decode_prefill``, ...) that carry the same ``rid``.
 
 Optional policy hooks:
 
@@ -45,9 +45,7 @@ byte-identical.
 Thread model: records are mutated from HTTP handler threads, the
 batcher worker, and the decode loop; one lock guards the live table and
 the ring. Hooks touch a few scalars under it — never an engine call.
-The clock is injectable for deterministic tests; when an ``obs`` tracer
-is installed the default clock is the tracer's, so joined spans share
-its timebase.
+The clock is injectable for deterministic tests.
 """
 
 from __future__ import annotations
@@ -61,7 +59,6 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional
 
-from bigdl_tpu.obs import spans as _spans
 from bigdl_tpu.obs.metrics import ITL_BUCKETS_MS
 
 __all__ = ["RequestRecord", "RequestTracer", "SloPolicy", "AccessLog",
@@ -405,10 +402,7 @@ class RequestTracer:
                  max_rounds: int = 64):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
-        if clock is None:
-            obs = _spans.get_tracer()
-            clock = obs.clock if obs is not None else time.perf_counter
-        self.clock = clock
+        self.clock = time.perf_counter if clock is None else clock
         self.capacity = int(capacity)
         self.max_rounds = int(max_rounds)
         self.slo = slo
@@ -622,10 +616,10 @@ class RequestTracer:
                status: Optional[int] = None,
                error: Optional[str] = None) -> None:
         """Terminalize the record: stamp ``t_finish``, publish derived
-        histograms, evaluate SLO, write the access log, join the obs
-        timeline, and move the record into the ring. Idempotent — a
-        second finish (server annotating HTTP status after the decode
-        loop already finished the record) only fills in ``status``."""
+        histograms, evaluate SLO, write the access log, and move the
+        record into the ring. Idempotent — a second finish (server
+        annotating HTTP status after the decode loop already finished
+        the record) only fills in ``status``."""
         if rid is None or state not in TERMINAL_STATES:
             return
         with self._lock:
@@ -679,30 +673,6 @@ class RequestTracer:
                     self._c_slo_good.inc()
         if self.access_log is not None:
             self.access_log.write(rec.to_dict())
-        self._join_obs(rec)
-
-    def _join_obs(self, rec: RequestRecord) -> None:
-        """Back-date the record's phases onto the obs.spans timeline as
-        ``req:*`` spans (category ``request``) keyed by rid — one slow
-        request renders against the batcher/engine spans that served
-        it. Skipped when the obs tracer runs a different clock (the
-        timebases would not line up)."""
-        tr = _spans.get_tracer()
-        if tr is None or tr.clock is not self.clock:
-            return
-        args = {"rid": rec.rid, "state": rec.state}
-        t_q0 = rec.t_queue if rec.t_queue is not None else rec.t_admit
-        phases = (("req:queue_wait", t_q0, rec.t_dequeue),
-                  ("req:prefill", rec.t_prefill0, rec.t_prefill1),
-                  ("req:decode", rec.t_prefill1, rec.t_last_token))
-        tr.record(f"req:{rec.endpoint}", rec.t_admit,
-                  rec.t_finish, depth=0,
-                  args={**args, "tokens_out": rec.tokens_out},
-                  cat="request")
-        for name, t0, t1 in phases:
-            if t0 is not None and t1 is not None and t1 > t0:
-                tr.record(name, t0, t1, depth=1, args=args,
-                          cat="request")
 
     # --------------------------------------------------------- inspection
     def in_flight(self) -> List[RequestRecord]:

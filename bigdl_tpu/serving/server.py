@@ -45,6 +45,7 @@ throughout so the process is drained, not killed.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import logging
 import queue as _queue_mod
@@ -656,7 +657,8 @@ class _Handler(BaseHTTPRequestHandler):
             self._send_json(*handler(payload), rid=rid)
             return
         if self.path.strip("/") == "generate" and payload.get("stream"):
-            self._stream_generate(payload, rid)
+            with _obs_span("generate_request", rid=rid):
+                self._stream_generate(payload, rid)
             return
         status, body = self.app.dispatch_post(self.path, payload,
                                               rid=rid)
@@ -682,7 +684,8 @@ class _Handler(BaseHTTPRequestHandler):
         allocator."""
         app = self.app
         t0 = time.perf_counter()
-        status, obj = app.start_generate_stream(payload, rid=rid)
+        with _obs_span("generate_admit", rid=rid):
+            status, obj = app.start_generate_stream(payload, rid=rid)
         if status != 200:
             self._send_json(status, obj, rid=rid)
             return
@@ -692,6 +695,10 @@ class _Handler(BaseHTTPRequestHandler):
         first = True
         n_out = 0
         deadline = time.monotonic() + app.request_timeout_s
+        # the span that is open: the wait for the first token, from
+        # submit's return; then one span from the first frame to the last
+        phase = contextlib.ExitStack()
+        phase.enter_context(_obs_span("generate_first_token_wait", rid=rid))
         try:
             self.send_response(200)
             self.send_header("x-request-id", rid)
@@ -726,10 +733,14 @@ class _Handler(BaseHTTPRequestHandler):
                             self._sse({"error": "stream timeout"}))
                         break
                     continue
-                if first and rt is not None:
-                    # first byte is about to hit the wire: THIS is the
-                    # TTFT the client feels, and what --slo judges
-                    rt.note_first_byte(rid)
+                if first:
+                    phase.close()
+                    phase.enter_context(_obs_span("generate_stream",
+                                                  rid=rid))
+                    if rt is not None:
+                        # first byte is about to hit the wire: THIS is
+                        # the TTFT the client feels, and what --slo judges
+                        rt.note_first_byte(rid)
                 self._write_chunk(self._sse({"tokens": toks}))
                 first = False
                 n_out += len(toks)
@@ -745,6 +756,7 @@ class _Handler(BaseHTTPRequestHandler):
             # page reservation NOW instead of decoding into a dead pipe
             stream.decoder.cancel(rid)
         finally:
+            phase.close()
             app.finish_generate_stream(rid, ok, t0)
 
     def log_message(self, fmt, *args):  # route access logs to logging

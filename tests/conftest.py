@@ -75,3 +75,77 @@ def rng():
 @pytest.fixture(autouse=True)
 def _np_seed():
     np.random.seed(0)
+
+
+@pytest.fixture(scope="session")
+def traced_toy_run(tmp_path_factory):
+    """One CPU profiler session (``benchmark.lib.trace``) over the real
+    serving stack and the real training loop at toy sizes: four streamed
+    ``/generate`` requests on a two-slot ``DecodeEngine`` (so two of them
+    queue), a pause in which the decode loop idles, a fifth request, and
+    three ``Optimizer`` steps. Gives the planes, the engine's registry and
+    the prompt lengths sent."""
+    import threading
+    import time
+
+    from benchmark.lib import trace
+    from benchmark.lib.serve import stream_generate
+    from bigdl_tpu import models, nn
+    from bigdl_tpu.dataset import BatchDataSet
+    from bigdl_tpu.optim import SGD, Optimizer, Trigger
+    from bigdl_tpu.serving import (DecodeEngine, MetricsRegistry,
+                                   ServingApp, make_server)
+
+    model = models.transformer_lm(50, d_model=32, num_layers=2,
+                                  num_heads=2, max_len=64)
+    params = model.init(jax.random.PRNGKey(1))
+    registry = MetricsRegistry()
+    decoder = DecodeEngine(model, params, slots=2, max_waiting=8,
+                           metrics=registry)
+    app = ServingApp(name="toy", metrics=registry, decoder=decoder)
+    srv = make_server(app, "127.0.0.1", 0)
+    port = srv.server_address[1]
+    decoder.start()
+    threading.Thread(target=srv.serve_forever, daemon=True).start()
+    prompts = [5, 9, 17, 33, 7]  # tokens; buckets are powers of two
+
+    def request(n_prompt, max_new=4):
+        rec = {"arrivals": [], "out": []}
+        stream_generate(port, list(range(1, n_prompt + 1)), max_new, rec,
+                        timeout=120)
+        assert len(rec["out"]) == max_new, rec
+
+    def optimize():
+        x = np.random.RandomState(0).randn(24, 6).astype(np.float32)
+        y = (x.sum(axis=1) > 0).astype(np.int32)
+        Optimizer(nn.Sequential(nn.Linear(6, 2), nn.LogSoftMax()),
+                  BatchDataSet(x, y, 8), nn.ClassNLLCriterion(),
+                  optim_method=SGD(learning_rate=0.1),
+                  end_when=Trigger.max_iteration(3)).optimize()
+
+    try:
+        for n in prompts:  # every program compiles before the session
+            request(n)
+        optimize()
+        before = {k: registry.counter(k).value for k in (
+            "prompt_tokens_total", "prefill_bucket_tokens_total",
+            "decode_queued_total", "decode_queue_wait_seconds_total")}
+        log_dir = str(tmp_path_factory.mktemp("traced_toy"))
+        trace.start(log_dir)
+        wave = [threading.Thread(target=request, args=(n, 12))
+                for n in prompts[:4]]
+        for t in wave:
+            t.start()
+        for t in wave:
+            t.join(120)
+        time.sleep(0.2)  # the loop idles, then a request wakes it
+        request(prompts[4])
+        optimize()
+        planes = trace.stop_and_load(log_dir)
+    finally:
+        srv.shutdown()
+        srv.server_close()
+        app.close()
+    return {"planes": planes, "registry": registry, "before": before,
+            "buckets": [decoder.prompt_bucket_for(n) for n in prompts],
+            "prompts": prompts}
