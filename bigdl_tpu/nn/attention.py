@@ -238,11 +238,11 @@ class MultiHeadAttention(SimpleModule):
         return x.reshape(b, s, n, f // n).transpose(0, 2, 1, 3)
 
     def _expand_kv(self, kv):
-        """Broadcast (b, n_kv, s, d) K/V over the query groups."""
+        """Copy (b, n_kv, s, d) K/V out to the query head count, for
+        ``attn_fn`` alone (``_forward``, ``prefill``): the flash kernel
+        takes equal head counts. ``decode_chunk`` reads K/V grouped."""
         g = self.num_heads // self.num_kv_heads
-        if g == 1:
-            return kv
-        return jnp.repeat(kv, g, axis=1)
+        return kv if g == 1 else jnp.repeat(kv, g, axis=1)
 
     def _rope(self, x, pos0):
         """Rotate (b, h, s, d) starting at absolute position ``pos0``."""
@@ -348,6 +348,16 @@ class MultiHeadAttention(SimpleModule):
         scores/softmax/weighted-sum are row-independent, so the m=1
         case IS decode_step (and per-row results match the sequential
         path bit-for-bit on the dense CPU path — pinned in tests).
+
+        The cache is read once, at its stored head count: the
+        g = num_heads // num_kv_heads query heads of a group and the m
+        chunk rows fold into one row axis (row r = j*m + i: head j of
+        the group, chunk position i = r % m), and both contractions are
+        matmuls of those g*m rows against the group's (S, d) K and V,
+        batched over (b, n_kv), operands in the activations' dtype with
+        f32 accumulation. No expanded copy of K/V exists; g == 1 (plain
+        multi-head) is the same code with a fold that moves nothing.
+
         Caller must keep idx + m <= cache length: dynamic_update_slice
         clamps out-of-range starts, which would silently shift the
         write window."""
@@ -358,21 +368,20 @@ class MultiHeadAttention(SimpleModule):
             cache["k"], k.astype(cache["k"].dtype), (0, 0, idx, 0))
         vc = jax.lax.dynamic_update_slice(
             cache["v"], v.astype(cache["v"].dtype), (0, 0, idx, 0))
-        ke = self._expand_kv(kc)
-        ve = self._expand_kv(vc)
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, ke.astype(q.dtype),
+        b, h, m, d = q.shape
+        q = q.reshape(b, self.num_kv_heads, -1, d)
+        s = jnp.einsum("bkrd,bksd->bkrs", q, kc.astype(q.dtype),
                        preferred_element_type=jnp.float32)
         s = s / (self.head_dim ** 0.5)
-        m = x.shape[1]
-        rows = idx + jnp.arange(m)[None, None, :, None]
-        live = jnp.arange(ke.shape[2])[None, None, None, :] <= rows
+        rows = idx + (jnp.arange(q.shape[2]) % m)[None, None, :, None]
+        live = jnp.arange(kc.shape[2])[None, None, None, :] <= rows
         s = jnp.where(live, s, -1e30)
         p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhqk,bhkd->bhqd", p.astype(q.dtype),
-                       ve.astype(q.dtype),
+        o = jnp.einsum("bkrs,bksd->bkrd", p.astype(q.dtype),
+                       vc.astype(q.dtype),
                        preferred_element_type=jnp.float32).astype(x.dtype)
         dt = x.dtype
-        o = self._merge_heads(o)
+        o = self._merge_heads(o.reshape(b, h, m, d))
         return (o @ params["wo"].astype(dt) + params["bo"].astype(dt),
                 {"k": kc, "v": vc})
 

@@ -20,7 +20,13 @@ batching):
   (``jax.vmap`` of ``model.decode_logits`` with per-slot positions), so
   requests of different lengths and arrival times share the batch. A
   finishing request frees its slot; the next waiting request prefills
-  into it while the others keep decoding.
+  into it while the others keep decoding. A step reads every weight and,
+  in attention, the whole cache once at its stored ``num_kv_heads``
+  (``MultiHeadAttention.decode_chunk``: the query heads of a group are
+  rows of one matmul against that group's K and V): all ``max_len``
+  positions of every slot, whatever is live.
+  ``decode_live_positions_total`` over ``decode_steps_total`` says how
+  many of them a step needed.
 
 ISSUE 14 rebuilt the hot path around three composable optimisations:
 
@@ -281,6 +287,7 @@ class DecodeEngine:
         self.metrics = metrics
         if metrics is None:
             self._m_tokens = self._m_steps = self._m_prefills = None
+            self._m_live_pos = None
             self._m_prompt_tokens = self._m_rejected = None
             self._m_bucket_tokens = None
             self._m_queued = self._m_queue_wait = None
@@ -293,6 +300,11 @@ class DecodeEngine:
         self._m_steps = metrics.counter(
             "decode_steps_total",
             "batched TARGET-model decode/verify steps executed")
+        self._m_live_pos = metrics.counter(
+            "decode_live_positions_total",
+            "cache positions those steps had to read: the live slots' "
+            "positions before each step (over decode_steps_total, "
+            "against slots x max_len: the share of the cache in use)")
         self._m_prefills = metrics.counter(
             "prefills_total", "prompt prefills executed")
         self._m_prompt_tokens = metrics.counter(
@@ -1141,6 +1153,12 @@ class DecodeEngine:
         return any(self._topk[i] > 0 or self._topp[i] < 1.0
                    for i in active)
 
+    def _count_step(self, active) -> None:
+        # before the emit loop advances the positions
+        if self._m_steps is not None:
+            self._m_steps.inc()
+            self._m_live_pos.inc(int(self._pos[active].sum()))
+
     def _step_plain(self, active) -> int:
         jnp = self._jnp
         with _obs_span("decode_args"):
@@ -1166,8 +1184,7 @@ class DecodeEngine:
                 raise
             with _obs_span("decode_host_read"):
                 toks_host = np.asarray(toks)
-        if self._m_steps is not None:
-            self._m_steps.inc()
+        self._count_step(active)
         with _obs_span("decode_emit"):
             for i in active:
                 req = self._reqs[i]
@@ -1233,8 +1250,7 @@ class DecodeEngine:
             emitted = np.asarray(emitted)
             n_emit = np.asarray(n_emit)
             n_acc = np.asarray(n_acc)
-        if self._m_steps is not None:
-            self._m_steps.inc()
+        self._count_step(active)
         if self._m_spec_prop is not None:
             self._m_spec_prop.inc((m - 1) * len(active))
             self._m_spec_acc.inc(int(sum(int(n_acc[i]) for i in active)))

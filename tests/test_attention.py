@@ -239,6 +239,107 @@ def test_gqa_generate_equivalence():
     np.testing.assert_array_equal(out, np.asarray(jnp.stack(ref, axis=1)))
 
 
+def _decode_chunk_by_expansion(mha, params, x, cache, idx):
+    """The plain reference of ``decode_chunk``: K/V copied out to the
+    query head count by hand, one query row a head (the formula the
+    grouped contractions replaced), at the same operand precision."""
+    q, k, v = mha._qkv(params, x)
+    if mha.rope:
+        q, k = mha._rope(q, idx), mha._rope(k, idx)
+    kc = jax.lax.dynamic_update_slice(cache["k"], k, (0, 0, idx, 0))
+    vc = jax.lax.dynamic_update_slice(cache["v"], v, (0, 0, idx, 0))
+    g = mha.num_heads // mha.num_kv_heads
+    ke, ve = jnp.repeat(kc, g, axis=1), jnp.repeat(vc, g, axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, ke,
+                   preferred_element_type=jnp.float32)
+    s = s / (mha.head_dim ** 0.5)
+    rows = idx + jnp.arange(x.shape[1])[None, None, :, None]
+    live = jnp.arange(ke.shape[2])[None, None, None, :] <= rows
+    p = jax.nn.softmax(jnp.where(live, s, -1e30), axis=-1)
+    o = jnp.einsum("bhqk,bhkd->bhqd", p.astype(q.dtype), ve,
+                   preferred_element_type=jnp.float32).astype(x.dtype)
+    o = mha._merge_heads(o)
+    return o @ params["wo"] + params["bo"], {"k": kc, "v": vc}
+
+
+# tol is absolute and relative (|got - want| <= tol + tol * |want|).
+# f32: the two formulas differ by summation order alone. bf16: the f32
+# accumulators agree to that order too, so the outputs differ by the
+# rounding of p and o to bf16 (2**-8 relative each) and the wo matmul
+# over them: 3e-2 holds a few of those steps, and a wrong row order,
+# mask or group moves an output by its own size
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5),
+                                       ("bfloat16", 3e-2)])
+@pytest.mark.parametrize("m", [1, 4])
+@pytest.mark.parametrize("g", [1, 4, 12])
+def test_decode_chunk_grouped_contraction(g, m, dtype, tol):
+    """``decode_chunk`` attends over the cache at its stored head count:
+    under ``vmap`` with a position of its own for every slot (the
+    engine's step) it equals the expand-then-contract reference, for
+    plain multi-head (g = 1) and grouped heads, one row and a chunk; in
+    f32 also the same rows of the full causal forward."""
+    n_kv, hd, max_len, slots = 2, 8, 24, 3
+    mha = MultiHeadAttention(n_kv * g * hd, n_kv * g, causal=True,
+                             num_kv_heads=n_kv, rope=True, rope_max_len=32)
+    p = jax.tree_util.tree_map(  # biases that a dropped term would show
+        lambda a: (a + 0.1).astype(dtype), mha.init(jax.random.PRNGKey(g)))
+    x = jax.random.normal(jax.random.PRNGKey(m),
+                          (slots, max_len, mha.d_model)).astype(dtype)
+    idx = jnp.asarray([3, 11, max_len - m], jnp.int32)  # the last: to the end
+    full, cache = mha.prefill(p, x, mha.init_cache(slots, max_len, dtype))
+    chunk = jax.vmap(lambda xi, i: jax.lax.dynamic_slice_in_dim(
+        xi, i, m, 0))(x, idx)
+
+    def one(fn):
+        def f(xi, ci, i):
+            ci = jax.tree_util.tree_map(lambda a: a[None], ci)
+            out, new = fn(mha, p, xi[None], ci, i)
+            return out[0], jax.tree_util.tree_map(lambda a: a[0], new)
+        return jax.jit(jax.vmap(f))(chunk, cache, idx)
+
+    got, new = one(MultiHeadAttention.decode_chunk)
+    want, new_ref = one(_decode_chunk_by_expansion)
+    assert got.dtype == jnp.dtype(dtype)
+    assert new["k"].shape == (slots, n_kv, max_len, hd)
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))
+    np.testing.assert_allclose(f32(got), f32(want), rtol=tol, atol=tol)
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(f32(new[name]), f32(new_ref[name]))
+    if dtype == "float32":  # rows idx..idx+m-1 of the whole-sequence forward
+        rows = jax.vmap(lambda fi, i: jax.lax.dynamic_slice_in_dim(
+            fi, i, m, 0))(full, idx)
+        np.testing.assert_allclose(f32(got), f32(rows), rtol=tol, atol=tol)
+
+
+def test_dense_decode_step_never_expands_the_cache():
+    """No array in the engine's dense decode step of a toy GQA model has
+    the shape of the cache copied out to the query heads, (slots,
+    num_heads, max_len, head_dim) folded or grouped: the copy cannot come
+    back unseen by a CPU-only check."""
+    from bigdl_tpu.models import transformer_lm
+    from bigdl_tpu.serving import DecodeEngine
+
+    slots, heads, n_kv, hd, max_len = 5, 6, 2, 4, 40
+    model = transformer_lm(50, d_model=heads * hd, num_layers=2,
+                           num_heads=heads, num_kv_heads=n_kv,
+                           max_len=max_len)
+    de = DecodeEngine(model, model.init(jax.random.PRNGKey(0)),
+                      slots=slots, max_len=max_len)
+
+    def shapes(jp):
+        for eqn in jp.eqns:
+            for v in eqn.outvars:
+                yield tuple(v.aval.shape)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from shapes(sub)
+
+    seen = set(shapes(de.trace_step_jaxpr().jaxpr))
+    assert (slots, n_kv, max_len, hd) in seen  # the cache itself is there
+    expanded = [s for s in seen if max_len in s and hd in s
+                and (heads in s or heads // n_kv in s)]
+    assert not expanded, expanded
+
+
 def test_segment_mask_packing_equivalence(rng):
     """Two documents packed into one row with make_segment_mask produce
     exactly the outputs of running each document alone — the packed-LM

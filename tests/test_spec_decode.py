@@ -118,6 +118,31 @@ def test_spec_accept_counters_and_dispatch_win(lm):
     assert g("decode_steps_total") < 20.0
 
 
+@pytest.mark.parametrize("speculate", [0, 2])
+def test_live_positions_counter(lm, speculate):
+    """``decode_live_positions_total`` rises each step by the live slots'
+    positions before it (the cache positions the step had to read, counted
+    before the emit loop advances them), on the plain step and on the
+    speculative round."""
+    model, params = lm
+    reg = MetricsRegistry()
+    de = DecodeEngine(model, params, slots=3, max_len=96,
+                      speculate=speculate, metrics=reg)
+    for prompt in PROMPTS[:2]:  # 6 and 3 tokens; the third slot stays free
+        de.submit(prompt, 30)
+    g = lambda n: reg._metrics[n].value
+    assert g("decode_live_positions_total") == 0
+    want = []
+    for _ in range(3):
+        want.append(int(de._pos[:2].sum()))
+        assert de.step() == 2
+        assert g("decode_live_positions_total") == sum(want)
+    assert g("decode_steps_total") == 3
+    assert want[0] == 6 + 3
+    if not speculate:  # one token a slot a step
+        assert want == [9, 11, 13]
+
+
 def test_spec_low_accept_rate_with_random_draft(lm, draft_lm):
     model, params = lm
     dm, dp = draft_lm
