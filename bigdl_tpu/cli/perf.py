@@ -178,6 +178,18 @@ def build_model(name: str, class_num: int = 1000, seq_len=None,
         "transformer_lm_32k": lambda: _lm(
             d_model=1024, num_layers=12, num_heads=8, max_len=32768,
             remat="full"),
+        # decoder-decoder LMs (models/sambay_lm.py): Mamba + window
+        # attention, then gated memory units + cross-attention over one
+        # shared KV cache. The first is the class at smoke-test sizes, as
+        # transformer_lm is of its class; the second has
+        # Phi-4-mini-flash-reasoning's published sizes (3.85B parameters:
+        # serve it with --bf16, and --buckets 1 --seq 256 for /predict)
+        "sambay_lm": lambda: _sambay(
+            _LM_VOCAB, d_model=256, num_layers=8, num_heads=4,
+            num_kv_heads=2, d_ff=512, window=64, max_len=512),
+        "phi4_mini_flash": lambda: _sambay(
+            200064, d_model=2560, num_layers=32, num_heads=40,
+            num_kv_heads=20, d_ff=10240, window=512, max_len=4096),
     }
     if name not in table:
         raise SystemExit(f"unknown model {name}; choose from {list(table)}")
@@ -188,7 +200,9 @@ def build_model(name: str, class_num: int = 1000, seq_len=None,
             "transformer_lm_1k": (1024,),
             "transformer_lm_1k_hd128": (1024,),
             "transformer_lm_16k": (16384,),
-            "transformer_lm_32k": (32768,)}.get(name, (224, 224, 3))
+            "transformer_lm_32k": (32768,),
+            "sambay_lm": (seq_len or 512,),
+            "phi4_mini_flash": (seq_len or 4096,)}.get(name, (224, 224, 3))
     # LM build overrides (tpulint): forced attn_impl and/or seq length
     # apply only to transformer_lm* names and only for this one call
     global _LM_OVERRIDE
@@ -206,6 +220,16 @@ def build_model(name: str, class_num: int = 1000, seq_len=None,
     finally:
         _LM_OVERRIDE = prev
     return model, size
+
+
+def _sambay(vocab, **kw):
+    import jax
+
+    from bigdl_tpu import models
+
+    return models.sambay_lm(
+        vocab, attn_impl="flash" if jax.default_backend() == "tpu" else None,
+        **kw)
 
 
 def _short_side(crop) -> int:

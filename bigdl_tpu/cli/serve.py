@@ -8,6 +8,8 @@ compiles, and (for LMs) continuous-batching KV-cache decode:
     bigdl-tpu serve resnet50 --model ckpt_dir --fusedBN apply \
         --autotune cached --buckets 1,2,4,8,16,32
     bigdl-tpu serve transformer_lm --model ckpt_dir --slots 8 --bf16
+    bigdl-tpu serve phi4_mini_flash --randomInit --bf16 --slots 64 \
+        --buckets 1 --seq 256
     curl -d '{"tokens": [3, 1, 4], "max_new_tokens": 8}' \
         localhost:8000/generate
 
@@ -264,8 +266,7 @@ def build_app(args):
                                    ServingApp, Watchdog)
 
     name = args.model
-    is_lm = name.startswith("transformer_lm")
-    if is_lm and args.vocabSize is not None:
+    if args.vocabSize is not None and name.startswith("transformer_lm"):
         from bigdl_tpu import models
         seq = args.seq or 128
         model = models.transformer_lm(
@@ -277,6 +278,8 @@ def build_app(args):
         from bigdl_tpu.cli.perf import build_model
         model, in_shape = build_model(name, class_num=args.classes,
                                       seq_len=args.seq)
+    # an LM is what offers DecodeEngine its two programs, whatever its name
+    is_lm = hasattr(model, "prefill_logits")
     common.apply_fused_bn(model, getattr(args, "fusedBN", None))
     compute_dtype = jnp.bfloat16 if args.bf16 else None
     if is_lm and compute_dtype is not None:
@@ -298,6 +301,16 @@ def build_app(args):
         n_replicas, tp_k = cfg.serving_replicas, cfg.serving_tp
         groups = replica_device_groups(n_replicas, tp_k)
         mesh0 = serving_mesh(groups[0])
+
+    if getattr(model, "recurrent_state", False) and (
+            args.kvPageTokens or args.prefixCache or args.speculate
+            or (getattr(args, "quantize", None) or "off") != "off"
+            or strategy):
+        raise SystemExit(
+            f"{name} keeps recurrent state in its decode slots and serves "
+            "on the dense path only: --kvPageTokens, --prefixCache, "
+            "--speculate, --quantize and --strategy are not supported "
+            "for it yet (serving/decode.py)")
 
     if args.checkpoint:
         if mesh0 is not None:
@@ -461,8 +474,10 @@ def build_app(args):
                 from bigdl_tpu.analysis import (run_decode_rules,
                                                 run_kv_sharding_rules,
                                                 run_sharding_rules)
-                head_dim = getattr(model.encoder._modules[0].mha,
-                                   "head_dim", model.d_model // 4)
+                # the page-layout fit is the one rule that reads it
+                head_dim = page_tokens and getattr(
+                    model.encoder._modules[0].mha, "head_dim",
+                    model.d_model // 4)
                 step_jaxpr = decoder.trace_step_jaxpr()
                 report = run_decode_rules(
                     step_jaxpr, page_tokens=page_tokens,

@@ -18,3 +18,4 @@ from bigdl_tpu.models.vit import ViT, vit, vit_b16, vit_s16
 from bigdl_tpu.models.transformer_lm import (
     TransformerLM, transformer_lm, packed_lm_targets, PackedNLLCriterion,
 )
+from bigdl_tpu.models.sambay_lm import SambaYLM, sambay_lm

@@ -284,6 +284,25 @@ class TransformerLM(Module):
             cache_attr[key] = jax.jit(run)
         return cache_attr[key](params, prompt, rng)
 
+    # ------------------------------------- what the serving engine asks for
+    def init_cache(self, batch, max_len, dtype=jnp.float32):
+        """What ``batch`` decode slots hold: K and V of ``max_len`` rows
+        for every layer."""
+        return self.encoder.init_cache(batch, max_len, dtype)
+
+    def prompt_buckets(self, max_len, dtype):
+        """The serving prefill's prompt-length ladder: the lengths at
+        which the flash kernel keeps its tuned block plan at this model's
+        head width."""
+        from bigdl_tpu.ops.attention_kernel import serving_prefill_buckets
+        head_dim = getattr(self.encoder._modules[0].mha, "head_dim",
+                           self.d_model // 4)
+        return serving_prefill_buckets(max_len, head_dim, True, dtype)
+
+    def cache_bytes_by_kind(self, cache):
+        from bigdl_tpu.obs.memory import tree_bytes
+        return {"kv_full": tree_bytes(cache)}
+
 
 def transformer_lm(vocab: int, **kw) -> TransformerLM:
     return TransformerLM(vocab, **kw)

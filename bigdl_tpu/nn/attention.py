@@ -616,3 +616,249 @@ class TransformerEncoder(Sequential):
             k = str(i)
             x, new[k] = m.decode_chunk(params[k], x, cache[k], idx)
         return x, new
+
+
+class DifferentialAttention(SimpleModule):
+    """Differential attention (Ye et al., arXiv:2410.05258) as SambaY
+    (arXiv:2507.06607) uses it: causal, grouped, without positional
+    encoding, optionally over a sliding ``window``, optionally with Q
+    alone projected here (``cross``: K and V are another layer's).
+
+    Query heads pair up as (q1_i, q2_i) and KV heads as (k1_j, k2_j),
+    (v1_j, v2_j), adjacent heads making a pair; query pair i reads KV pair
+    i // g. With ``P1 = softmax(q1 k1^T / sqrt(d))``, ``P2`` likewise and
+    ``V = [v1 | v2]``::
+
+        o_i = RMSNorm(P1 V - lam * P2 V) * (1 - lam0)
+        lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lam0
+        lam0 = 0.8 - 0.6 * exp(-0.3 * depth)
+
+    Everything here works on PAIRS: a pair of 64-wide heads is one
+    128-wide row ``[h1 | h2]`` (the plain reshape of the projection's
+    output), K and V are cached that way, and the two softmaxes are the
+    scores of ``[q1 | 0]`` and ``[0 | q2]`` against the one ``[k1 | k2]``:
+    both read K once, at full lane width.
+
+    A slot's cache is ``{"k", "v"}`` of ``(batch, kv_pairs, rows,
+    2 * head_dim)``: ``rows = max_len`` for a full layer, and for a window
+    layer a ring of ``window`` rows written at ``position % window``.
+    Without positional encoding the order inside the ring is free, so the
+    count of valid rows is the whole mask.
+    """
+
+    def __init__(self, d_model: int, num_heads: int, num_kv_heads: int,
+                 depth: int, window: Optional[int] = None,
+                 cross: bool = False, attn_impl: Optional[str] = None,
+                 eps: float = 1e-5, init_std: float = 0.02,
+                 name: Optional[str] = None):
+        super().__init__(name)
+        if num_heads % 2 or num_kv_heads % 2 or d_model % num_heads:
+            raise ValueError("differential attention pairs heads: "
+                             f"{num_heads} query and {num_kv_heads} KV "
+                             f"heads over d_model {d_model}")
+        if (num_heads // 2) % (num_kv_heads // 2):
+            raise ValueError(f"{num_heads // 2} query pairs not divisible "
+                             f"by {num_kv_heads // 2} KV pairs")
+        if attn_impl not in (None, "flash"):
+            raise ValueError(f"attn_impl {attn_impl!r} not in (None, "
+                             "'flash')")
+        self.d_model = d_model
+        self.head_dim = d_model // num_heads
+        self.pair_dim = 2 * self.head_dim
+        self.kv_pairs = num_kv_heads // 2
+        self.group = (num_heads // 2) // self.kv_pairs
+        self.window = window
+        self.cross = cross
+        self.flash = attn_impl == "flash"
+        self.eps = eps
+        self.init_std = init_std
+        self.lam0 = 0.8 - 0.6 * math.exp(-0.3 * depth)
+
+    def init(self, rng):
+        ks = jax.random.split(rng, 8)
+        d, dkv = self.d_model, self.kv_pairs * self.pair_dim
+        mk = lambda k, dout: self.init_std * jax.random.normal(k, (d, dout))
+        lam = lambda k: 0.1 * jax.random.normal(k, (self.head_dim,))
+        p = {"wq": mk(ks[0], d), "bq": jnp.zeros((d,)),
+             "wo": mk(ks[1], d), "bo": jnp.zeros((d,)),
+             "lq1": lam(ks[2]), "lk1": lam(ks[3]),
+             "lq2": lam(ks[4]), "lk2": lam(ks[5]),
+             "ln_sub": {"weight": jnp.ones((self.pair_dim,))}}
+        if not self.cross:
+            p.update(wk=mk(ks[6], dkv), bk=jnp.zeros((dkv,)),
+                     wv=mk(ks[7], dkv), bv=jnp.zeros((dkv,)))
+        return p
+
+    def init_cache(self, batch: int, max_len: int, dtype=jnp.float32):
+        rows = self.window or max_len
+        shape = (batch, self.kv_pairs, rows, self.pair_dim)
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+    # ------------------------------------------------------------- pieces
+    def _pairs(self, x, n):
+        b, s, _ = x.shape
+        return x.reshape(b, s, n, self.pair_dim).transpose(0, 2, 1, 3)
+
+    def project_q(self, params, x):
+        """x (b, s, d) -> (b, kv_pairs, group, s, pair_dim)."""
+        dt = x.dtype
+        q = self._pairs(x @ params["wq"].astype(dt) + params["bq"].astype(dt),
+                        self.kv_pairs * self.group)
+        return q.reshape(q.shape[0], self.kv_pairs, self.group,
+                         *q.shape[2:])
+
+    def project_kv(self, params, x):
+        """x (b, s, d) -> K, V of (b, kv_pairs, s, pair_dim)."""
+        dt = x.dtype
+        k = x @ params["wk"].astype(dt) + params["bk"].astype(dt)
+        v = x @ params["wv"].astype(dt) + params["bv"].astype(dt)
+        return self._pairs(k, self.kv_pairs), self._pairs(v, self.kv_pairs)
+
+    def _halves(self, q):
+        """(b, k, g, m, 2d) -> (b, k, 2g, m, 2d): [q1 | 0] then [0 | q2]
+        of every pair, so row c of the new axis is softmax c + 1."""
+        first = (jnp.arange(self.pair_dim) < self.head_dim).astype(q.dtype)
+        both = jnp.stack([q * first, q * (1 - first)], axis=3)
+        return both.reshape(q.shape[0], q.shape[1], -1, *q.shape[3:])
+
+    def _scores_softmax_v(self, q2, k, v, live):
+        """q2 (b, k, r, m, 2d) against k, v (b, k, S, 2d) under ``live``
+        (broadcastable to (b, k, 1, m, S)): float32 softmax, operands in
+        the activations' dtype. -> (b, k, r, m, 2d) float32."""
+        s = jnp.einsum("bkrmd,bksd->bkrms", q2, k.astype(q2.dtype),
+                       preferred_element_type=jnp.float32)
+        s = jnp.where(live, s / math.sqrt(self.head_dim), _NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("bkrms,bksd->bkrmd", p.astype(q2.dtype),
+                          v.astype(q2.dtype),
+                          preferred_element_type=jnp.float32)
+
+    def _difference(self, params, a, dt):
+        """a (b, k, 2g, m, 2d) float32, the two softmaxes' outputs ->
+        (b, m, d_model): difference, sub-norm, merge, output projection."""
+        b, k, _, m, d2 = a.shape
+        a = a.reshape(b, k, self.group, 2, m, d2)
+        f32 = lambda n: params[n].astype(jnp.float32)
+        lam = (jnp.exp(jnp.sum(f32("lq1") * f32("lk1")))
+               - jnp.exp(jnp.sum(f32("lq2") * f32("lk2"))) + self.lam0)
+        o = a[:, :, :, 0] - lam * a[:, :, :, 1]
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                              + self.eps)
+        o = o * params["ln_sub"]["weight"].astype(jnp.float32)
+        o = (o * (1.0 - self.lam0)).astype(dt)
+        o = o.transpose(0, 3, 1, 2, 4).reshape(b, m, self.d_model)
+        return o @ params["wo"].astype(dt) + params["bo"].astype(dt)
+
+    def _banded(self, q2, k, v):
+        """Window attention over a sequence longer than the window, in
+        blocks of ``window`` queries against their own block of keys and
+        the one before: O(s x window) scores."""
+        w = self.window
+        b, kp, r, s, d2 = q2.shape
+        pad = -s % w
+        if pad:
+            q2 = jnp.pad(q2, ((0, 0),) * 3 + ((0, pad), (0, 0)))
+            k, v = (jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                    for t in (k, v))
+        nb = (s + pad) // w
+
+        def two_blocks(t):  # (b, k, s, d) -> (b, k * nb, 2w, d)
+            t = t.reshape(b, kp, nb, w, d2)
+            prev = jnp.concatenate([jnp.zeros_like(t[:, :, :1]),
+                                    t[:, :, :-1]], axis=2)
+            return jnp.concatenate([prev, t], axis=3).reshape(
+                b, kp * nb, 2 * w, d2)
+
+        qb = q2.reshape(b, kp, r, nb, w, d2).transpose(0, 1, 3, 2, 4, 5)
+        qb = qb.reshape(b, kp * nb, r, w, d2)
+        i = jnp.arange(w)[:, None]
+        j = jnp.arange(2 * w)[None, :]
+        # key j of the pair of blocks sits at w * (n - 1) + j, query i at
+        # w * n + i: seen iff i - w < key <= i, and no block precedes n = 0
+        live = (j > i) & (j <= i + w)
+        live = live[None] & ((jnp.arange(nb) > 0)[:, None, None] | (j >= w))
+        live = jnp.broadcast_to(live[None], (kp, nb, w, 2 * w)).reshape(
+            1, kp * nb, 1, w, 2 * w)
+        a = self._scores_softmax_v(qb, two_blocks(k), two_blocks(v), live)
+        a = a.reshape(b, kp, nb, r, w, d2).transpose(0, 1, 3, 2, 4, 5)
+        return a.reshape(b, kp, r, nb * w, d2)[:, :, :, :s]
+
+    def attend_seq(self, params, x, k, v):
+        """Causal attention of every row of x (b, s, d) over K, V
+        (b, kv_pairs, s, pair_dim) of the same positions."""
+        q2 = self._halves(self.project_q(params, x))
+        s = x.shape[1]
+        if self.window and s > self.window:
+            a = self._banded(q2, k, v)
+        elif self.flash and not self.window:
+            # the kernel scales by 1/sqrt of the padded width
+            from bigdl_tpu.ops import flash_attention
+            b, kp, r, _, d2 = q2.shape
+            qf = (q2 * math.sqrt(2.0)).astype(q2.dtype).reshape(
+                b, kp * r, s, d2)
+            a = flash_attention(qf, jnp.repeat(k, r, axis=1),
+                                jnp.repeat(v, r, axis=1), causal=True)
+            a = a.reshape(b, kp, r, s, d2).astype(jnp.float32)
+        else:
+            i = jnp.arange(s)[:, None]
+            j = jnp.arange(s)[None, :]
+            live = j <= i
+            if self.window:
+                live &= j > i - self.window
+            a = self._scores_softmax_v(q2, k, v, live)
+        return self._difference(params, a, x.dtype)
+
+    def _forward(self, params, x, *, training, rng):
+        # a tensor: self-attention; (x, k, v): Q-only cross-attention
+        if isinstance(x, (tuple, list)):
+            return self.attend_seq(params, *x)
+        return self.attend_seq(params, x, *self.project_kv(params, x))
+
+    # ----------------------------------------------- autoregressive decode
+    def prefill(self, params, x, cache, last=None):
+        """Whole-prompt forward that also fills the slot's cache. A full
+        layer's K/V go to rows 0..s-1 (rows after ``last`` hold a padded
+        bucket's garbage, which decode overwrites before it attends
+        them). A window layer's ring gets the last ``window`` real rows,
+        position p at row p % window: row r holds the largest position
+        <= ``last`` (traced; default s-1) that is congruent to r.
+        Returns (out, cache, (k, v))."""
+        k, v = self.project_kv(params, x)
+        out = self.attend_seq(params, x, k, v)
+        s = x.shape[1]
+        if self.window:
+            w = self.window
+            last = s - 1 if last is None else last
+            r = jnp.arange(w)
+            src = jnp.clip(last - (last - r) % w, 0, s - 1)
+            new = {n: jnp.take(t, src, axis=2).astype(cache[n].dtype)
+                   for n, t in (("k", k), ("v", v))}
+        else:
+            new = {n: jax.lax.dynamic_update_slice(
+                       cache[n], t.astype(cache[n].dtype), (0, 0, 0, 0))
+                   for n, t in (("k", k), ("v", v))}
+        return out, new, (k, v)
+
+    def decode_step(self, params, x, cache, idx):
+        """One token x (b, 1, d) at absolute position ``idx`` (traced):
+        writes its K/V (at ``idx % window`` of a ring) and attends over
+        what is live: positions 0..idx, or the ring's min(idx + 1,
+        window) valid rows. A cross layer writes nothing and reads the
+        cache it is given."""
+        if self.cross:
+            new = cache
+        else:
+            k, v = self.project_kv(params, x)
+            at = idx % self.window if self.window else idx
+            new = {n: jax.lax.dynamic_update_slice(
+                       cache[n], t.astype(cache[n].dtype), (0, 0, at, 0))
+                   for n, t in (("k", k), ("v", v))}
+        rows = jnp.arange(new["k"].shape[2])
+        live = rows < jnp.minimum(idx + 1, rows.shape[0]) if self.window \
+            else rows <= idx
+        q2 = self._halves(self.project_q(params, x))
+        a = self._scores_softmax_v(q2, new["k"], new["v"], live)
+        return self._difference(params, a, x.dtype), new
+
+
+__all__.append("DifferentialAttention")

@@ -1,5 +1,19 @@
-"""Incremental generation for ``transformer_lm`` with a preallocated
-KV cache and continuous-batching slots.
+"""Incremental generation for the LMs (``transformer_lm``, ``sambay_lm``)
+with a preallocated cache and continuous-batching slots.
+
+What a slot may hold is the model's to say: the engine asks it for
+``init_cache(batch, max_len, dtype)`` and ``prompt_buckets(max_len,
+dtype)`` and treats the cache as a pytree whose leaves all have the slot
+as their first axis. ``TransformerLM`` answers with K and V of ``max_len``
+rows a layer. ``SambaYLM`` answers with four kinds of leaf side by side: a
+ring of ``window`` K/V rows for each window layer, ``max_len`` K/V rows
+for its one shared layer, and for each state-space layer a float32 scan
+state and the convolution's last rows. A prefill hands over a whole slot
+(``_write_slot``), so a reused slot keeps nothing of the last request, and
+the model's ``prefill_logits`` stops every leaf at the prompt's last real
+token whatever the bucket's padding. Page pools, the prefix cache,
+speculation, kv8 and tp placement below assume per-layer K/V rows: for a
+model that declares ``recurrent_state`` the engine refuses them.
 
 ``TransformerLM.generate`` is the offline shape of decoding: one request,
 one fori_loop, prompt and token budget baked into the compile. An online
@@ -161,6 +175,29 @@ class DecodeEngine:
         self._worker_error: Optional[BaseException] = None
         self._last_beat = self.clock()
         self.model = model
+        if getattr(model, "recurrent_state", False):
+            # a slot of such a model holds a scan state and a ring beside
+            # its K/V rows; only the dense slab carries those
+            missing = [why for on, why in (
+                (kv_page_tokens, "kv_page_tokens: page pools hold "
+                                 "per-layer K/V rows only "
+                                 "(serving/kv_pages.py)"),
+                (prefix_cache, "prefix_cache: a shared prefix is a page "
+                               "copy, and the state after the prefix is "
+                               "in no page (serving/prefix_cache.py)"),
+                (speculate, "speculate: a rejected draft token cannot "
+                            "be taken out of a scan state "
+                            "(serving/spec_decode.py)"),
+                (quantize not in (None, "off"),
+                 "quantize: no 8-bit form of the state-space weights or "
+                 "of the state (serving/quant.py)"),
+                (mesh is not None, "mesh: no tp layout for the scan "
+                                   "(serving/sharding.py)")) if on]
+            if missing:
+                raise ValueError(
+                    f"{type(model).__name__} keeps recurrent state in its "
+                    "slots and serves on the dense path only; not "
+                    "supported yet: " + "; ".join(missing))
         # ---- quantized serving (ISSUE 17): weights go 8-bit BEFORE tp
         # placement so each scale vector ships to the mesh alongside its
         # weight (column-split weight -> split scale). Idempotent: trees
@@ -192,12 +229,8 @@ class DecodeEngine:
         self._jax, self._jnp = jax, jnp
 
         if prompt_buckets is None:
-            from bigdl_tpu.ops.attention_kernel import serving_prefill_buckets
-            head_dim = getattr(
-                model.encoder._modules[0].mha, "head_dim",
-                model.d_model // 4)
-            prompt_buckets = serving_prefill_buckets(
-                self.max_len, head_dim, True, self.cache_dtype)
+            prompt_buckets = model.prompt_buckets(self.max_len,
+                                                  self.cache_dtype)
         self.prompt_buckets = tuple(sorted(set(int(b)
                                                for b in prompt_buckets)))
 
@@ -235,7 +268,7 @@ class DecodeEngine:
             self._cache = None
         else:
             self._kv = None
-            self._cache = model.encoder.init_cache(
+            self._cache = model.init_cache(
                 self.slots, self.max_len, self.cache_dtype)
             if self._shard is not None:
                 self._cache = self._shard.place_kv(self._cache)
@@ -265,7 +298,7 @@ class DecodeEngine:
                 raise ValueError("draft_model without draft_params")
             self._draft_dtype = (self.draft_model.compute_dtype
                                  or jnp.float32)
-            self._draft_cache = self.draft_model.encoder.init_cache(
+            self._draft_cache = self.draft_model.init_cache(
                 self.slots, self.max_len, self._draft_dtype)
             if self._shard is not None:
                 # a distinct draft model gets its own Megatron layout
@@ -287,7 +320,7 @@ class DecodeEngine:
         self.metrics = metrics
         if metrics is None:
             self._m_tokens = self._m_steps = self._m_prefills = None
-            self._m_live_pos = None
+            self._m_live_pos = self._m_window_pos = None
             self._m_prompt_tokens = self._m_rejected = None
             self._m_bucket_tokens = None
             self._m_queued = self._m_queue_wait = None
@@ -305,6 +338,11 @@ class DecodeEngine:
             "cache positions those steps had to read: the live slots' "
             "positions before each step (over decode_steps_total, "
             "against slots x max_len: the share of the cache in use)")
+        self._m_window_pos = metrics.counter(
+            "decode_window_positions_total",
+            "ring rows those steps had to read in each window layer: the "
+            "live slots' min(position, window) before each step (0 for a "
+            "model without window layers)")
         self._m_prefills = metrics.counter(
             "prefills_total", "prompt prefills executed")
         self._m_prompt_tokens = metrics.counter(
@@ -377,6 +415,11 @@ class DecodeEngine:
             logger.info("decode KV cache: %d bytes (%d slots x max_len "
                         "%d, %s)", kv_total, self.slots, self.max_len,
                         self.cache_dtype)
+            for kind in self.cache_bytes_by_kind():
+                metrics.gauge(
+                    f"decode_cache_bytes_{kind}",
+                    f"resident bytes of the slots' {kind} leaves",
+                    fn=lambda kind=kind: self.cache_bytes_by_kind()[kind])
         if self.speculate > 0:
             self._m_spec_prop = metrics.counter(
                 "spec_proposed_total", "draft tokens proposed")
@@ -399,13 +442,21 @@ class DecodeEngine:
             self._m_draft_steps = None
 
     def kv_bytes(self) -> int:
-        """Resident KV bytes — allocated pages when paged, the dense
-        slab otherwise. Per-replica truth; the dp fleet aggregate sums
-        this across replicas (ISSUE 16 satellite)."""
+        """Resident cache bytes — allocated pages when paged, the dense
+        slab otherwise, whatever kinds of leaf it holds. Per-replica
+        truth; the dp fleet aggregate sums this across replicas (ISSUE 16
+        satellite)."""
         if self.paged:
             return self._kv.allocated_bytes()
         from bigdl_tpu.obs.memory import tree_bytes
         return tree_bytes(self._cache)
+
+    def cache_bytes_by_kind(self) -> dict:
+        """The dense slab's resident bytes by kind of leaf, as the model
+        names them: ``kv_full`` (max_len K/V rows a slot), and for a
+        model with window or state-space layers ``kv_window``,
+        ``ssm_state``, ``conv_state``. Sums to :meth:`kv_bytes`."""
+        return self.model.cache_bytes_by_kind(self._cache)
 
     def kv_pages_in_use(self) -> int:
         return self._kv.alloc.pages_in_use if self.paged else 0
@@ -446,8 +497,8 @@ class DecodeEngine:
         shard = self._shard
         if shard is not None:
             cache1_abs = jax.eval_shape(
-                lambda: model.encoder.init_cache(1, self.max_len,
-                                                 self.cache_dtype))
+                lambda: model.init_cache(1, self.max_len,
+                                         self.cache_dtype))
             self._cache1_sh = shard.kv_shardings(cache1_abs)
             self._state_sh = (self._kv.pool_shardings if self.paged
                               else shard.kv_shardings(self._cache))
@@ -460,8 +511,8 @@ class DecodeEngine:
 
         def _prefill(params, tokens, last):
             # tokens (1, bucket) int32; last = true_len - 1 (traced)
-            cache = model.encoder.init_cache(1, self.max_len,
-                                             self.cache_dtype)
+            cache = model.init_cache(1, self.max_len,
+                                     self.cache_dtype)
             logits, cache = model.prefill_logits(params, tokens, cache,
                                                  last)
             return logits[0].astype(jnp.float32), cache
@@ -979,7 +1030,7 @@ class DecodeEngine:
             dmodel, ddtype = self.draft_model, self._draft_dtype
 
             def _dprefill(dparams, tokens, last):
-                cache = dmodel.encoder.init_cache(1, self.max_len, ddtype)
+                cache = dmodel.init_cache(1, self.max_len, ddtype)
                 _, cache = dmodel.prefill_logits(dparams, tokens, cache,
                                                  last)
                 return cache
@@ -987,8 +1038,7 @@ class DecodeEngine:
             pin = {}
             if self._shard is not None:
                 dcache1_abs = jax.eval_shape(
-                    lambda: dmodel.encoder.init_cache(1, self.max_len,
-                                                      ddtype))
+                    lambda: dmodel.init_cache(1, self.max_len, ddtype))
                 pin = self._pin(self._shard.kv_shardings(dcache1_abs))
             self._draft_prefill_jit = jax.jit(_dprefill, **pin)
         cache1 = self._draft_prefill_jit(
@@ -1158,6 +1208,10 @@ class DecodeEngine:
         if self._m_steps is not None:
             self._m_steps.inc()
             self._m_live_pos.inc(int(self._pos[active].sum()))
+            window = getattr(self.model, "window", None)
+            if window:
+                self._m_window_pos.inc(
+                    int(np.minimum(self._pos[active], window).sum()))
 
     def _step_plain(self, active) -> int:
         jnp = self._jnp
@@ -1310,7 +1364,9 @@ class DecodeEngine:
                    "worker_up": self._worker_error is None,
                    "tp": self._shard.n_shard if self._shard else 1,
                    "kv": {"paged": self.paged}}
-            if self.paged:
+            if not self.paged:
+                out["kv"]["bytes_by_kind"] = self.cache_bytes_by_kind()
+            else:
                 out["kv"].update({
                     "page_tokens": self.page_tokens,
                     "pool_pages": self._kv.pool_pages,
@@ -1513,8 +1569,8 @@ def abstract_decode_engine(model, *, slots: int = 4,
     else:
         eng._kv = None
         eng._cache = jax.eval_shape(
-            lambda: model.encoder.init_cache(eng.slots, eng.max_len,
-                                             eng.cache_dtype))
+            lambda: model.init_cache(eng.slots, eng.max_len,
+                                     eng.cache_dtype))
 
     shard = eng._shard
     if shard is not None:
