@@ -122,6 +122,12 @@ class DecodeRequest:
         self.t_queued = None  # engine clock when it joined the queue
 
 
+# A plain step the device has been handed and the host has not read:
+# the device array of its tokens, and the (slot, request, position) of
+# every slot it advanced.
+_Flight = collections.namedtuple("_Flight", "toks advanced")
+
+
 class DecodeEngine:
     """Continuous-batching KV-cache decoder over a fixed slot count.
 
@@ -135,6 +141,29 @@ class DecodeEngine:
     speculative. Without a worker thread the caller drives ``step``
     (tests, ``generate``); ``start()`` launches the decode loop for the
     HTTP server.
+
+    A plain step is two halves: a **dispatch** (``_dispatch``: hand the
+    device the step, advance the host's positions, count it) and a
+    **retire** (``_retire``: read its tokens, emit, finish, hand off).
+    The step program samples on the device from the logits it carries,
+    runs every slot whether live or not, and keys its randomness by
+    ``(seed, position)``, so nothing on the device waits for the host's
+    read. ``step()`` dispatches and retires the same step and leaves
+    nothing in flight. The decode loop keeps one step in flight
+    (``_flight``): under the lock it dispatches step n+1, then retires
+    step n, so the device runs while the host emits. A dispatch advances
+    a slot only while its request's budget is not covered by the tokens
+    emitted plus the one in flight; any other slot runs, as a free slot
+    does, at a position it may write junk to (its last one, or 0) with
+    its output dropped. A retire emits a token only if the slot still
+    holds the request the step advanced: one that ended meanwhile (stop
+    token, ``cancel``, deadline) has its run-ahead token dropped
+    (``decode_dropped_tokens_total``), and what that step wrote lands in
+    rows the slot's next owner overwrites, since every later install is
+    behind it in the device's queue. The tokens are those of the
+    one-step-deep engine, bit for bit. The speculative round needs its
+    accepted counts on the host before it can draft again and stays
+    synchronous.
 
     * ``kv_page_tokens`` — page size in tokens; None keeps the dense
       layout. Must divide ``max_len``. ``pool_pages`` overrides the
@@ -286,6 +315,7 @@ class DecodeEngine:
         self._topp = np.ones(self.slots, np.float32)
         self._seed = np.zeros(self.slots, np.uint32)
         self._pending = np.zeros(self.slots, np.int32)  # speculative only
+        self._flight: Optional[_Flight] = None  # the decode loop's alone
         self._thread = None
         self._closed = False
 
@@ -321,6 +351,7 @@ class DecodeEngine:
         if metrics is None:
             self._m_tokens = self._m_steps = self._m_prefills = None
             self._m_live_pos = self._m_window_pos = None
+            self._m_runahead = self._m_dropped = None
             self._m_prompt_tokens = self._m_rejected = None
             self._m_bucket_tokens = None
             self._m_queued = self._m_queue_wait = None
@@ -343,6 +374,15 @@ class DecodeEngine:
             "ring rows those steps had to read in each window layer: the "
             "live slots' min(position, window) before each step (0 for a "
             "model without window layers)")
+        self._m_runahead = metrics.counter(
+            "decode_runahead_steps_total",
+            "of those steps, the ones dispatched while an earlier step's "
+            "tokens were still unread (over decode_steps_total: how often "
+            "the decode loop runs ahead of its own emit)")
+        self._m_dropped = metrics.counter(
+            "decode_dropped_tokens_total",
+            "tokens a run-ahead step computed for a slot whose request "
+            "had ended by the time the step was retired, and dropped")
         self._m_prefills = metrics.counter(
             "prefills_total", "prompt prefills executed")
         self._m_prompt_tokens = metrics.counter(
@@ -1047,12 +1087,13 @@ class DecodeEngine:
                                              jnp.int32(slot))
 
     # ------------------------------------------------------------- emission
-    def _emit(self, req, slot: int, toks, accepted=None) -> bool:
+    def _emit(self, req, slot: int, toks, accepted=None, pos=None) -> bool:
         """Append generated tokens to ``req`` (respecting stop token and
         max_new budget), resolve + hand off if finished. ``accepted`` is
         the speculative draft tokens the verify kept this round (None on
-        the plain path). Returns True if the request completed. Lock
-        held."""
+        the plain path); ``pos`` the slot's position after these tokens,
+        where the host's own may already be a step further (default: the
+        host's). Returns True if the request completed. Lock held."""
         done = False
         emitted = 0
         for tok in toks:
@@ -1071,7 +1112,7 @@ class DecodeEngine:
                 req.rid, emitted, accepted=accepted,
                 pages=(len(self._kv.slot_pages[slot])
                        if self.paged else None),
-                pos=int(self._pos[slot]))
+                pos=int(self._pos[slot] if pos is None else pos))
         if req.emit is not None and emitted:
             # streaming sink (ISSUE 18): hand the round's accepted
             # tokens to the HTTP handler's queue. A broken sink must
@@ -1139,13 +1180,21 @@ class DecodeEngine:
         shutdown freed slots early).
 
         Returns True iff a request was found and cancelled. Safe against
-        the speculative verify/accept race: ``step()`` holds the engine
-        lock for the ENTIRE round (draft feeds, the chunked verify
-        dispatch, acceptance, and emission), so a cancel landing between
-        a verify dispatch and its accept simply waits for the round to
-        retire — it can never free pages the in-flight verify is still
-        writing, and a stale ``_pending`` feed is reset by the next
-        ``_install`` into that slot."""
+        the speculative verify/accept race: a round holds the engine
+        lock from its first dispatch to its last emission (draft feeds,
+        the chunked verify dispatch, acceptance, and emission), so a
+        cancel landing between a verify dispatch and its accept simply
+        waits for the round to retire — it can never free pages the
+        in-flight verify is still writing, and a stale ``_pending`` feed
+        is reset by the next ``_install`` into that slot. A plain step
+        the decode loop has in flight is another matter: the cancel lands
+        between two rounds while the device still runs (or has queued) a
+        step that advanced this slot. Nothing waits for it. The slot is
+        freed and handed on at once; the step's write goes to a row the
+        next owner's prefill, queued behind it on the device, overwrites
+        (dense: the whole slot; paged: the freed page, or the null page),
+        and its token is dropped at retire because the slot no longer
+        holds the request it was computed for."""
         if rid is None:
             return False
         err = RuntimeError(f"request {rid} cancelled: {reason}")
@@ -1178,24 +1227,37 @@ class DecodeEngine:
     def step(self) -> int:
         """One batched decode step: every active slot emits one token
         (plain) or up to ``speculate+1`` tokens (speculative round).
-        Returns the number of active slots advanced (0 = idle). Finished
-        requests resolve their futures and hand their slot to the next
-        waiting request; expired ones are dropped before compute."""
+        Returns the number of active slots (0 = idle). Finished requests
+        resolve their futures and hand their slot to the next waiting
+        request; expired ones are dropped before compute. One step deep:
+        the step it dispatches is the step it retires, and nothing is in
+        flight when it returns."""
+        return self._round(ahead=False)
+
+    def _round(self, ahead: bool) -> int:
+        """One round under the engine lock. ``ahead``: leave the plain
+        step this round dispatches in flight and retire the one the round
+        before left (the decode loop); else retire what it dispatched."""
         with self._locked("decode_lock_wait"):
             self._last_beat = self.clock()
             self._expire(self.clock())
             active = [i for i, r in enumerate(self._reqs)
                       if r is not None]
-            if not active:
-                return 0
-            with _obs_span("decode_round", active=len(active)):
-                if self.speculate > 0:
-                    return self._step_spec(active)
-                return self._step_plain(active)
+            if active:
+                with _obs_span("decode_round", active=len(active)):
+                    if self.speculate > 0:
+                        return self._step_spec(active)
+                    self._step_plain(active, ahead)
+            if self._flight is not None and not any(
+                    r is not None for r in self._reqs):
+                # every request the step in flight advanced has ended
+                self._drop_flight()
+            return len(active)
 
-    def _sampling_args(self):
+    def _sampling_args(self, pos=None):
         jnp = self._jnp
-        return (jnp.asarray(self._pos), jnp.asarray(self._temp),
+        return (jnp.asarray(self._pos if pos is None else pos),
+                jnp.asarray(self._temp),
                 jnp.asarray(self._topk), jnp.asarray(self._topp),
                 jnp.asarray(self._seed))
 
@@ -1204,7 +1266,7 @@ class DecodeEngine:
                    for i in active)
 
     def _count_step(self, active) -> None:
-        # before the emit loop advances the positions
+        # at dispatch, before the positions advance
         if self._m_steps is not None:
             self._m_steps.inc()
             self._m_live_pos.inc(int(self._pos[active].sum()))
@@ -1213,38 +1275,89 @@ class DecodeEngine:
                 self._m_window_pos.inc(
                     int(np.minimum(self._pos[active], window).sum()))
 
-    def _step_plain(self, active) -> int:
-        jnp = self._jnp
+    def _step_plain(self, active, ahead: bool) -> None:
+        """Dispatch a step, then retire one: the same step, or with
+        ``ahead`` the step the round before left in flight, so that the
+        device runs this round's step while the host emits the last
+        one's tokens."""
         with _obs_span("decode_args"):
-            prog = self._get_step(self._needs_warp(active))
-            pos, temp, topk, topp, seed = self._sampling_args()
-        with _obs_span("decode_step", active=len(active)):
-            try:
-                if self.paged:
-                    toks, self._logits, self._kv.pools = prog(
-                        self.params, self._logits, self._kv.pools,
-                        jnp.asarray(self._kv.page_table), pos, temp,
-                        topk, topp, seed)
-                else:
-                    toks, self._logits, self._cache = prog(
-                        self.params, self._logits, self._cache, pos,
-                        temp, topk, topp, seed)
-            except Exception as e:
-                # RESOURCE_EXHAUSTED autopsy (ISSUE 12): the KV cache is
-                # usually the culprit — report to --traceDir + fault
-                # log, then die as before
-                from bigdl_tpu.obs import memory as _obs_mem
-                _obs_mem.handle_oom(e, "decode_step")
-                raise
-            with _obs_span("decode_host_read"):
-                toks_host = np.asarray(toks)
-        self._count_step(active)
-        with _obs_span("decode_emit"):
+            # a slot whose budget the token in flight already covers is
+            # run like a free slot; the host knows without reading
+            unread = ({slot for slot, req, _ in self._flight.advanced
+                       if self._reqs[slot] is req}
+                      if self._flight is not None else ())
+            advance, held = [], []
             for i in active:
                 req = self._reqs[i]
-                self._pos[i] += 1
-                self._emit(req, i, [int(toks_host[i])])
-        return len(active)
+                (advance if len(req.out) + (i in unread)
+                 < req.max_new_tokens else held).append(i)
+            args = self._step_args(advance, held) if advance else None
+        with _obs_span("decode_step", active=len(advance)):
+            flight = self._dispatch(advance, *args) if advance else None
+            if ahead:
+                flight, self._flight = self._flight, flight
+            if flight is None:  # the loop's first round after idling
+                return
+            with _obs_span("decode_host_read"):
+                toks_host = np.asarray(flight.toks)
+        with _obs_span("decode_emit"):
+            self._retire(flight, toks_host)
+
+    def _step_args(self, advance, held):
+        """The program and the five sampling arrays of a step that
+        advances ``advance``. A live slot that is not advanced (``held``)
+        runs at its last position, a free one at 0: neither is ever run
+        past its ``prompt + max_new - 1``."""
+        pos = self._pos.copy()
+        pos[held] -= 1
+        return (self._get_step(self._needs_warp(advance)),
+                *self._sampling_args(pos))
+
+    def _dispatch(self, advance, prog, pos, temp, topk, topp,
+                  seed) -> _Flight:
+        """Hand the device one plain step and advance the host's
+        positions; the host reads nothing."""
+        jnp = self._jnp
+        try:
+            if self.paged:
+                toks, self._logits, self._kv.pools = prog(
+                    self.params, self._logits, self._kv.pools,
+                    jnp.asarray(self._kv.page_table), pos, temp,
+                    topk, topp, seed)
+            else:
+                toks, self._logits, self._cache = prog(
+                    self.params, self._logits, self._cache, pos,
+                    temp, topk, topp, seed)
+        except Exception as e:
+            # RESOURCE_EXHAUSTED autopsy (ISSUE 12): the KV cache is
+            # usually the culprit — report to --traceDir + fault
+            # log, then die as before
+            from bigdl_tpu.obs import memory as _obs_mem
+            _obs_mem.handle_oom(e, "decode_step")
+            raise
+        self._count_step(advance)
+        if self._flight is not None and self._m_runahead is not None:
+            self._m_runahead.inc()
+        advanced = [(i, self._reqs[i], int(self._pos[i])) for i in advance]
+        self._pos[advance] += 1
+        return _Flight(toks, advanced)
+
+    def _retire(self, flight: _Flight, toks_host) -> None:
+        """Emit a dispatched step's tokens, each to the request it was
+        computed for if the slot still holds it."""
+        for slot, req, pos in flight.advanced:
+            if self._reqs[slot] is req:
+                self._emit(req, slot, [int(toks_host[slot])], pos=pos + 1)
+            elif self._m_dropped is not None:
+                self._m_dropped.inc()
+
+    def _drop_flight(self) -> None:
+        """Forget the step in flight without reading it (lock held):
+        its tokens are dropped and counted, and what it writes goes where
+        any slot's next owner overwrites."""
+        flight, self._flight = self._flight, None
+        if flight is not None and self._m_dropped is not None:
+            self._m_dropped.inc(len(flight.advanced))
 
     def _step_spec(self, active) -> int:
         """One speculative round: m-1 draft proposals + the sync feed,
@@ -1411,6 +1524,7 @@ class DecodeEngine:
                 if req is not None:
                     self._release_slot(i)
                     dead.append(req)
+            self._drop_flight()
             self._work.notify_all()
         err = (exc if isinstance(exc, WorkerDied)
                else WorkerDied(f"decode worker died: {exc}"))
@@ -1439,7 +1553,9 @@ class DecodeEngine:
                             self._last_beat = self.clock()
                         if self._closed:
                             return
-                    self.step()
+                    # one plain step ahead of the emit; a speculative
+                    # round needs its accepted counts before the next
+                    self._round(ahead=self.speculate == 0)
             except BaseException as e:
                 # the loop is the only thing advancing decode: record
                 # the cause, fail every waiter, fast-fail future submits
@@ -1466,6 +1582,7 @@ class DecodeEngine:
                         RuntimeError("decode engine closed mid-request"))
                     if rt is not None and req.rid is not None:
                         rt.finish(req.rid, "closed")
+            self._drop_flight()
             self._work.notify_all()
         t, self._thread = self._thread, None
         if t is not None:
