@@ -780,7 +780,7 @@ def run_memory_rules(plan: Optional[dict],
             f"HBM {hbm / 2**30:.1f} GiB ({frac * 100:.0f}%) — top: "
             f"{top_s}",
             where=where,
-            hint="shrink the batch (bigdl-tpu explain --mem predicts "
+            hint="shrink the batch (bigdl-tpu explain <model> predicts "
                  "the max that fits), drop --optim momentum state, or "
                  "shard the model (--strategy tp)",
             detail={"total_bytes": total, "hbm_bytes": hbm,
@@ -794,7 +794,7 @@ def run_memory_rules(plan: Optional[dict],
             f"(threshold {HBM_WARN_FRAC * 100:.0f}%) — top: {top_s}",
             where=where,
             hint="headroom this thin ooms on fragmentation; "
-                 "bigdl-tpu explain --mem forecasts the fit per batch",
+                 "bigdl-tpu explain <model> forecasts the fit per batch",
             detail={"total_bytes": total, "hbm_bytes": hbm,
                     "frac": round(frac, 4)}))
     return report

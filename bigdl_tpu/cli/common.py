@@ -135,8 +135,12 @@ def add_obs_args(p: argparse.ArgumentParser) -> None:
                         "--obs")
     p.add_argument("--traceSteps", default=None, metavar="N@M",
                    help="capture a jax.profiler trace of steps M..M+N-1 "
-                        "mid-run into --traceDir (verified parseable "
-                        "with utils/xplane on close). Independently, "
+                        "mid-run into --traceDir/capture_<M>. On close "
+                        "the capture is verified (its .xplane.pb parses "
+                        "with utils/xplane) and its record says where "
+                        "it is; it is not reduced to numbers here: open "
+                        "it in XProf or Perfetto (the program's spans "
+                        "are in it as bigdl:<name>). Independently, "
                         "SIGUSR2 or `touch DIR/CAPTURE` opens a bounded "
                         "window on a run already in flight")
     p.add_argument("--metricsPort", type=int, default=None, metavar="PORT",
@@ -575,8 +579,8 @@ def setup_logging() -> None:
 
 
 # the --strategy surface (ISSUE 8): the five parallelism families the
-# MULTICHIP_r05 dryruns validate, now reachable from perf/bench/training
-# instead of living only in __graft_entry__.py
+# __graft_entry__.py dryruns validate, reachable from perf and the
+# training CLIs instead of living only there
 STRATEGY_CHOICES = ("dp", "tp", "sp", "pp", "ep")
 
 
@@ -591,7 +595,7 @@ def add_strategy_arg(p: argparse.ArgumentParser) -> None:
                         "(transformer_lm* models), ep = expert-parallel "
                         "MoE. Optional :K sizes the non-data axis (e.g. "
                         "tp:4 = 4-way model parallel, pp:2 = 2 stages); "
-                        "defaults mirror the MULTICHIP_r05 dryrun "
+                        "defaults mirror __graft_entry__.py's dryrun "
                         "shapes. CPU-testable end to end with XLA_FLAGS="
                         "--xla_force_host_platform_device_count=8. "
                         "Replaces the deprecated --dataParallel "
@@ -650,8 +654,9 @@ def check_strategy_dispatch(steps: int, flag: str = "--stepsPerDispatch"):
 
 def strategy_mesh_axes(name: str, n_devices: int, k: Optional[int] = None
                        ) -> dict:
-    """Axis layout of one strategy over ``n_devices`` (the MULTICHIP_r05
-    dryrun shapes). ``k`` sizes the non-data axis; defaults: tp/sp split
+    """Axis layout of one strategy over ``n_devices`` (the shapes of
+    ``__graft_entry__.py``'s dryrun). ``k`` sizes the non-data axis;
+    defaults: tp/sp split
     devices 2-way on data (n>=4), pp uses 4 stages (n%4==0) else 2, ep
     puts every device on the expert axis."""
     n = int(n_devices)

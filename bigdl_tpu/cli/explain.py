@@ -1,25 +1,18 @@
-"""`bigdl-tpu explain` — turn a profile into an explanation (ISSUE 8).
+"""`bigdl-tpu explain` — a model's HBM plan (ISSUE 12).
 
-    bigdl-tpu explain /tmp/obs/capture_4            # a capture window
-    bigdl-tpu explain /tmp/xp --steps 5 --gflops 94 # any profiler dir
-    bigdl-tpu explain resnet50 -b 32 -i 5           # run + explain
+    bigdl-tpu explain resnet50 -b 32
+    bigdl-tpu explain transformer_lm --quantize int8+kv8   # slot forecast
 
-The target is either a ``jax.profiler`` output directory (a perf
-``--profile`` dir or an obs ``capture_<step>`` window) or a perf-zoo
-model name — the latter runs a short profiled throughput loop first
-(``cli/perf.py``), then attributes its own trace with the run's analytic
-FLOPs numerator and mesh peak, so the table carries FLOP share and
-roofline utilization, not just times. Output: the per-category table
-(``utils/table``) with the collective breakout and MFU decomposition,
-or ``--json`` (one line, printed last — ``tail -1`` safe).
+No run: the training step of a perf-zoo model is lowered+compiled at two
+batch sizes, the per-category byte plan of the exact step is rendered
+(totalling to ``compiled.memory_analysis()``), and the linear per-sample
+fit predicts the max batch that still fits the device HBM. ``--json``
+prints the same as one line.
 
-``--mem`` (ISSUE 12) is the memory twin for a model target: no run —
-the training step is lowered+compiled at two batch sizes, the
-per-category byte plan of the exact step is rendered (totalling to
-``compiled.memory_analysis()``), and the linear per-sample fit predicts
-the max batch that still fits the device HBM:
-
-    bigdl-tpu explain --mem resnet50 -b 32
+The command explains memory and nothing else. A capture directory
+(``--traceSteps``, SIGUSR2, ``perf --profile``) is a ``jax.profiler``
+directory: open it in XProf or Perfetto. The one reducer of a device
+trace whose numbers the ledger holds is ``benchmark/lib/trace.py``.
 """
 
 from __future__ import annotations
@@ -27,157 +20,79 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import sys
 
 
 def main(argv=None):
     p = argparse.ArgumentParser(
         "bigdl-tpu explain",
-        description="classify every device op of a profile into the "
-                    "PERF.md §16 taxonomy (matmul/conv/bn_norm/"
-                    "attention/elementwise/collective/infeed/host_other)"
-                    " with per-collective subtotals and an MFU "
-                    "decomposition")
-    p.add_argument("target",
-                   help="jax.profiler trace dir (e.g. an obs "
-                        "capture_<step> window) OR a perf model name "
-                        "(runs a short profiled loop first)")
+        description="per-category HBM plan of a model's compiled "
+                    "training step, headroom against the device "
+                    "capacity, and the predicted max batch from a "
+                    "two-point per-sample fit — no training run")
+    p.add_argument("target", help="a perf model name")
     p.add_argument("--json", action="store_true",
                    help="machine output (one JSON line, printed last)")
-    p.add_argument("--mem", action="store_true",
-                   help="memory mode (model target only): per-category "
-                        "HBM plan of the compiled training step, "
-                        "headroom against the device capacity, and the "
-                        "predicted max batch from a two-point "
-                        "per-sample fit — no training run")
     p.add_argument("-b", "--batchSize", type=int, default=16,
-                   help="batch for model-mode runs")
-    p.add_argument("-i", "--iteration", type=int, default=5,
-                   help="timed steps for model-mode runs (= the step "
-                        "count the attribution divides by)")
-    p.add_argument("--steps", type=int, default=None,
-                   help="step count of a profile-dir target (enables "
-                        "ms/step and the per-step collective column)")
-    p.add_argument("--gflops", type=float, default=None,
-                   help="analytic step GFLOPs of the profiled run "
-                        "(perf JSON's step_gflops_analytic) — enables "
-                        "FLOP share / utilization for a profile-dir "
-                        "target")
-    p.add_argument("--gflopsConv", type=float, default=None,
-                   help="conv share of --gflops (perf JSON's "
-                        "step_gflops_by_kind.conv); rest is matmul")
-    p.add_argument("--peak", type=float, default=None,
-                   help="whole-mesh peak FLOP/s for the roofline join "
-                        "(perf JSON's peak_flops_assumed x n_devices)")
-    p.add_argument("--top", type=int, default=3,
-                   help="top ops listed per category")
+                   help="batch the plan is made for (and twice it, for "
+                        "the fit)")
     p.add_argument("--seq", type=int, default=None,
-                   help="transformer_lm* sequence override (model mode)")
+                   help="transformer_lm* sequence override")
     p.add_argument("--quantize", default=None,
                    choices=("off", "int8", "fp8", "kv8", "int8+kv8",
                             "fp8+kv8"),
-                   help="with --mem on a transformer_lm target: account "
-                        "the serving KV cache and weights under this "
+                   help="on a transformer_lm target: account the "
+                        "serving KV cache and weights under this "
                         "quantize mode (ISSUE 17) and re-fit the "
                         "max-slot forecast — kv8 roughly quarters the "
                         "per-slot bytes, so ~2x the slots fit")
-    from bigdl_tpu.cli.common import (_add_platform_arg, add_strategy_arg,
-                                      apply_platform)
+    from bigdl_tpu.cli.common import _add_platform_arg, apply_platform
     _add_platform_arg(p)
-    add_strategy_arg(p)
     args = p.parse_args(argv)
+    if os.path.isdir(args.target):
+        print(f"bigdl-tpu explain: {args.target} is a directory; explain "
+              "takes a perf model name and prints its HBM plan. A capture "
+              "directory is a jax.profiler directory: open it in XProf or "
+              "Perfetto (the reducer the ledger uses is "
+              "benchmark/lib/trace.py)", file=sys.stderr)
+        return 2
     apply_platform(args)
 
-    from bigdl_tpu.obs import attrib
-
-    if args.mem:
-        # memory mode (ISSUE 12): two abstract plans -> category table
-        # + headroom + predicted max batch; no timed run
-        if os.path.isdir(args.target):
-            raise SystemExit(
-                "--mem explains a MODEL's memory plan (it compiles the "
-                "step); pass a perf model name, not a profile dir")
-        from bigdl_tpu.obs import memory
-        b = args.batchSize
-        plan = memory.plan_for_model(args.target, b, seq_len=args.seq)
-        plan2 = memory.plan_for_model(args.target, 2 * b,
-                                      seq_len=args.seq)
-        fc = memory.forecast(plan, plan2)
-        kvp = fcs = None
-        if args.target.startswith("transformer_lm"):
-            # serving-side companion (ISSUE 17): per-slot KV bytes and
-            # the max-slot fit, dtype-aware under --quantize
-            kvp = memory.serving_kv_plan(args.target, seq_len=args.seq,
-                                         quantize=args.quantize)
-            fcs = memory.forecast_slots(kvp)
-        if args.json:
-            out = memory.compact(plan)
-            out["model"] = args.target
-            out["forecast"] = fc
-            out["plan_2x"] = memory.compact(plan2)
-            if kvp is not None:
-                out["serving_kv"] = kvp
-                out["forecast_slots"] = fcs
-            print(json.dumps(out))
-        else:
-            print(f"memory plan: {args.target} b={b} "
-                  f"({plan.get('device')})")
-            print(memory.render(plan, fc))
-            if kvp is not None:
-                print(f"\nserving (quantize={kvp['quantize']}): "
-                      f"kv/slot {kvp['kv_bytes_per_slot']} B "
-                      f"(L={kvp['max_len']}, "
-                      f"dtype={kvp['cache_dtype']}"
-                      + (f", page={kvp['page_tokens']}"
-                         if kvp['page_tokens'] else "")
-                      + f"), weights {kvp['params_bytes']} B"
-                      f" -> predicted max slots "
-                      f"{fcs['predicted_max_slots']}")
-        return 0
-
-    if os.path.isdir(args.target):
-        step_flops = args.gflops * 1e9 if args.gflops else None
-        by_kind = None
-        if step_flops and args.gflopsConv is not None:
-            conv = args.gflopsConv * 1e9
-            by_kind = {"matmul": max(0.0, step_flops - conv),
-                       "conv": conv}
-        summary = attrib.attribute_profile(
-            args.target, steps=args.steps, step_flops=step_flops,
-            flops_by_kind=by_kind, peak_flops=args.peak,
-            top_ops=args.top)
-    else:
-        # model mode: short profiled perf run, then attribute its trace
-        # with the run's own numerators (perf prints its JSON line
-        # first; ours is last)
-        import tempfile
-
-        from bigdl_tpu.cli import perf
-
-        tmp = tempfile.mkdtemp(prefix="bigdl_explain_")
-        out = perf.run(args.target, args.batchSize, args.iteration,
-                       "random", profile_dir=tmp,
-                       strategy=args.strategy, seq_len=args.seq)
-        gf = out.get("step_gflops_analytic") or 0.0
-        kinds = out.get("step_gflops_by_kind") or {}
-        summary = attrib.attribute_profile(
-            tmp, steps=args.iteration * out.get("inner_steps", 1),
-            step_flops=gf * 1e9 or None,
-            flops_by_kind={k: v * 1e9 for k, v in kinds.items()} or None,
-            peak_flops=(out.get("peak_flops_assumed") or 0)
-            * out.get("n_devices", 1) or None,
-            top_ops=args.top)
-        summary["perf"] = {k: out.get(k) for k in (
-            "model", "batch", "strategy", "n_devices", "mesh",
-            "records_per_second", "mfu_pct", "device")}
-
+    from bigdl_tpu.obs import memory
+    b = args.batchSize
+    plan = memory.plan_for_model(args.target, b, seq_len=args.seq)
+    plan2 = memory.plan_for_model(args.target, 2 * b, seq_len=args.seq)
+    fc = memory.forecast(plan, plan2)
+    kvp = fcs = None
+    if args.target.startswith("transformer_lm"):
+        # serving-side companion (ISSUE 17): per-slot KV bytes and
+        # the max-slot fit, dtype-aware under --quantize
+        kvp = memory.serving_kv_plan(args.target, seq_len=args.seq,
+                                     quantize=args.quantize)
+        fcs = memory.forecast_slots(kvp)
     if args.json:
-        c = attrib.compact(summary)
-        c["xplane"] = summary.get("xplane")
-        if "perf" in summary:
-            c["perf"] = summary["perf"]
-        print(json.dumps(c))
+        out = memory.compact(plan)
+        out["model"] = args.target
+        out["forecast"] = fc
+        out["plan_2x"] = memory.compact(plan2)
+        if kvp is not None:
+            out["serving_kv"] = kvp
+            out["forecast_slots"] = fcs
+        print(json.dumps(out))
     else:
-        print(attrib.render(summary))
+        print(f"memory plan: {args.target} b={b} "
+              f"({plan.get('device')})")
+        print(memory.render(plan, fc))
+        if kvp is not None:
+            print(f"\nserving (quantize={kvp['quantize']}): "
+                  f"kv/slot {kvp['kv_bytes_per_slot']} B "
+                  f"(L={kvp['max_len']}, "
+                  f"dtype={kvp['cache_dtype']}"
+                  + (f", page={kvp['page_tokens']}"
+                     if kvp['page_tokens'] else "")
+                  + f"), weights {kvp['params_bytes']} B"
+                  f" -> predicted max slots "
+                  f"{fcs['predicted_max_slots']}")
     return 0
 
 

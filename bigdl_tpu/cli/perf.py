@@ -334,13 +334,7 @@ def _annotate_bn_fused(out: dict, model) -> None:
 
 _PHASE_COLUMNS = ("data_wait_s", "h2d_s", "dispatch_s", "device_s",
                   "ckpt_s", "stall_frac")
-# ISSUE 8: attribution columns, schema-stable like the phase columns —
-# null until a capture window closed (no capture = no device timeline to
-# attribute), then the per-step collective seconds, the collective share
-# of device time, and the compact per-category attribution of the run's
-# LAST verified window.
-_ATTRIB_COLUMNS = ("collective_s", "collective_frac", "attrib")
-# ISSUE 12: the memory columns, schema-stable like the attrib columns —
+# ISSUE 12: the memory columns, schema-stable like the phase columns —
 # null obs-off; under --obs the peak HBM bytes (live device.memory_stats
 # when the backend has them, else the static plan's modeled total), the
 # headroom fraction against the matched per-chip capacity, and the
@@ -356,10 +350,10 @@ def _annotate_obs_phases(out: dict, obs_state, phase: dict | None = None,
     modulo exactly these nulls), measured cumulative seconds under
     --obs. ``stall_frac`` is the feed-stall fraction of wall time — the
     number PERF.md §4 could previously only infer. Under --obs the
-    trace/capture artifacts ride along as ``obs``, and a closed capture
-    window additionally fills the attribution columns (ISSUE 8)."""
-    for c in _ATTRIB_COLUMNS:
-        out[c] = None
+    trace/capture artifacts ride along as ``obs``: a capture's record
+    says where its ``jax.profiler`` directory is and whether it parsed
+    (read it in XProf/Perfetto; the ledger's numbers come from
+    ``benchmark/lib/trace.py``)."""
     for c in _MEM_COLUMNS:
         out[c] = None
     on = (obs_state is not None and obs_state.enabled
@@ -402,18 +396,8 @@ def _annotate_obs_phases(out: dict, obs_state, phase: dict | None = None,
     if "captures" in info:
         o["captures"] = [
             {k: c[k] for k in ("start_step", "stop_step", "trigger",
-                               "ok", "dir", "error", "attrib",
-                               "attrib_error") if k in c}
+                               "ok", "dir", "error") if k in c}
             for c in info["captures"]]
-        for c in reversed(info["captures"]):
-            a = c.get("attrib")
-            if a:  # newest attributed window wins
-                steps = max(1, int(a.get("steps") or 1))
-                out["attrib"] = a
-                out["collective_s"] = round(
-                    a["collective_s"] / steps, 6)
-                out["collective_frac"] = a["collective_frac"]
-                break
     if o:
         out["obs"] = o
 
@@ -459,7 +443,8 @@ def _setup_strategy_harness(strat_name: str, model_name: str, batch: int,
     Geometry comes from the requested transformer_lm* config
     (:data:`_LM_GEOM`, seq overridable via --seq); the criterion is MSE
     over the block stack (embedding/head run replicated outside a real
-    pipeline and are excluded, exactly like the MULTICHIP_r05 dryrun)."""
+    pipeline and are excluded, exactly like ``__graft_entry__.py``'s
+    dryrun)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -561,8 +546,8 @@ def run(model_name: str, batch: int, iterations: int, data_type: str,
         data_workers: int = 0, prefetch_depth: int = 2,
         stage: str = "off"):
     """Throughput harness entry. ``autotune`` optionally installs the
-    tuning mode (the CLI does it via --autotune/apply_platform; bench.py
-    children pass it directly). ``fused_bn`` ('off'/'stats'/'apply')
+    tuning mode (the CLI does it via --autotune/apply_platform; library
+    callers pass it directly). ``fused_bn`` ('off'/'stats'/'apply')
     installs the Pallas BN path on the built model — the flag spelling of
     the resnet50_fbn/_fba model names. ``strategy`` ('dp'/'tp'/'sp'/
     'pp'/'ep', optionally NAME:K) runs the timed loop over every visible
@@ -615,8 +600,8 @@ def _run_timed(model_name: str, batch: int, iterations: int, data_type: str,
     # mesh re-formation + rebuild + recompile up to warmup IS restore_ms
     t_attempt0 = time.perf_counter()
 
-    # persistent compile cache: library callers (bench.py children, the
-    # chip smoke) reach the harness without going through apply_platform
+    # persistent compile cache: library callers (the chip smoke, the
+    # tests) reach the harness without going through apply_platform
     from bigdl_tpu.cli import common as _common
     _common.enable_compile_cache()
 
@@ -889,22 +874,6 @@ def _run_timed(model_name: str, batch: int, iterations: int, data_type: str,
 
     peak_per_chip, peak_label = _peak_flops(jax.devices()[0])
     peak = peak_per_chip * n_dev if peak_per_chip else None
-    if obs_state is not None and obs_state.capture is not None:
-        # attribution context (ISSUE 8): every capture window this run
-        # closes gets the run's own FLOPs numerator and mesh peak, so
-        # the post-capture attribution can decompose MFU instead of
-        # reporting bare times
-        cap = obs_state.capture
-        if step_flops:
-            cap.step_flops = step_flops * inner_steps
-            cap.flops_by_kind = {kk: v * inner_steps
-                                 for kk, v in flops_kinds.items()}
-        cap.peak_flops = peak
-        if strat is not None and strat.grad_comm_info() is not None:
-            # the captured window's collective times belong to a
-            # compressed wire — attribution records say so
-            cap.grad_comm = strat.grad_comm_info()
-
     if obs_state is not None and obs_state.enabled:
         # HBM attribution context (ISSUE 12): the static per-category
         # plan of the exact compiled step + a live sampler, installed
@@ -1077,7 +1046,7 @@ def _run_timed(model_name: str, batch: int, iterations: int, data_type: str,
         "mesh": mesh_axes,
         # ISSUE 10: what the gradient wire carried — every line says so
         # ("off"/null single-device or uncompressed, so compressed-vs-
-        # plain A/Bs join on schema-stable columns next to collective_s)
+        # plain A/Bs join on schema-stable columns)
         "grad_compress": (grad_comm_cfg.compress
                           if (grad_comm_cfg is not None
                               and grad_comm_cfg.active
@@ -1098,8 +1067,7 @@ def _run_timed(model_name: str, batch: int, iterations: int, data_type: str,
         "peak_flops_assumed": peak_per_chip,
         "peak_flops_device_match": peak_label,
         "step_gflops_analytic": round(flops_analytic / 1e9, 3),
-        # the matmul/conv split of the analytic numerator — what the
-        # attribution engine joins category times against (ISSUE 8)
+        # the matmul/conv split of the analytic numerator
         "step_gflops_by_kind": {
             "matmul": round(flops_kinds["matmul"] / 1e9, 3),
             "conv": round(flops_kinds["conv"] / 1e9, 3)},

@@ -1,12 +1,11 @@
 """Shared program-configuration provenance (ISSUE 18 satellite; first
 bite of ROADMAP item 5).
 
-Four surfaces used to hand-assemble the same "which program config
+Several surfaces used to hand-assemble the same "which program config
 produced this number" fields — the perf JSON line
 (``cli/perf.py`` annotators), the ``/metrics`` ``_info`` gauge
-(``serving/engine.provenance`` + ``cli/serve``), the ``bench.py``
-companion rows, and the bench-script capture records — and a fifth
-consumer (``bigdl-tpu batch-predict``) was about to appear. This module
+(``serving/engine.provenance`` + ``cli/serve``), the capture records of
+``scripts/serving_bench.py``, ``bigdl-tpu batch-predict``. This module
 is the single assembly point:
 
 * :func:`provenance_dict` builds the shared core — BN fusion mode,
@@ -17,11 +16,12 @@ is the single assembly point:
   scrape-safe scalars and always emits every key (the ``/metrics``
   ``_info`` idiom: a stable label set).
 * :data:`PROVENANCE_COMPANION_KEYS` is the canonical key list record
-  assemblies copy from a result dict (``bench.py`` companions, capture
-  records) — one list to extend when a new provenance column lands.
+  assemblies copy from a result dict (``scripts/serving_bench.py``'s
+  capture records) — one list to extend when a new provenance column
+  lands.
 
 Every field is read from the live process state at call time, exactly
-as the four hand-rolled copies did, so routing through here changes no
+as the hand-rolled copies did, so routing through here changes no
 output — it only removes the copies that could drift.
 """
 
@@ -32,7 +32,7 @@ from typing import Optional
 __all__ = ["provenance_dict", "PROVENANCE_COMPANION_KEYS"]
 
 # provenance columns a record assembly copies verbatim from a result
-# dict (bench companions, capture records): the config core plus the
+# dict (serving_bench capture records): the config core plus the
 # feed-attribution columns that make perf rows self-describing
 PROVENANCE_COMPANION_KEYS = ("conv_layouts", "conv_geom", "autotune",
                              "bn_fused", "pipeline", "stall_frac",

@@ -18,8 +18,11 @@ reader. This package is the substrate built once:
   training (step-phase histograms), resilience (fault/retry counters),
   and serving alike;
 * :mod:`capture` — on-demand ``jax.profiler`` windows mid-run
-  (``--traceSteps N@M``, SIGUSR2, touch-file), verified parseable with
-  ``utils/xplane`` on close;
+  (``--traceSteps N@M``, SIGUSR2, touch-file). A capture is verified,
+  not attributed: on close the ``*.xplane.pb`` must parse with
+  ``utils/xplane`` and the record says where it is. Reading it is
+  XProf/Perfetto's job for an operator; the one reducer whose numbers
+  the ledger holds is ``benchmark/lib/trace.py``;
 * :mod:`http`    — a live ``/metrics`` listener for training runs,
   reusing serving's exposition format.
 
@@ -27,13 +30,10 @@ Wired as ``--obs``/``--traceDir``/``--traceSteps``/``--metricsPort`` on
 the perf + training CLIs (``cli/common.py``), with per-step phase
 columns (``data_wait_s``, ``h2d_s``, ``dispatch_s``, ``device_s``,
 ``ckpt_s``, ``stall_frac``) stamped into every perf JSON line next to
-``bn_fused``/``lint``/``supervisor``. ROADMAP items 2 (collective time
-broken out) and 4 (feed-stall metering) read from this layer.
+``bn_fused``/``lint``/``supervisor``.
 """
 
-from bigdl_tpu.obs import attrib, memory
-from bigdl_tpu.obs.attrib import (ATTRIB_CATEGORIES, attribute,
-                                  attribute_profile, classify_op)
+from bigdl_tpu.obs import memory
 from bigdl_tpu.obs.capture import (CaptureController, parse_trace_steps,
                                    TOUCH_FILE_NAME)
 from bigdl_tpu.obs.http import MetricsServer, start_metrics_server
@@ -51,8 +51,6 @@ from bigdl_tpu.obs.spans import (Tracer, counter, disable, enable,
                                  span)
 
 __all__ = [
-    "attrib", "ATTRIB_CATEGORIES", "attribute", "attribute_profile",
-    "classify_op",
     "CaptureController", "parse_trace_steps", "TOUCH_FILE_NAME",
     "MetricsServer", "start_metrics_server",
     "memory", "HbmSampler", "build_plan", "device_hbm_bytes", "forecast",

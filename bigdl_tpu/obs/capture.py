@@ -74,18 +74,6 @@ class CaptureController:
                  window_steps: int = 5, touch_file: Optional[str] = None,
                  install_signal: bool = True):
         self.trace_dir = str(trace_dir)
-        # optional attribution context (ISSUE 8): the harness that knows
-        # its step FLOPs / mesh peak installs them so every verified
-        # window closes with an MFU-decomposed attribution, not just
-        # "parsed ok". None = attribution still runs, times only.
-        self.step_flops: Optional[float] = None
-        self.flops_by_kind: Optional[dict] = None
-        self.peak_flops: Optional[float] = None
-        # gradient-communication context (ISSUE 10): when the harness
-        # compressed/bucketed the grad all-reduce, the config rides into
-        # every attributed window so a captured collective_s can be read
-        # against the wire bytes that produced it
-        self.grad_comm: Optional[dict] = None
         os.makedirs(self.trace_dir, exist_ok=True)
         self._planned: Optional[Tuple[int, int]] = (
             parse_trace_steps(trace_steps) if trace_steps else None)
@@ -217,33 +205,6 @@ class CaptureController:
         rec["ok"] = bool(planes)
         if not planes:
             rec["error"] = "xplane parsed but contains no planes"
-            return
-        self._attribute(rec, planes)
-
-    def _attribute(self, rec: dict, planes) -> None:
-        """Post-capture attribution (ISSUE 8): every verified window is
-        immediately explained — per-category device time with the
-        collective breakout stamped into the capture record and
-        published as ``attrib_*`` gauges on the shared registry. A
-        failure here is recorded, never raised: a window that parsed
-        but resisted classification is still a good capture."""
-        try:
-            from bigdl_tpu.obs import attrib as _attrib
-            from bigdl_tpu.obs.metrics import get_registry
-            steps = max(1, int(rec["stop_step"]) - int(rec["start_step"]))
-            summary = _attrib.attribute(
-                planes, steps=steps, step_flops=self.step_flops,
-                flops_by_kind=self.flops_by_kind,
-                peak_flops=self.peak_flops)
-            rec["attrib"] = _attrib.compact(summary)
-            if self.grad_comm is not None:
-                rec["grad_comm"] = dict(self.grad_comm)
-            _attrib.publish(summary, get_registry())
-        except Exception as e:
-            rec["attrib_error"] = (
-                f"attrib: {type(e).__name__}: {e}"[:200])
-            logger.warning("obs capture attribution failed: %s",
-                           rec["attrib_error"])
 
     # ----------------------------------------------------------- reporting
     def annotation(self) -> List[dict]:

@@ -1,8 +1,7 @@
 """HBM attribution: explain every byte, forecast the fit, autopsy the
 OOM (ISSUE 12 tentpole).
 
-PR 8 made device *time* explainable (``obs/attrib.py``); this module is
-the memory twin. Four surfaces:
+Four surfaces:
 
 * **static plan** — :func:`build_plan` at trace time: per-category byte
   accounting (params / optimizer state / gradients+grad-comm buckets /
@@ -27,7 +26,7 @@ the memory twin. Four surfaces:
 * **fit forecaster** — :func:`forecast` fits total bytes linearly over
   two plans at different batch sizes (fixed + per-sample slope) and
   predicts the max batch that still fits the device; ``bigdl-tpu
-  explain --mem <model>`` renders it (:func:`plan_for_model` /
+  explain <model>`` renders it (:func:`plan_for_model` /
   :func:`render`).
 
 Like ``resilience.faults``, the cross-layer channel is one module-level
@@ -250,7 +249,7 @@ def plan_for_model(model_name: str, batch: int,
                    use_bf16: bool = False) -> dict:
     """Build, lower, and compile the single-device training step for a
     perf-zoo model at ``batch`` and return its memory plan — the
-    ``explain --mem`` / forecaster entry point. Mirrors the perf
+    ``bigdl-tpu explain`` / forecaster entry point. Mirrors the perf
     harness's step (SGD+momentum, value_and_grad, donated state) so the
     plan describes the bytes a real run would hold."""
     import jax
@@ -312,7 +311,7 @@ def serving_kv_plan(model_name: str, *, seq_len: Optional[int] = None,
     layout — int8 rows + one f32 scale per (page, head, token), exactly
     :class:`~bigdl_tpu.serving.kv_pages.QuantPool`'s arrays) plus the
     resident weight bytes under ``--quantize``. This is the dtype-aware
-    half of ``explain --mem``: quantized modes change per-slot and
+    half of ``bigdl-tpu explain``: quantized modes change per-slot and
     fixed bytes, and :func:`forecast_slots` re-fits the max-slot
     prediction from them."""
     import jax
@@ -401,8 +400,7 @@ def _fmt_bytes(n) -> str:
 
 
 def render(plan: dict, fc: Optional[dict] = None) -> str:
-    """Human table of the plan (and forecast, when given) — the memory
-    twin of ``attrib.render``."""
+    """Human table of the plan (and forecast, when given)."""
     from bigdl_tpu.utils.table import format_table
 
     total = max(1, plan["total_bytes"])
@@ -439,7 +437,7 @@ def render(plan: dict, fc: Optional[dict] = None) -> str:
 
 def compact(plan: dict) -> dict:
     """The small spelling stamped into perf JSON lines as the ``mem``
-    detail dict (schema-stable sibling of ``attrib``)."""
+    detail dict."""
     return {
         "categories": {k: int(v) for k, v in plan["categories"].items()
                        if v},

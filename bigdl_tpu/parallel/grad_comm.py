@@ -37,8 +37,8 @@ strategies compose through :meth:`DataParallel.reduce_grads`:
     latency-hiding scheduler is free to overlap each bucket's reduce
     with backward compute that hasn't produced later buckets yet.
     Whether a given XLA build honors the dtype steering has not been
-    measured on the chip (ROADMAP S7: ``collective_s`` compressed vs
-    plain, same attribution columns).
+    measured on the chip (ROADMAP S7: ``collective_exposed_share.train``
+    in the benchmark's ``train_dp4`` cell, compressed vs plain).
   - an explicit ``jax.shard_map`` path (:func:`compressed_psum`):
     per-bucket ``lax.psum`` over the mesh axis on the compressed value —
     the manual-collective building block for strategies that hold

@@ -98,9 +98,7 @@ def jaxpr_flops_by_kind(jaxpr) -> dict:
     ``{"matmul": f, "conv": f}``. ``dot_general`` (and Pallas kernels
     with an author-declared CostEstimate — their declared FLOPs are MXU
     dot FLOPs by construction, PERF.md §5) count as matmul;
-    ``conv_general_dilated`` as conv. The attribution engine
-    (``obs/attrib.py``) joins these against the profiled matmul/conv
-    category times to get per-category achieved-vs-roofline utilization."""
+    ``conv_general_dilated`` as conv."""
     if isinstance(jaxpr, jex_core.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     total = {"matmul": 0.0, "conv": 0.0}

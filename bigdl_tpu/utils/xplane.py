@@ -19,11 +19,9 @@ schema additions can't break the reader.
 from __future__ import annotations
 
 import os
-import re
 from typing import Dict, Iterator, List, Tuple
 
 __all__ = ["parse_xspace", "find_xplane_pb", "device_planes", "op_totals",
-           "collectives", "collective_kind", "COLLECTIVE_KINDS",
            "XPlane", "XLine", "XEvent"]
 
 
@@ -188,56 +186,15 @@ def device_planes(planes: List[XPlane]) -> List[XPlane]:
             and any(ln.events for ln in p.lines)]
 
 
-# HLO spellings of the cross-device collectives, most specific first
-# (``reduce-scatter`` must not fall into a bare ``reduce`` bucket, and
-# ``all-reduce-start``/``-done`` async halves count as all-reduce). The
-# jax-level names (psum/ppermute/all_to_all) appear when the event label
-# carries named-scope provenance instead of raw HLO.
-COLLECTIVE_KINDS: Tuple[Tuple[str, "re.Pattern[str]"], ...] = (
-    ("all_reduce", re.compile(r"all[-_]?reduce|\bpsum\b", re.I)),
-    ("reduce_scatter", re.compile(r"reduce[-_]?scatter", re.I)),
-    ("all_gather", re.compile(r"all[-_]?gather", re.I)),
-    ("all_to_all", re.compile(r"all[-_]?to[-_]?all", re.I)),
-    ("collective_permute",
-     re.compile(r"collective[-_]?permute|\bppermute\b", re.I)),
-    ("collective_broadcast", re.compile(r"collective[-_]?broadcast", re.I)),
-)
-
-
-def collective_kind(name: str) -> "str | None":
-    """Collective kind of one op/fusion label, or None for non-collective
-    ops. reduce-scatter is tested before all_reduce so the compound name
-    never degrades into the wrong bucket."""
-    if re.search(r"reduce[-_]?scatter", name, re.I):
-        return "reduce_scatter"
-    for kind, pat in COLLECTIVE_KINDS:
-        if pat.search(name):
-            return kind
-    return None
-
-
-def collectives(planes: List[XPlane]) -> Dict[str, Dict[str, float]]:
-    """Per-collective-kind totals across the given planes:
-    ``kind -> {"total_ps", "count"}`` (ROADMAP item 2's "per-step
-    collective time broken out" — the raw substrate; callers divide by
-    step count). Kinds with no events are absent, so an empty dict means
-    a genuinely collective-free profile (single device, or a host-only
-    trace)."""
-    out: Dict[str, Dict[str, float]] = {}
-    for name, ent in op_totals(planes).items():
-        kind = collective_kind(name)
-        if kind is None:
-            continue
-        agg = out.setdefault(kind, {"total_ps": 0.0, "count": 0})
-        agg["total_ps"] += ent["total_ps"]
-        agg["count"] += ent["count"]
-    return out
-
-
 def op_totals(planes: List[XPlane]) -> Dict[str, Dict[str, float]]:
     """Aggregate event durations by op/fusion label across the given
     planes: label -> {"total_ps", "count"}. Events whose metadata id has
-    no registered name fall under "<unnamed:ID>"."""
+    no registered name fall under "<unnamed:ID>". Every line of a plane is
+    walked, and a TPU plane carries the step, the program and the ops as
+    separate lines (and a ``while`` covers its body): a label's own total
+    is sound, a sum across labels counts time twice or more. Busy and idle
+    time come from ``benchmark/lib/trace.py`` (union of intervals on the
+    op line)."""
     totals: Dict[str, Dict[str, float]] = {}
     for pl in planes:
         for ln in pl.lines:

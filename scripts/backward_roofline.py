@@ -106,25 +106,17 @@ def join_profile(profile_dir, cells, names, top, steps, tol):
     from bigdl_tpu.utils.xplane import (device_planes, find_xplane_pb,
                                         op_totals, parse_xspace)
 
-    from bigdl_tpu.obs.attrib import attribute, classify_op
-
     pb = find_xplane_pb(profile_dir)
     if pb is None:
         raise SystemExit(f"no *.xplane.pb under {profile_dir}")
     planes = parse_xspace(pb)
     totals = op_totals(device_planes(planes))
-    # the ISSUE 8 attribution of the same profile rides along so the
-    # roofline table and the category/collective breakout come from ONE
-    # parse — consumers stop re-deriving it (and unknown keys like
-    # collective_s/attrib in perf JSON lines are now first-class here)
-    summary = attribute(planes, steps=max(1, steps))
     ranked = sorted(totals.items(), key=lambda kv: -kv[1]["total_ps"])
     rows = []
     for name, ent in ranked[:top]:
         ms_step = ent["total_ps"] / 1e9 / max(1, steps)
         row = {"op": name, "ms_per_step": round(ms_step, 3),
-               "count": ent["count"],
-               "category": classify_op(name)[0], "match": None}
+               "count": ent["count"], "match": None}
         # nearest isolated cell by relative duration distance
         best_key, best_d = None, tol
         for (g, p), per in cells.items():
@@ -150,7 +142,7 @@ def join_profile(profile_dir, cells, names, top, steps, tol):
                 if ceil else None,
             }
         rows.append(row)
-    return pb, rows, summary
+    return pb, rows
 
 
 def load_perf_mem(path):
@@ -177,7 +169,7 @@ def load_perf_mem(path):
             "batch": found.get("batch")}
 
 
-def markdown(iso_rows, prof_rows, pb, attrib_summary=None, mem=None):
+def markdown(iso_rows, prof_rows, pb, mem=None):
     out = ["### Isolated backward roofline (probe microbenches)", "",
            "| shape | pass | NHWC ms | NHWC TF/s | best | best ms | "
            "best TF/s | best/NHWC time |",
@@ -189,34 +181,20 @@ def markdown(iso_rows, prof_rows, pb, attrib_summary=None, mem=None):
             f"{r['best_tfs']} | {r['pct_of_ceiling_default']}% |")
     if prof_rows is not None:
         out += ["", f"### Profile join (top fusions, {pb})", "",
-                "| op | category | ms/step | matched bench | "
+                "| op | ms/step | matched bench | "
                 "achieved TF/s | ceiling TF/s | % of ceiling |",
-                "|---|---|---|---|---|---|---|"]
+                "|---|---|---|---|---|---|"]
         for r in prof_rows:
             m = r["match"]
-            cat = r.get("category", "-")
             if m:
                 out.append(
-                    f"| {r['op']} | {cat} | {r['ms_per_step']} | "
+                    f"| {r['op']} | {r['ms_per_step']} | "
                     f"{m['shape']}/{m['pass']}/{m['layout']} "
                     f"(±{m['rel_duration_gap']}) | {m['achieved_tfs']} | "
                     f"{m['ceiling_tfs']} | {m['pct_of_ceiling']}% |")
             else:
-                out.append(f"| {r['op']} | {cat} | {r['ms_per_step']} | "
+                out.append(f"| {r['op']} | {r['ms_per_step']} | "
                            "unmatched | — | — | — |")
-    if attrib_summary is not None:
-        out += ["", "### Device-time attribution (PERF.md §16 taxonomy)",
-                "", "| category | time_s | frac % | ms/step |",
-                "|---|---|---|---|"]
-        steps = max(1, attrib_summary.get("steps") or 1)
-        for cat, d in attrib_summary["categories"].items():
-            out.append(f"| {cat} | {d['time_s']:.5f} "
-                       f"| {100 * d['frac']:.1f} "
-                       f"| {d['time_s'] * 1e3 / steps:.3f} |")
-        for kind, d in attrib_summary["collectives"].items():
-            out.append(f"| coll:{kind} | {d['time_s']:.5f} "
-                       f"| {100 * d['frac']:.1f} "
-                       f"| {d['time_s'] * 1e3 / steps:.3f} |")
     if mem is not None:
         pk, hr = mem.get("hbm_peak_bytes"), mem.get("hbm_headroom_frac")
         out += ["", "### HBM attribution (ISSUE 12, from --perfJson)", "",
@@ -258,12 +236,12 @@ def main(argv=None):
 
     cells, names = load_probe(args.probe)
     iso = isolated_table(cells, names)
-    pb, prof, summary = (None, None, None)
+    pb, prof = None, None
     if args.profile:
-        pb, prof, summary = join_profile(args.profile, cells, names,
-                                         args.top, args.steps, args.tol)
+        pb, prof = join_profile(args.profile, cells, names,
+                                args.top, args.steps, args.tol)
     mem = load_perf_mem(args.perfJson) if args.perfJson else None
-    md = markdown(iso, prof, pb, summary, mem)
+    md = markdown(iso, prof, pb, mem)
     if args.out:
         with open(args.out, "w") as f:
             f.write(md)
@@ -271,13 +249,8 @@ def main(argv=None):
     else:
         sys.stdout.write(md)
     if args.json:
-        attrib_compact = None
-        if summary is not None:
-            from bigdl_tpu.obs.attrib import compact
-            attrib_compact = compact(summary)
         with open(args.json, "w") as f:
-            json.dump({"isolated": iso, "profile": prof,
-                       "attrib": attrib_compact, "mem": mem,
+            json.dump({"isolated": iso, "profile": prof, "mem": mem,
                        "xplane": pb}, f, indent=1, sort_keys=True)
             f.write("\n")
 

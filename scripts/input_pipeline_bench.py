@@ -10,7 +10,8 @@ crop/flip + normalize + batch assembly) in train mode at 224x224.
 
 Prints one JSON line: images/sec plus the decode backend in use.
 Reference bar: MTLabeledBGRImgToBatch.scala:48-133 kept Xeon clusters
-saturated; our bar is >= the measured model img/s (BENCH_r03).
+saturated; our bar is >= the measured model img/s
+(`git show 1f6203a:BENCH_r03.json`, another installation).
 
 ISSUE 13 sweep mode — grid the executor pipeline and report per-config
 stall fraction against a simulated device step:

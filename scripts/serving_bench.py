@@ -907,7 +907,8 @@ def run_dp_sweep(args):
 def _companion_keys():
     """The shared provenance companion-key list (cli/provenance.py),
     loaded by file path so the bench parent never imports the bigdl_tpu
-    package (whose import pulls jax; see bench.py for the failure mode).
+    package (whose import pulls jax: a parent that has touched jax
+    holds the chip, and a child that needs it then fails or hangs).
     """
     import importlib.util
     path = os.path.join(REPO, "bigdl_tpu", "cli", "provenance.py")

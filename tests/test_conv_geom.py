@@ -416,55 +416,6 @@ def test_perf_line_stamps_geom_policy():
         "layouts": {"wgrad": "NCHW"}}]
 
 
-# ------------------------------------------------- bench hygiene satellites
-class TestBenchHygiene:
-    def _bench(self):
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "bench", os.path.join(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__))), "bench.py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
-    def test_vs_baseline_null_while_unpublished(self):
-        bench = self._bench()
-        # TPU row: still null — published{} is empty
-        line = bench._build_line("resnet50", {
-            "backend": "tpu", "batch": 128, "dtype": "bfloat16",
-            "images_per_second_per_chip": 2662.7}, {})
-        assert line["vs_baseline"] is None
-        assert "degraded" not in line
-
-    def test_no_tpu_result_is_an_error_not_a_line(self):
-        """ISSUE 21: the CPU fallback, the probe cache and the degraded
-        row are gone — a child that did not run on the TPU is an error,
-        and the parent never appends to the committed partial record."""
-        bench = self._bench()
-        src = open(bench.__file__).read()
-        for gone in ("degraded", "PROBE", "BENCH_PARTIAL", "_TIMEOUT\"",
-                     "BENCH_TPU_TIMEOUT"):
-            assert gone not in src, gone
-        assert not hasattr(bench, "_partial")
-
-    def test_pipe_ab_and_geom_ab_present(self):
-        # PR 3 dropped resnet50_pipe (0.99% MFU told us nothing new);
-        # ISSUE 13 re-admits it as the before leg of the executor feed
-        # A/B, paired with resnet50_pipe_exec
-        names = {row[0] for row in self._bench()._companions(128, 20)}
-        assert {"resnet50_pipe", "resnet50_pipe_exec",
-                "resnet50_geom"} <= names
-
-    def test_hard_grade_tta_pinned(self):
-        src = open(os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "bench.py")).read()
-        child = src[src.index("def child("):src.index("def _attempt(")]
-        assert "hard=True" in child
-        # grade provenance rides into the companion extraction
-        assert '"hard_data"' in src and '"grade_lift"' in src
-
-
 # --------------------------------------------------------- compiled (TPU)
 @pytest.mark.tpu
 def test_conv_geom_compiled_on_tpu():

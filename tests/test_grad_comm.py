@@ -407,14 +407,3 @@ class TestCli:
         args = p.parse_args(["--gradCompress", "fp16+ec",
                              "--gradBuckets", "4"])
         assert args.gradCompress == "fp16+ec" and args.gradBuckets == "4"
-
-    def test_bench_line_carries_columns(self):
-        import bench
-        result = {"batch": 16, "dtype": "float32",
-                  "images_per_second_per_chip": 10.0, "backend": "tpu",
-                  "strategy": "dp", "n_devices": 8, "mesh": "data:8",
-                  "collective_s": 0.001, "collective_frac": 0.1,
-                  "grad_compress": "bf16", "grad_buckets": 3}
-        line = bench._build_line("lenet5", result, {})
-        assert line["grad_compress"] == "bf16"
-        assert line["grad_buckets"] == 3

@@ -1,9 +1,8 @@
 """ISSUE 8: ``--strategy`` through the perf harness on the 8-device CPU
 mesh — dp loss parity with the single-device run (the reference's
 DistriOptimizerSpec bar), mesh/device-count stamping in every JSON
-line, schema-stable null attribution columns when no capture fires, and
-the cli/common strategy machinery (spec parsing, mesh shapes, the
-stepsPerDispatch/innerSteps x strategy SystemExit contract the hidden
+line, and the cli/common strategy machinery (spec parsing, mesh shapes,
+the stepsPerDispatch/innerSteps x strategy SystemExit contract the hidden
 data_parallel branch used to skip)."""
 
 import jax
@@ -15,8 +14,7 @@ from bigdl_tpu.cli.perf import run
 
 def test_perf_strategy_dp_matches_single_device():
     """Acceptance: perf --strategy dp on 8 virtual CPU devices lands on
-    the single-device loss, with strategy/mesh/n_devices stamped and the
-    attribution columns null (no capture window fired)."""
+    the single-device loss, with strategy/mesh/n_devices stamped."""
     assert len(jax.devices()) == 8
     single = run("lenet5", 16, 4, "constant", use_bf16=False)
     dp = run("lenet5", 16, 4, "constant", use_bf16=False, strategy="dp")
@@ -26,9 +24,6 @@ def test_perf_strategy_dp_matches_single_device():
     assert dp["strategy"] == "dp"
     assert dp["mesh"] == {"data": 8}
     assert dp["n_devices"] == 8
-    for out in (single, dp):  # schema-stable nulls without a capture
-        for c in ("collective_s", "collective_frac", "attrib"):
-            assert c in out and out[c] is None
 
 
 def test_perf_deprecated_data_parallel_alias():
