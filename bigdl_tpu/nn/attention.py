@@ -358,16 +358,15 @@ class MultiHeadAttention(SimpleModule):
         f32 accumulation. No expanded copy of K/V exists; g == 1 (plain
         multi-head) is the same code with a fold that moves nothing.
 
-        Caller must keep idx + m <= cache length: dynamic_update_slice
-        clamps out-of-range starts, which would silently shift the
-        write window."""
+        Caller must keep idx + m <= cache length: ``write_rows`` is a
+        dynamic_update_slice, which clamps out-of-range starts and would
+        silently shift the write window."""
         q, k, v = self._qkv(params, x)
         if self.rope:
             q, k = self._rope(q, idx), self._rope(k, idx)
-        kc = jax.lax.dynamic_update_slice(
-            cache["k"], k.astype(cache["k"].dtype), (0, 0, idx, 0))
-        vc = jax.lax.dynamic_update_slice(
-            cache["v"], v.astype(cache["v"].dtype), (0, 0, idx, 0))
+        from bigdl_tpu.ops.cache_write import write_rows
+        kc = write_rows(cache["k"], k, idx)
+        vc = write_rows(cache["v"], v, idx)
         b, h, m, d = q.shape
         q = q.reshape(b, self.num_kv_heads, -1, d)
         s = jnp.einsum("bkrd,bksd->bkrs", q, kc.astype(q.dtype),
@@ -850,8 +849,8 @@ class DifferentialAttention(SimpleModule):
         else:
             k, v = self.project_kv(params, x)
             at = idx % self.window if self.window else idx
-            new = {n: jax.lax.dynamic_update_slice(
-                       cache[n], t.astype(cache[n].dtype), (0, 0, at, 0))
+            from bigdl_tpu.ops.cache_write import write_rows
+            new = {n: write_rows(cache[n], t, at)
                    for n, t in (("k", k), ("v", v))}
         rows = jnp.arange(new["k"].shape[2])
         live = rows < jnp.minimum(idx + 1, rows.shape[0]) if self.window \

@@ -40,7 +40,12 @@ batching):
   rows of one matmul against that group's K and V): all ``max_len``
   positions of every slot, whatever is live.
   ``decode_live_positions_total`` over ``decode_steps_total`` says how
-  many of them a step needed.
+  many of them a step needed. The step writes each slot's new K and V
+  rows at that slot's own position with one in-place kernel call a cache
+  leaf (``ops.cache_write.write_rows``, whose batching rule replaces the
+  serial scatter loop ``vmap`` would make of the per-slot write; an
+  engine with a mesh keeps the scatter). ``/debug/slots`` says which
+  under ``kv.row_write`` once the step has been traced.
 
 ISSUE 14 rebuilt the hot path around three composable optimisations:
 
@@ -588,6 +593,9 @@ class DecodeEngine:
         self._accept_programs: dict = {}
         self._suffix_programs: dict = {}
         self._draft_step_jit = None
+        # what the row write's batching rule chose, a written leaf, when
+        # the plain step was traced (/debug/slots "row_write")
+        self._row_write: list = []
 
     def _pin(self, *out_sh):
         """``out_shardings=`` kwarg for a jit whose outputs must land in
@@ -598,6 +606,15 @@ class DecodeEngine:
             return {}
         return {"out_shardings": (out_sh if len(out_sh) > 1
                                   else out_sh[0])}
+
+    def _row_writes(self, chosen=None):
+        """Around a model's one-token step while a vmapped program is
+        traced: ``ops.cache_write``'s batching rule writes every slot's
+        row with one in-place kernel call a leaf, but for a mesh, where
+        a Pallas call cannot be partitioned and the per-slot scatter
+        stays. ``chosen`` collects what the rule chose."""
+        from bigdl_tpu.ops.cache_write import step_trace
+        return step_trace(chosen, kernel=self._shard is None)
 
     def _sample_fn(self, warp: bool):
         jax, jnp = self._jax, self._jnp
@@ -630,8 +647,9 @@ class DecodeEngine:
             def _one(params, logits, cache1, pos, temp, topk, topp, seed):
                 tok = sample(logits, pos, temp, topk, topp, seed)
                 cache_b = jax.tree_util.tree_map(lambda a: a[None], cache1)
-                lg, cache_b = model.decode_logits(params, tok[None, None],
-                                                  cache_b, pos)
+                with self._row_writes(self._row_write):
+                    lg, cache_b = model.decode_logits(
+                        params, tok[None, None], cache_b, pos)
                 return (tok, lg[0].astype(jnp.float32),
                         jax.tree_util.tree_map(lambda a: a[0], cache_b))
 
@@ -650,8 +668,9 @@ class DecodeEngine:
                     cache1 = _kvp.gather_cache(pools, pages)
                     cache_b = jax.tree_util.tree_map(
                         lambda a: a[None], cache1)
-                    lg, cache_b = model.decode_logits(
-                        params, tok[None, None], cache_b, pos)
+                    with self._row_writes(self._row_write):
+                        lg, cache_b = model.decode_logits(
+                            params, tok[None, None], cache_b, pos)
                     tok_kv = jax.tree_util.tree_map(
                         lambda c: jax.lax.dynamic_slice_in_dim(
                             c[0], pos, 1, axis=1)[:, 0, :], cache_b)
@@ -681,8 +700,9 @@ class DecodeEngine:
 
         def _one(dparams, tok, cache1, pos, temp, topk, topp, seed):
             cache_b = jax.tree_util.tree_map(lambda a: a[None], cache1)
-            lg, cache_b = dmodel.decode_logits(dparams, tok[None, None],
-                                               cache_b, pos)
+            with self._row_writes():
+                lg, cache_b = dmodel.decode_logits(
+                    dparams, tok[None, None], cache_b, pos)
             prop, q = _spec.draft_propose(lg[0].astype(jnp.float32),
                                           temp, topk, topp, seed, pos)
             return (prop, q,
@@ -1477,6 +1497,10 @@ class DecodeEngine:
                    "worker_up": self._worker_error is None,
                    "tp": self._shard.n_shard if self._shard else 1,
                    "kv": {"paged": self.paged}}
+            if self._row_write:  # known once the step has been traced
+                out["kv"]["row_write"] = (
+                    "batched" if "scatter" not in self._row_write
+                    else "scatter")
             if not self.paged:
                 out["kv"]["bytes_by_kind"] = self.cache_bytes_by_kind()
             else:

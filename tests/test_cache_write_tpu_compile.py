@@ -1,0 +1,50 @@
+"""``cache_write_rows`` compiled for a described v5e at the benchmark's
+real leaf shapes: what interpret mode cannot refuse (tiling, fast memory,
+the alias). No chip is needed and nothing runs; where the topology cannot
+be described here the tests skip. The topology is described inside a
+fixture, never at import (one process at a time may load the TPU's
+library, and every xdist worker imports this file)."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from bigdl_tpu.ops import cache_write as cw
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no TPU backend to compile for (a v5e:2x2 topology "
+                    f"cannot be described here): {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32, jnp.int8],
+                         ids=["bf16", "f32", "int8"])
+@pytest.mark.parametrize("shape", [(48, 2, 4096, 128), (64, 10, 512, 128),
+                                   (64, 10, 4096, 128)],
+                         ids=["starcoder2", "sambay_ring", "sambay_full"])
+def test_the_kernel_compiles_in_place_for_v5e(one_chip, monkeypatch, shape,
+                                              dtype):
+    monkeypatch.setattr(cw, "_interpret", lambda: False)
+    S, kh, T, d = shape
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    compiled = jax.jit(cw._write_batched, donate_argnums=(0,)).lower(
+        sds(shape, dtype), sds((S, kh, 1, d), dtype),
+        sds((S,), jnp.int32)).compile()
+    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+    mem = compiled.memory_analysis()
+    cache_bytes = S * kh * T * d * jnp.dtype(dtype).itemsize
+    # the cache goes in and comes out in one buffer, and nothing else of
+    # its size exists
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes // 100

@@ -262,6 +262,35 @@ def test_a_request_ended_while_its_step_is_in_flight(pair, how):
     assert count(pair.hand, "decode_dropped_tokens_total") == dropped + 1
 
 
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_three_slots_at_different_positions_equal_each_run_alone(kind):
+    """Eight steps with three slots live at positions 5, 9 and 12: every
+    slot's row goes to its own position in one batched write (ISSUE 33),
+    and each answer is the one its request gets with the engine to
+    itself."""
+    build, kw = KINDS[kind]
+    model, params = build()
+    reqs = [dict(tokens=PROMPTS[i], max_new_tokens=8, temperature=0.9,
+                 seed=40 + i) for i in (0, 1, 2)]
+
+    def run(batch):
+        eng = DecodeEngine(model, params, slots=3, prompt_buckets=(16,),
+                           **kw)
+        try:
+            futs, _ = submit_all(eng, batch)
+            assert sorted(int(p) for p in eng._pos)[3 - len(batch):] == \
+                sorted(len(r["tokens"]) for r in batch)
+            drain(eng, futs, "sync")
+            return results(futs)
+        finally:
+            eng.close()
+
+    together = run(reqs)
+    assert [len(o) for o in together] == [8, 8, 8]
+    assert together == [run([r])[0] for r in reqs]
+    assert len({tuple(o) for o in together}) == 3
+
+
 def test_step_keeps_its_contract(pair):
     """What ``benchmark/lib/serve.py``'s check relies on: after k calls
     of ``step()`` the slot's logits are those of position ``s - 1 + k``,
