@@ -190,6 +190,19 @@ def build_model(name: str, class_num: int = 1000, seq_len=None,
         "phi4_mini_flash": lambda: _sambay(
             200064, d_model=2560, num_layers=32, num_heads=40,
             num_kv_heads=20, d_ff=10240, window=512, max_len=4096),
+        # linear-attention / softmax hybrids with a routed FFN in every
+        # layer (models/hybrid_moe_lm.py): three KDA layers after each
+        # gated NoPE GQA layer, sigmoid top-k routing, a shared expert.
+        # The first is the class at smoke-test sizes, all 16 experts held;
+        # the second is Solar-Open2-250B's published widths as one chip's
+        # share of an eight-way expert-parallel deployment: 4 of 48
+        # layers, 40 of 320 experts a layer, 24,576 of 196,608 vocabulary
+        # rows (3.31B parameters: serve it with --bf16)
+        "hybrid_moe_lm": lambda: _hybrid_moe(
+            "hybrid_moe_lm", _LM_VOCAB, d_model=256, num_layers=4,
+            num_heads=4, num_kv_heads=2, head_dim=64, gate_rank=32,
+            num_experts=16, top_k=4, expert_width=128, max_len=512),
+        "solar_open2": lambda: _hybrid_moe("solar_open2", max_len=4096),
     }
     if name not in table:
         raise SystemExit(f"unknown model {name}; choose from {list(table)}")
@@ -202,7 +215,9 @@ def build_model(name: str, class_num: int = 1000, seq_len=None,
             "transformer_lm_16k": (16384,),
             "transformer_lm_32k": (32768,),
             "sambay_lm": (seq_len or 512,),
-            "phi4_mini_flash": (seq_len or 4096,)}.get(name, (224, 224, 3))
+            "phi4_mini_flash": (seq_len or 4096,),
+            "hybrid_moe_lm": (seq_len or 512,),
+            "solar_open2": (seq_len or 4096,)}.get(name, (224, 224, 3))
     # LM build overrides (tpulint): forced attn_impl and/or seq length
     # apply only to transformer_lm* names and only for this one call
     global _LM_OVERRIDE
@@ -229,6 +244,16 @@ def _sambay(vocab, **kw):
 
     return models.sambay_lm(
         vocab, attn_impl="flash" if jax.default_backend() == "tpu" else None,
+        **kw)
+
+
+def _hybrid_moe(preset, *a, **kw):
+    import jax
+
+    from bigdl_tpu import models
+
+    return getattr(models, preset)(
+        *a, attn_impl="flash" if jax.default_backend() == "tpu" else None,
         **kw)
 
 

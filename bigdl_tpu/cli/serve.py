@@ -10,6 +10,8 @@ compiles, and (for LMs) continuous-batching KV-cache decode:
     bigdl-tpu serve transformer_lm --model ckpt_dir --slots 8 --bf16
     bigdl-tpu serve phi4_mini_flash --randomInit --bf16 --slots 64 \
         --buckets 1 --seq 256
+    bigdl-tpu serve solar_open2 --randomInit --bf16 --slots 64 \
+        --buckets 1 --seq 256
     curl -d '{"tokens": [3, 1, 4], "max_new_tokens": 8}' \
         localhost:8000/generate
 
@@ -307,8 +309,11 @@ def build_app(args):
             or (getattr(args, "quantize", None) or "off") != "off"
             or strategy):
         raise SystemExit(
-            f"{name} keeps recurrent state in its decode slots and serves "
-            "on the dense path only: --kvPageTokens, --prefixCache, "
+            f"{name} keeps recurrent state in its decode slots"
+            + (" and routed expert stacks in its layers"
+               if getattr(model, "routed_experts", False) else "")
+            + " and serves on the dense path only: --kvPageTokens, "
+            "--prefixCache, "
             "--speculate, --quantize and --strategy are not supported "
             "for it yet (serving/decode.py)")
 

@@ -19,3 +19,6 @@ from bigdl_tpu.models.transformer_lm import (
     TransformerLM, transformer_lm, packed_lm_targets, PackedNLLCriterion,
 )
 from bigdl_tpu.models.sambay_lm import SambaYLM, sambay_lm
+from bigdl_tpu.models.hybrid_moe_lm import (
+    HybridMoELM, hybrid_moe_lm, solar_open2,
+)

@@ -9,8 +9,9 @@ or ring attention via ``attn_impl``), weight-tied logits head option, and
 
 Scales along every axis the framework ships: dp (batch), tp (Megatron
 specs apply to the blocks), sp (ring attention over `seq`), pp
-(`PipelineStack` of the same TransformerEncoderLayer blocks), MoE (swap
-``d_ff`` MLPs for :class:`bigdl_tpu.nn.MoE` via ``moe_experts``).
+(`PipelineStack` of the same TransformerEncoderLayer blocks). Its MLPs
+are dense; the LM with a routed FFN in every layer is
+:class:`bigdl_tpu.models.HybridMoELM`.
 """
 
 from __future__ import annotations

@@ -12,5 +12,6 @@ from bigdl_tpu.nn.structural import *  # noqa: F401,F403
 from bigdl_tpu.nn.recurrent import *  # noqa: F401,F403
 from bigdl_tpu.nn.attention import *  # noqa: F401,F403
 from bigdl_tpu.nn.ssm import *  # noqa: F401,F403
+from bigdl_tpu.nn.linear_attention import *  # noqa: F401,F403
 from bigdl_tpu.nn.moe import *  # noqa: F401,F403
 from bigdl_tpu.nn.criterion import *  # noqa: F401,F403

@@ -38,7 +38,10 @@ __all__ = [
     "dot_product_attention",
     "make_segment_mask",
     "LayerNorm",
+    "RMSNorm",
+    "grouped_cache_attention",
     "MultiHeadAttention",
+    "GatedAttention",
     "PositionalEncoding",
     "TransformerEncoderLayer",
     "TransformerEncoder",
@@ -138,6 +141,28 @@ class LayerNorm(SimpleModule):
         return y.astype(x.dtype)
 
 
+class RMSNorm(SimpleModule):
+    """Root-mean-square normalization over the last dimension, weight
+    only. The mean of squares in fp32, output cast back to the input
+    dtype."""
+
+    def __init__(self, dim: int, eps: float = 1e-5,
+                 name: Optional[str] = None):
+        super().__init__(name)
+        self.dim = dim
+        self.eps = eps
+
+    def init(self, rng):
+        del rng
+        return {"weight": jnp.ones((self.dim,))}
+
+    def _forward(self, params, x, *, training, rng):
+        xf = x.astype(jnp.float32)
+        y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                               + self.eps)
+        return (y * params["weight"]).astype(x.dtype)
+
+
 def rope_tables(max_len: int, dim: int, base: float = 10000.0):
     """cos/sin tables for rotary position embeddings (RoPE), NeoX-style
     half-split pairing: dims [0:dim/2] rotate with [dim/2:dim]."""
@@ -155,6 +180,27 @@ def apply_rope(x, cos, sin):
     c = cos.astype(x.dtype)
     s = sin.astype(x.dtype)
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+
+
+def grouped_cache_attention(q, kc, vc, idx):
+    """``q`` (b, h, m, d) at absolute positions idx..idx+m-1 against a
+    cache ``kc``, ``vc`` (b, n_kv, S, d) that already holds those rows:
+    causal softmax attention read at the cache's own head count (the
+    fold ``MultiHeadAttention.decode_chunk`` describes). Returns
+    (b, h, m, d) float32."""
+    b, h, m, d = q.shape
+    q = q.reshape(b, kc.shape[1], -1, d)
+    s = jnp.einsum("bkrd,bksd->bkrs", q, kc.astype(q.dtype),
+                   preferred_element_type=jnp.float32)
+    s = s / (d ** 0.5)
+    rows = idx + (jnp.arange(q.shape[2]) % m)[None, None, :, None]
+    live = jnp.arange(kc.shape[2])[None, None, None, :] <= rows
+    s = jnp.where(live, s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bkrs,bksd->bkrd", p.astype(q.dtype),
+                   vc.astype(q.dtype),
+                   preferred_element_type=jnp.float32)
+    return o.reshape(b, h, m, d)
 
 
 class MultiHeadAttention(SimpleModule):
@@ -367,22 +413,101 @@ class MultiHeadAttention(SimpleModule):
         from bigdl_tpu.ops.cache_write import write_rows
         kc = write_rows(cache["k"], k, idx)
         vc = write_rows(cache["v"], v, idx)
-        b, h, m, d = q.shape
-        q = q.reshape(b, self.num_kv_heads, -1, d)
-        s = jnp.einsum("bkrd,bksd->bkrs", q, kc.astype(q.dtype),
-                       preferred_element_type=jnp.float32)
-        s = s / (self.head_dim ** 0.5)
-        rows = idx + (jnp.arange(q.shape[2]) % m)[None, None, :, None]
-        live = jnp.arange(kc.shape[2])[None, None, None, :] <= rows
-        s = jnp.where(live, s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bkrs,bksd->bkrd", p.astype(q.dtype),
-                       vc.astype(q.dtype),
-                       preferred_element_type=jnp.float32).astype(x.dtype)
+        o = grouped_cache_attention(q, kc, vc, idx).astype(x.dtype)
         dt = x.dtype
-        o = self._merge_heads(o.reshape(b, h, m, d))
+        o = self._merge_heads(o)
         return (o @ params["wo"].astype(dt) + params["bo"].astype(dt),
                 {"k": kc, "v": vc})
+
+
+class GatedAttention(SimpleModule):
+    """Causal softmax GQA without positions and with an output gate
+    (Qwen3-Next's gated attention, as Solar Open 2's softmax layers use
+    it): ``out = (softmax(q k^T / sqrt(head_dim)) v * sigmoid(x Wg)) Wo``,
+    the gate elementwise on the concatenated heads; no bias, no rotation,
+    and ``num_heads * head_dim`` need not be ``d_model``. Prefill runs
+    ``attn_impl`` (``"flash"``: the Pallas forward kernel) on K/V copied
+    out to the query head count; a decode step writes its row with
+    ``write_rows`` and reads the cache grouped
+    (:func:`grouped_cache_attention`)."""
+
+    def __init__(self, d_model: int, num_heads: int, num_kv_heads: int,
+                 head_dim: int, attn_impl: Optional[AttnFn | str] = None,
+                 init_std: float = 0.02, name: Optional[str] = None):
+        super().__init__(name)
+        if num_heads % num_kv_heads:
+            raise ValueError(f"num_heads {num_heads} not divisible by "
+                             f"num_kv_heads {num_kv_heads}")
+        self.d_model, self.head_dim = d_model, head_dim
+        self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+        self.init_std = init_std
+        if attn_impl == "flash":
+            from bigdl_tpu.ops import flash_attention
+            attn_impl = flash_attention
+        self.attn_fn: AttnFn = attn_impl or dot_product_attention
+
+    def init(self, rng):
+        ks = jax.random.split(rng, 5)
+        d, hq = self.d_model, self.num_heads * self.head_dim
+        hkv = self.num_kv_heads * self.head_dim
+        mk = lambda k, shape: self.init_std * jax.random.normal(k, shape)
+        return {"wq": mk(ks[0], (d, hq)), "wk": mk(ks[1], (d, hkv)),
+                "wv": mk(ks[2], (d, hkv)), "wg": mk(ks[3], (d, hq)),
+                "wo": mk(ks[4], (hq, d))}
+
+    def init_cache(self, batch: int, max_len: int, dtype=jnp.float32):
+        shape = (batch, self.num_kv_heads, max_len, self.head_dim)
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+    def _heads(self, x, n):
+        b, s, _ = x.shape
+        return x.reshape(b, s, n, self.head_dim).transpose(0, 2, 1, 3)
+
+    def _qkv(self, params, x):
+        dt = x.dtype
+        return (self._heads(x @ params["wq"].astype(dt), self.num_heads),
+                self._heads(x @ params["wk"].astype(dt), self.num_kv_heads),
+                self._heads(x @ params["wv"].astype(dt), self.num_kv_heads))
+
+    def _out(self, params, x, a):
+        """a (b, h, s, d) attention output -> gated, through Wo. The gate
+        and its product in float32: one rounding on the way into Wo."""
+        b, h, s, d = a.shape
+        a = a.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+        gate = jax.nn.sigmoid(jnp.dot(
+            x, params["wg"].astype(x.dtype),
+            preferred_element_type=jnp.float32))
+        a = (a.astype(jnp.float32) * gate).astype(x.dtype)
+        return a @ params["wo"].astype(x.dtype)
+
+    def _attend_seq(self, params, x):
+        q, k, v = self._qkv(params, x)
+        g = self.num_heads // self.num_kv_heads
+        a = self.attn_fn(q, jnp.repeat(k, g, axis=1),
+                         jnp.repeat(v, g, axis=1), causal=True, mask=None)
+        return self._out(params, x, a), k, v
+
+    def _forward(self, params, x, *, training, rng):
+        return self._attend_seq(params, x)[0]
+
+    def prefill(self, params, x, cache):
+        """Whole-prompt forward that also writes K/V rows 0..s-1 (rows of
+        a bucket's padding are overwritten by decode before they are
+        attended). Returns (out, cache)."""
+        out, k, v = self._attend_seq(params, x)
+        return out, {n: jax.lax.dynamic_update_slice(
+            cache[n], t.astype(cache[n].dtype), (0, 0, 0, 0))
+            for n, t in (("k", k), ("v", v))}
+
+    def decode_step(self, params, x, cache, idx):
+        """x (b, m, d) at absolute positions idx..idx+m-1 (m = 1 in the
+        engine's step): writes its K/V rows, then attends over 0..idx."""
+        from bigdl_tpu.ops.cache_write import write_rows
+        q, k, v = self._qkv(params, x)
+        new = {"k": write_rows(cache["k"], k, idx),
+               "v": write_rows(cache["v"], v, idx)}
+        a = grouped_cache_attention(q, new["k"], new["v"], idx)
+        return self._out(params, x, a), new
 
 
 class PositionalEncoding(SimpleModule):

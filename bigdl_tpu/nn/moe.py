@@ -14,19 +14,29 @@ reference).
 
 Load-balancing uses the standard auxiliary loss (mean gate fraction x mean
 token fraction per expert); retrieve it from the returned state.
+
+Two classes live here. :class:`MoE` is the MixtureTable parity and its
+sparse, capacity-bounded extension over any expert module: softmax gate,
+tokens above an expert's capacity dropped. :class:`RoutedFFN` is the
+routed feed-forward layer of the language models
+(``models/hybrid_moe_lm.py``): sigmoid scores with a selection bias, top-k
+with renormalised weights, a shared expert, no capacity and no dropped
+token at any token count, and a layer that is told which experts it
+holds (one chip's share of an expert-parallel deployment).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from bigdl_tpu.core.module import Module
+from bigdl_tpu.core.module import Module, SimpleModule
 
-__all__ = ["MoE"]
+__all__ = ["MoE", "RoutedFFN", "routed_experts"]
 
 
 class MoE(Module):
@@ -152,3 +162,165 @@ class MoE(Module):
             params["experts"])
         gate = jax.device_put(params["gate"], NamedSharding(mesh, P()))
         return {"gate": gate, "experts": ex}
+
+
+# ------------------------------------------------------ the LM's routed FFN
+def _tile(n: int, choices) -> int:
+    return next((t for t in choices if n % t == 0), n)
+
+
+def _routed_experts(x, local, wts, w13, w2):
+    """``x`` (T, d) tokens; ``local`` (T, k) int32 the picks as indices
+    into the held stack, ``held`` itself for a pick on an absent expert;
+    ``wts`` (T, k) float32; ``w13`` (held, d, 2 * width), ``w2`` (held,
+    width, d). Returns (T, d) float32: ``sum_j wts[t, j] *
+    SwiGLU_{local[t, j]}(x[t])`` over the held picks.
+
+    Exact at every T with static shapes: the T * k picks are sorted by
+    expert (absent ones last, in a group that is never computed), and one
+    grouped matmul a projection (``megablox.gmm``, rows of a group against
+    that expert's matrix) walks the tiles of the groups that have rows, so
+    an expert nobody chose is never read."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from bigdl_tpu.ops.attention_kernel import _interpret
+    (T, d), k, held = x.shape, local.shape[1], w13.shape[0]
+    width = w2.shape[1]
+    m = T * k
+    tm = 128 if m >= 128 else -(-m // 16) * 16
+    m_pad = -(-m // tm) * tm
+    key = jnp.pad(local.reshape(m), (0, m_pad - m), constant_values=held)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.bincount(key, length=held + 1).astype(jnp.int32)
+    xs = jnp.take(x, jnp.minimum(order // k, T - 1), axis=0)
+    call = functools.partial(gmm, group_sizes=sizes,
+                             preferred_element_type=jnp.float32,
+                             interpret=_interpret())
+    gu = call(xs, w13.astype(x.dtype), tiling=(
+        tm, min(d, 1024), _tile(2 * width, (1280, 1024, 512, 256, 128))))
+    act = (jax.nn.silu(gu[:, :width]) * gu[:, width:]).astype(x.dtype)
+    y = call(act, w2.astype(x.dtype), tiling=(
+        tm, min(width, 1280), _tile(d, (1024, 512, 256, 128))))
+    y = jnp.take(y, jnp.argsort(order)[:m], axis=0).reshape(T, k, d)
+    return jnp.sum(jnp.where((local < held)[..., None],
+                             y * wts[..., None], 0.0), axis=1)
+
+
+@jax.custom_batching.custom_vmap
+def routed_experts(x, local, wts, w13, w2):
+    """:func:`_routed_experts`, with a batching rule of its own: under
+    ``vmap`` (``DecodeEngine``'s step is a ``vmap`` over slots of a
+    one-token model) the batch's tokens are one token axis of one grouped
+    product, so a step reads each chosen expert once for all slots."""
+    return _routed_experts(x, local, wts, w13, w2)
+
+
+@routed_experts.def_vmap
+def _routed_experts_vmap(axis_size, in_batched, x, local, wts, w13, w2):
+    if in_batched[3] or in_batched[4]:
+        raise NotImplementedError("routed_experts: a batch of expert stacks")
+    x, local, wts = (t if b else jnp.broadcast_to(t, (axis_size,) + t.shape)
+                     for t, b in zip((x, local, wts), in_batched))
+    flat = lambda t: t.reshape((-1,) + t.shape[2:])
+    out = routed_experts(flat(x), flat(local), flat(wts), w13, w2)
+    return out.reshape(x.shape[:2] + out.shape[1:]), True
+
+
+class RoutedFFN(SimpleModule):
+    """The routed feed-forward layer of a mixture-of-experts LM (the
+    ``glm4_moe`` / DeepSeek-V3 router): ``s = sigmoid(x Wr)`` over all
+    ``num_experts``, in float32; the ``top_k`` chosen are the largest of
+    ``s + b`` (``b`` a selection bias used for the choice only); weights
+    ``s_e / sum_chosen s`` times ``scale``; ``out = sum_chosen w_e
+    SwiGLU_e(x) + Shared(x)``, the shared expert a SwiGLU of
+    ``shared_width``.
+
+    The layer holds experts ``share * held .. share * held + held - 1``
+    only (``held`` = ``num_experts``: all of them). Choice and weights are
+    over all ``num_experts``; the sum runs over the chosen experts that
+    are held, and what the absent ones would have added is left out: one
+    chip's part of an expert-parallel layer, without the exchange.
+
+    ``forward`` returns ``(out, picked)``: ``picked`` (..., words) uint32,
+    bit ``i % 32`` of word ``i // 32`` set where the token chose held
+    expert ``i``."""
+
+    def __init__(self, d_model: int, width: int, num_experts: int,
+                 top_k: int, held: Optional[int] = None, share: int = 0,
+                 shared_width: int = 0, scale: float = 1.0,
+                 init_std: float = 0.02, name: Optional[str] = None):
+        super().__init__(name)
+        held = num_experts if held is None else held
+        if not 0 < held <= num_experts or num_experts % held:
+            raise ValueError(f"held {held} does not divide num_experts "
+                             f"{num_experts}")
+        if not 0 <= share < num_experts // held:
+            raise ValueError(f"share {share}: {num_experts // held} shares "
+                             f"of {held} experts")
+        self.d_model, self.width, self.num_experts = (d_model, width,
+                                                      num_experts)
+        self.top_k, self.held, self.share = top_k, held, share
+        self.shared_width, self.scale = shared_width, scale
+        self.init_std = init_std
+        self.words = -(-held // 32)
+
+    def init(self, rng):
+        ks = jax.random.split(rng, 5)
+        d, w, sw = self.d_model, self.width, self.shared_width
+        mk = lambda k, shape: self.init_std * jax.random.normal(k, shape)
+        out = {"router": {"weight": mk(ks[0], (d, self.num_experts)),
+                          "bias": jnp.zeros((self.num_experts,))},
+               "w13": mk(ks[1], (self.held, d, 2 * w)),
+               "w2": mk(ks[2], (self.held, w, d))}
+        if sw:
+            out["shared_w13"] = mk(ks[3], (d, 2 * sw))
+            out["shared_w2"] = mk(ks[4], (sw, d))
+        return out
+
+    def scores(self, params, x):
+        """x (T, d) -> sigmoid scores (T, num_experts); the product in
+        float32 at ``highest`` precision whatever ``x``'s dtype."""
+        f32 = jnp.float32
+        return jax.nn.sigmoid(jnp.dot(
+            x.astype(f32), params["router"]["weight"].astype(f32),
+            precision=jax.lax.Precision.HIGHEST))
+
+    def weights(self, s, idx):
+        """The chosen experts' scores, renormalised, times ``scale``."""
+        w = jnp.take_along_axis(s, idx, axis=-1)
+        return self.scale * w / jnp.sum(w, axis=-1, keepdims=True)
+
+    def route(self, params, x):
+        """x (T, d) -> (chosen expert ids (T, k) int32, weights (T, k)
+        float32), over all ``num_experts``; scores and top-k in float32."""
+        s = self.scores(params, x)
+        _, idx = jax.lax.top_k(
+            s + params["router"]["bias"].astype(jnp.float32), self.top_k)
+        return idx, self.weights(s, idx)
+
+    def _forward(self, params, x, *, training, rng):
+        lead, dt = x.shape[:-1], x.dtype
+        x = x.reshape(-1, self.d_model)
+        with jax.named_scope("moe_route"):
+            idx, wts = self.route(params, x)
+            local = idx - self.share * self.held
+            local = jnp.where((local >= 0) & (local < self.held), local,
+                              self.held)
+            bits = jnp.where(
+                (local[..., None] // 32 == jnp.arange(self.words))
+                & (local[..., None] < self.held),
+                jnp.uint32(1) << (local[..., None] % 32).astype(jnp.uint32),
+                jnp.uint32(0))
+            picked = jnp.sum(bits, axis=1, dtype=jnp.uint32)
+        with jax.named_scope("moe_experts"):
+            out = routed_experts(x, local, wts, params["w13"], params["w2"])
+        if self.shared_width:
+            with jax.named_scope("moe_shared"):
+                gu = jnp.dot(x, params["shared_w13"].astype(dt),
+                             preferred_element_type=jnp.float32)
+                sw = self.shared_width
+                act = (jax.nn.silu(gu[:, :sw]) * gu[:, sw:]).astype(dt)
+                out = out + jnp.dot(act, params["shared_w2"].astype(dt),
+                                    preferred_element_type=jnp.float32)
+        return (out.astype(dt).reshape(*lead, self.d_model),
+                picked.reshape(*lead, self.words))

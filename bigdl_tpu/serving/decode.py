@@ -1,5 +1,5 @@
-"""Incremental generation for the LMs (``transformer_lm``, ``sambay_lm``)
-with a preallocated cache and continuous-batching slots.
+"""Incremental generation for the LMs (``transformer_lm``, ``sambay_lm``,
+``hybrid_moe_lm``) with a preallocated cache and continuous-batching slots.
 
 What a slot may hold is the model's to say: the engine asks it for
 ``init_cache(batch, max_len, dtype)`` and ``prompt_buckets(max_len,
@@ -8,12 +8,20 @@ as their first axis. ``TransformerLM`` answers with K and V of ``max_len``
 rows a layer. ``SambaYLM`` answers with four kinds of leaf side by side: a
 ring of ``window`` K/V rows for each window layer, ``max_len`` K/V rows
 for its one shared layer, and for each state-space layer a float32 scan
-state and the convolution's last rows. A prefill hands over a whole slot
+state and the convolution's last rows. ``HybridMoELM`` answers with
+``max_len`` K/V rows for each softmax layer and, for each linear-attention
+layer, a float32 matrix state a head and the convolution's last rows; its
+step also counts on the device (which held experts each slot's token
+chose), and a model that offers ``decode_logits_stats`` /
+``step_counters`` / ``count_step`` has those counts added to the engine's
+counters when the step retires. A prefill hands over a whole slot
 (``_write_slot``), so a reused slot keeps nothing of the last request, and
 the model's ``prefill_logits`` stops every leaf at the prompt's last real
 token whatever the bucket's padding. Page pools, the prefix cache,
-speculation, kv8 and tp placement below assume per-layer K/V rows: for a
-model that declares ``recurrent_state`` the engine refuses them.
+speculation, kv8 and tp placement below assume per-layer K/V rows and
+dense MLP weights: for a model that declares ``recurrent_state`` the
+engine refuses them, and names the routed expert stack beside the state
+where a model declares ``routed_experts``.
 
 ``TransformerLM.generate`` is the offline shape of decoding: one request,
 one fori_loop, prompt and token budget baked into the compile. An online
@@ -128,9 +136,11 @@ class DecodeRequest:
 
 
 # A plain step the device has been handed and the host has not read:
-# the device array of its tokens, and the (slot, request, position) of
-# every slot it advanced.
-_Flight = collections.namedtuple("_Flight", "toks advanced")
+# the device array of its tokens, the (slot, request, position) of every
+# slot it advanced, and what the model's step counted on the device (None
+# for a model that counts nothing).
+_Flight = collections.namedtuple("_Flight", "toks advanced stats",
+                                 defaults=(None,))
 
 
 class DecodeEngine:
@@ -211,7 +221,9 @@ class DecodeEngine:
         self.model = model
         if getattr(model, "recurrent_state", False):
             # a slot of such a model holds a scan state and a ring beside
-            # its K/V rows; only the dense slab carries those
+            # its K/V rows; only the dense slab carries those. Routed
+            # experts are a stack no 8-bit form or tp layout knows
+            experts = getattr(model, "routed_experts", False)
             missing = [why for on, why in (
                 (kv_page_tokens, "kv_page_tokens: page pools hold "
                                  "per-layer K/V rows only "
@@ -224,13 +236,20 @@ class DecodeEngine:
                             "(serving/spec_decode.py)"),
                 (quantize not in (None, "off"),
                  "quantize: no 8-bit form of the state-space weights or "
-                 "of the state (serving/quant.py)"),
-                (mesh is not None, "mesh: no tp layout for the scan "
-                                   "(serving/sharding.py)")) if on]
+                 "of the state" + (", nor of the routed expert stack"
+                                   if experts else "")
+                 + " (serving/quant.py)"),
+                (mesh is not None,
+                 "mesh: no tp layout for the scan"
+                 + (", nor for the routed expert stack and its exchange"
+                    if experts else "") + " (serving/sharding.py)"))
+                if on]
             if missing:
                 raise ValueError(
                     f"{type(model).__name__} keeps recurrent state in its "
-                    "slots and serves on the dense path only; not "
+                    "slots" + (" and routed expert stacks in its layers"
+                               if experts else "")
+                    + " and serves on the dense path only; not "
                     "supported yet: " + "; ".join(missing))
         # ---- quantized serving (ISSUE 17): weights go 8-bit BEFORE tp
         # placement so each scale vector ships to the mesh alongside its
@@ -363,6 +382,7 @@ class DecodeEngine:
             self._m_expired = self._m_dead = self._m_cancelled = None
             self._m_spec_prop = self._m_spec_acc = None
             self._m_draft_steps = None
+            self._m_model = {}
             return
         self._m_tokens = metrics.counter(
             "generated_tokens_total", "decode tokens emitted")
@@ -384,6 +404,10 @@ class DecodeEngine:
             "of those steps, the ones dispatched while an earlier step's "
             "tokens were still unread (over decode_steps_total: how often "
             "the decode loop runs ahead of its own emit)")
+        # what the model's own step counts (``step_counters``: name -> help)
+        self._m_model = {
+            name: metrics.counter(name, text) for name, text in
+            getattr(self.model, "step_counters", {}).items()}
         self._m_dropped = metrics.counter(
             "decode_dropped_tokens_total",
             "tokens a run-ahead step computed for a slot whose request "
@@ -642,16 +666,19 @@ class DecodeEngine:
             return prog
         jax, jnp = self._jax, self._jnp
         model, sample = self.model, self._sample_fn(warp)
+        # a model whose step counts on the device: a fourth output
+        decode = getattr(model, "decode_logits_stats", model.decode_logits)
 
         if not self.paged:
             def _one(params, logits, cache1, pos, temp, topk, topp, seed):
                 tok = sample(logits, pos, temp, topk, topp, seed)
                 cache_b = jax.tree_util.tree_map(lambda a: a[None], cache1)
                 with self._row_writes(self._row_write):
-                    lg, cache_b = model.decode_logits(
+                    lg, cache_b, *stats = decode(
                         params, tok[None, None], cache_b, pos)
                 return (tok, lg[0].astype(jnp.float32),
-                        jax.tree_util.tree_map(lambda a: a[0], cache_b))
+                        jax.tree_util.tree_map(lambda a: a[0], cache_b),
+                        *(st[0] for st in stats))
 
             prog = jax.jit(
                 jax.vmap(_one, in_axes=(None, 0, 0, 0, 0, 0, 0, 0)),
@@ -1319,9 +1346,18 @@ class DecodeEngine:
             if flight is None:  # the loop's first round after idling
                 return
             with _obs_span("decode_host_read"):
-                toks_host = np.asarray(flight.toks)
+                if flight.stats is None:
+                    toks_host = np.asarray(flight.toks)
+                else:  # the step's counts ride with its tokens: one wait
+                    toks_host, stats_host = self._jax.device_get(
+                        (flight.toks, flight.stats))
         with _obs_span("decode_emit"):
             self._retire(flight, toks_host)
+            if flight.stats is not None and self._m_model:
+                live = [slot for slot, _, _ in flight.advanced]
+                for name, n in self.model.count_step(
+                        stats_host[live]).items():
+                    self._m_model[name].inc(n)
 
     def _step_args(self, advance, held):
         """The program and the five sampling arrays of a step that
@@ -1339,13 +1375,14 @@ class DecodeEngine:
         positions; the host reads nothing."""
         jnp = self._jnp
         try:
+            stats = ()
             if self.paged:
                 toks, self._logits, self._kv.pools = prog(
                     self.params, self._logits, self._kv.pools,
                     jnp.asarray(self._kv.page_table), pos, temp,
                     topk, topp, seed)
             else:
-                toks, self._logits, self._cache = prog(
+                toks, self._logits, self._cache, *stats = prog(
                     self.params, self._logits, self._cache, pos,
                     temp, topk, topp, seed)
         except Exception as e:
@@ -1360,7 +1397,7 @@ class DecodeEngine:
             self._m_runahead.inc()
         advanced = [(i, self._reqs[i], int(self._pos[i])) for i in advance]
         self._pos[advance] += 1
-        return _Flight(toks, advanced)
+        return _Flight(toks, advanced, stats[0] if stats else None)
 
     def _retire(self, flight: _Flight, toks_host) -> None:
         """Emit a dispatched step's tokens, each to the request it was

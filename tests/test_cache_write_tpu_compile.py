@@ -1,5 +1,6 @@
-"""``cache_write_rows`` compiled for a described v5e at the benchmark's
-real leaf shapes: what interpret mode cannot refuse (tiling, fast memory,
+"""The decode step's kernels compiled for a described v5e at the
+benchmark's real shapes (``cache_write_rows``, and the routed experts'
+grouped matmuls): what interpret mode cannot refuse (tiling, fast memory,
 the alias). No chip is needed and nothing runs; where the topology cannot
 be described here the tests skip. The topology is described inside a
 fixture, never at import (one process at a time may load the TPU's
@@ -48,3 +49,24 @@ def test_the_kernel_compiles_in_place_for_v5e(one_chip, monkeypatch, shape,
     # its size exists
     assert mem.alias_size_in_bytes == cache_bytes
     assert mem.temp_size_in_bytes < cache_bytes // 100
+
+
+@pytest.mark.parametrize("tokens", [64, 512], ids=["decode_64_slots",
+                                                   "prefill_512"])
+def test_the_routed_experts_compile_for_v5e(one_chip, monkeypatch, tokens):
+    """The routed layer's grouped matmuls (``nn/moe.py``, jax's
+    ``megablox.gmm``) at Solar-Open2-250B's widths and this chip's 40
+    held experts: two Mosaic calls, and no copy of an expert stack."""
+    from bigdl_tpu.nn import moe
+    from bigdl_tpu.ops import attention_kernel
+    monkeypatch.setattr(attention_kernel, "_interpret", lambda: False)
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    compiled = jax.jit(moe._routed_experts).lower(
+        sds((tokens, 4096), jnp.bfloat16), sds((tokens, 8), jnp.int32),
+        sds((tokens, 8), jnp.float32),
+        sds((40, 4096, 2560), jnp.bfloat16),
+        sds((40, 1280, 4096), jnp.bfloat16)).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 2
+    stacks = 40 * 3 * 4096 * 1280 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < stacks // 10
