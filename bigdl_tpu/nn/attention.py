@@ -186,20 +186,20 @@ def grouped_cache_attention(q, kc, vc, idx):
     """``q`` (b, h, m, d) at absolute positions idx..idx+m-1 against a
     cache ``kc``, ``vc`` (b, n_kv, S, d) that already holds those rows:
     causal softmax attention read at the cache's own head count (the
-    fold ``MultiHeadAttention.decode_chunk`` describes). Returns
-    (b, h, m, d) float32."""
+    fold ``MultiHeadAttention.decode_chunk`` describes: the heads of a
+    group and the chunk's rows are the rows of one contraction a KV
+    head), float32 scores, mask and softmax, the probabilities cast to
+    the activations' dtype. It is ``ops.cache_attention.attend_rows``
+    with rows 0..idx+m-1 live: alone the plain form over all S rows; its
+    batching rule bounds a one-token step's read by each slot's live
+    rows (an m > 1 chunk, whose rows need a causal mask each, is read
+    whole).
+
+    Returns (b, h, m, d) float32."""
+    from bigdl_tpu.ops.cache_attention import attend_rows
     b, h, m, d = q.shape
-    q = q.reshape(b, kc.shape[1], -1, d)
-    s = jnp.einsum("bkrd,bksd->bkrs", q, kc.astype(q.dtype),
-                   preferred_element_type=jnp.float32)
-    s = s / (d ** 0.5)
-    rows = idx + (jnp.arange(q.shape[2]) % m)[None, None, :, None]
-    live = jnp.arange(kc.shape[2])[None, None, None, :] <= rows
-    s = jnp.where(live, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkrs,bksd->bkrd", p.astype(q.dtype),
-                   vc.astype(q.dtype),
-                   preferred_element_type=jnp.float32)
+    o = attend_rows(q.reshape(b, kc.shape[1], -1, d), kc, vc, idx + m,
+                    1.0 / math.sqrt(d), m)
     return o.reshape(b, h, m, d)
 
 
@@ -395,14 +395,14 @@ class MultiHeadAttention(SimpleModule):
         case IS decode_step (and per-row results match the sequential
         path bit-for-bit on the dense CPU path — pinned in tests).
 
-        The cache is read once, at its stored head count: the
-        g = num_heads // num_kv_heads query heads of a group and the m
-        chunk rows fold into one row axis (row r = j*m + i: head j of
-        the group, chunk position i = r % m), and both contractions are
-        matmuls of those g*m rows against the group's (S, d) K and V,
-        batched over (b, n_kv), operands in the activations' dtype with
-        f32 accumulation. No expanded copy of K/V exists; g == 1 (plain
-        multi-head) is the same code with a fold that moves nothing.
+        The cache is read once, at its stored head count: the g heads
+        of a group and the m chunk rows fold into one row axis (row
+        r = j*m + i: head j, chunk position i), both contractions are
+        matmuls of those rows against the group's (S, d) K and V, in the
+        activations' dtype with f32 accumulation, and no expanded copy
+        of K/V exists. Alone, all S rows are read and the dead ones
+        masked; under the engine's ``vmap`` a one-token step reads each
+        slot's live rows only (``ops.cache_attention``'s batching rule).
 
         Caller must keep idx + m <= cache length: ``write_rows`` is a
         dynamic_update_slice, which clamps out-of-range starts and would
@@ -977,12 +977,12 @@ class DifferentialAttention(SimpleModule):
             from bigdl_tpu.ops.cache_write import write_rows
             new = {n: write_rows(cache[n], t, at)
                    for n, t in (("k", k), ("v", v))}
-        rows = jnp.arange(new["k"].shape[2])
-        live = rows < jnp.minimum(idx + 1, rows.shape[0]) if self.window \
-            else rows <= idx
+        from bigdl_tpu.ops.cache_attention import attend_rows
+        count = jnp.minimum(idx + 1, self.window) if self.window else idx + 1
         q2 = self._halves(self.project_q(params, x))
-        a = self._scores_softmax_v(q2, new["k"], new["v"], live)
-        return self._difference(params, a, x.dtype), new
+        a = attend_rows(q2[:, :, :, 0], new["k"], new["v"], count,
+                        1.0 / math.sqrt(self.head_dim))
+        return self._difference(params, a[:, :, :, None], x.dtype), new
 
 
 __all__.append("DifferentialAttention")

@@ -45,23 +45,28 @@ def row_tile(dtype) -> int:
 
 class _StepTrace(threading.local):
     kernel = True    # False: a mesh, where the kernel cannot be partitioned
-    chosen = None    # the engine's list of the forms the rule chose
+    bounded = True   # False: a paged view, which ops.cache_attention reads whole
+    chosen = None    # the engine's list of the forms the rules chose
 
 
 _trace = _StepTrace()
 
 
 @contextlib.contextmanager
-def step_trace(chosen=None, kernel: bool = True):
+def step_trace(chosen=None, kernel: bool = True, bounded: bool = True):
     """Around the tracing of a vmapped step. The rule appends the form it
-    chose for each write (``"batched"`` or ``"scatter"``) to ``chosen``;
-    ``kernel=False`` (an engine with a mesh) keeps it to the scatter."""
-    old = _trace.kernel, _trace.chosen
-    _trace.kernel, _trace.chosen = kernel, chosen
+    chose for each write (``"batched"`` or ``"scatter"``) to ``chosen``,
+    and ``ops.cache_attention``'s rule the form of each read
+    (``"bounded"`` or ``"whole"``); ``kernel=False`` (an engine with a
+    mesh) keeps them to the scatter and the whole read,
+    ``bounded=False`` (a step over a gathered copy of paged rows) the
+    read alone."""
+    old = _trace.kernel, _trace.bounded, _trace.chosen
+    _trace.kernel, _trace.bounded, _trace.chosen = kernel, bounded, chosen
     try:
         yield
     finally:
-        _trace.kernel, _trace.chosen = old
+        _trace.kernel, _trace.bounded, _trace.chosen = old
 
 
 def _plain(cache, rows, at):

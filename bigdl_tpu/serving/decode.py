@@ -43,17 +43,17 @@ batching):
   requests of different lengths and arrival times share the batch. A
   finishing request frees its slot; the next waiting request prefills
   into it while the others keep decoding. A step reads every weight and,
-  in attention, the whole cache once at its stored ``num_kv_heads``
-  (``MultiHeadAttention.decode_chunk``: the query heads of a group are
-  rows of one matmul against that group's K and V): all ``max_len``
-  positions of every slot, whatever is live.
+  in attention, each slot's LIVE cache rows once at their stored
+  ``num_kv_heads`` (``MultiHeadAttention.decode_chunk``): one kernel call
+  a reading layer, ``ceil(position / 512)`` row blocks of a slot and not
+  its ``max_len`` rows (``ops.cache_attention.attend_rows``, whose
+  batching rule replaces the whole-cache read ``vmap`` would make).
   ``decode_live_positions_total`` over ``decode_steps_total`` says how
-  many of them a step needed. The step writes each slot's new K and V
-  rows at that slot's own position with one in-place kernel call a cache
-  leaf (``ops.cache_write.write_rows``, whose batching rule replaces the
-  serial scatter loop ``vmap`` would make of the per-slot write; an
-  engine with a mesh keeps the scatter). ``/debug/slots`` says which
-  under ``kv.row_write`` once the step has been traced.
+  many rows a step needed. The step writes each slot's new K and V rows
+  at that slot's own position with one in-place kernel call a cache leaf
+  (``ops.cache_write.write_rows``, likewise). An engine with a mesh
+  keeps the scatter and the whole read, a paged engine the whole read:
+  ``/debug/slots`` says which (``kv.row_write``, ``kv.cache_read``).
 
 ISSUE 14 rebuilt the hot path around three composable optimisations:
 
@@ -617,9 +617,9 @@ class DecodeEngine:
         self._accept_programs: dict = {}
         self._suffix_programs: dict = {}
         self._draft_step_jit = None
-        # what the row write's batching rule chose, a written leaf, when
-        # the plain step was traced (/debug/slots "row_write")
-        self._row_write: list = []
+        # what the rules of the row write ("batched" | "scatter" a leaf)
+        # and of the cache read ("bounded" | "whole" a layer) chose when
+        self._step_forms: list = []  # the plain step was traced
 
     def _pin(self, *out_sh):
         """``out_shardings=`` kwarg for a jit whose outputs must land in
@@ -631,14 +631,14 @@ class DecodeEngine:
         return {"out_shardings": (out_sh if len(out_sh) > 1
                                   else out_sh[0])}
 
-    def _row_writes(self, chosen=None):
-        """Around a model's one-token step while a vmapped program is
-        traced: ``ops.cache_write``'s batching rule writes every slot's
-        row with one in-place kernel call a leaf, but for a mesh, where
-        a Pallas call cannot be partitioned and the per-slot scatter
-        stays. ``chosen`` collects what the rule chose."""
+    def _step_trace(self, keep: bool = False):
+        """``ops.cache_write.step_trace`` around a vmapped step's tracing:
+        no kernel for a mesh, the whole read over a paged step's pages."""
         from bigdl_tpu.ops.cache_write import step_trace
-        return step_trace(chosen, kernel=self._shard is None)
+        if keep:  # /debug/slots shows the plain step traced last
+            self._step_forms.clear()
+        return step_trace(self._step_forms if keep else None,
+                          self._shard is None, not self.paged)
 
     def _sample_fn(self, warp: bool):
         jax, jnp = self._jax, self._jnp
@@ -673,7 +673,7 @@ class DecodeEngine:
             def _one(params, logits, cache1, pos, temp, topk, topp, seed):
                 tok = sample(logits, pos, temp, topk, topp, seed)
                 cache_b = jax.tree_util.tree_map(lambda a: a[None], cache1)
-                with self._row_writes(self._row_write):
+                with self._step_trace(keep=True):
                     lg, cache_b, *stats = decode(
                         params, tok[None, None], cache_b, pos)
                 return (tok, lg[0].astype(jnp.float32),
@@ -695,7 +695,7 @@ class DecodeEngine:
                     cache1 = _kvp.gather_cache(pools, pages)
                     cache_b = jax.tree_util.tree_map(
                         lambda a: a[None], cache1)
-                    with self._row_writes(self._row_write):
+                    with self._step_trace(keep=True):
                         lg, cache_b = model.decode_logits(
                             params, tok[None, None], cache_b, pos)
                     tok_kv = jax.tree_util.tree_map(
@@ -727,7 +727,7 @@ class DecodeEngine:
 
         def _one(dparams, tok, cache1, pos, temp, topk, topp, seed):
             cache_b = jax.tree_util.tree_map(lambda a: a[None], cache1)
-            with self._row_writes():
+            with self._step_trace():
                 lg, cache_b = dmodel.decode_logits(
                     dparams, tok[None, None], cache_b, pos)
             prop, q = _spec.draft_propose(lg[0].astype(jnp.float32),
@@ -1534,10 +1534,12 @@ class DecodeEngine:
                    "worker_up": self._worker_error is None,
                    "tp": self._shard.n_shard if self._shard else 1,
                    "kv": {"paged": self.paged}}
-            if self._row_write:  # known once the step has been traced
+            if self._step_forms:  # known once the step has been traced
                 out["kv"]["row_write"] = (
-                    "batched" if "scatter" not in self._row_write
+                    "batched" if "scatter" not in self._step_forms
                     else "scatter")
+                out["kv"]["cache_read"] = [
+                    f for f in self._step_forms if f in ("bounded", "whole")]
             if not self.paged:
                 out["kv"]["bytes_by_kind"] = self.cache_bytes_by_kind()
             else:
@@ -1764,4 +1766,5 @@ def abstract_decode_engine(model, *, slots: int = 4,
     eng._accept_programs = {}
     eng._suffix_programs = {}
     eng._draft_step_jit = None
+    eng._step_forms = []
     return eng

@@ -1,11 +1,13 @@
 """The decode step's kernels compiled for a described v5e at the
-benchmark's real shapes (``cache_write_rows``, and the routed experts'
-grouped matmuls): what interpret mode cannot refuse (tiling, fast memory,
-the alias). No chip is needed and nothing runs; where the topology cannot
-be described here the tests skip. The topology is described inside a
+benchmark's real shapes (``cache_write_rows``, ``cache_attend_rows``, and
+the routed experts' grouped matmuls): what interpret mode cannot refuse
+(tiling, fast memory, the alias). No chip is needed and nothing runs;
+where the topology cannot be described here the tests skip. The topology
+is described inside a
 fixture, never at import (one process at a time may load the TPU's
 library, and every xdist worker imports this file)."""
 
+import functools
 import os
 
 import jax
@@ -13,6 +15,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from bigdl_tpu.ops import cache_attention as ca
 from bigdl_tpu.ops import cache_write as cw
 
 
@@ -49,6 +52,32 @@ def test_the_kernel_compiles_in_place_for_v5e(one_chip, monkeypatch, shape,
     # its size exists
     assert mem.alias_size_in_bytes == cache_bytes
     assert mem.temp_size_in_bytes < cache_bytes // 100
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("shape,r", [((48, 2, 4096, 128), 12),
+                                     ((64, 10, 4096, 128), 4),
+                                     ((64, 10, 512, 128), 4),
+                                     ((64, 8, 4096, 128), 8)],
+                         ids=["starcoder2", "sambay_full", "sambay_ring",
+                              "solar_open2"])
+def test_the_bounded_read_compiles_for_v5e(one_chip, monkeypatch, shape, r,
+                                           dtype):
+    """``cache_attend_rows`` at the cells' shapes: one Mosaic call that
+    leaves the caches where they are (no copy of one among the program's
+    temporaries) and fits the VMEM it asks for."""
+    monkeypatch.setattr(ca, "_interpret", lambda: False)
+    S, kh, T, d = shape
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    compiled = jax.jit(functools.partial(
+        ca._attend_bounded, scale=d ** -0.5)).lower(
+        sds((S, kh, r, d), dtype), sds(shape, dtype), sds(shape, dtype),
+        sds((S,), jnp.int32)).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    cache_bytes = S * kh * T * d * jnp.dtype(dtype).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes // 20
 
 
 @pytest.mark.parametrize("tokens", [64, 512], ids=["decode_64_slots",
