@@ -203,6 +203,13 @@ def build_model(name: str, class_num: int = 1000, seq_len=None,
             num_heads=4, num_kv_heads=2, head_dim=64, gate_rank=32,
             num_experts=16, top_k=4, expert_width=128, max_len=512),
         "solar_open2": lambda: _hybrid_moe("solar_open2", max_len=4096),
+        # the same class under its second configuration: plain GQA by two
+        # layout lists (a NoPE full-attention layer, then three RoPE
+        # layers with a 4,096-row window ring), an early softmax-top-k
+        # router over 64 ReGLU experts, all held. SmallThinker-21BA3B's
+        # published widths, 8 of 52 layers (3.97B parameters: serve it
+        # with --bf16)
+        "smallthinker": lambda: _hybrid_moe("smallthinker", max_len=16384),
     }
     if name not in table:
         raise SystemExit(f"unknown model {name}; choose from {list(table)}")
@@ -217,7 +224,8 @@ def build_model(name: str, class_num: int = 1000, seq_len=None,
             "sambay_lm": (seq_len or 512,),
             "phi4_mini_flash": (seq_len or 4096,),
             "hybrid_moe_lm": (seq_len or 512,),
-            "solar_open2": (seq_len or 4096,)}.get(name, (224, 224, 3))
+            "solar_open2": (seq_len or 4096,),
+            "smallthinker": (seq_len or 16384,)}.get(name, (224, 224, 3))
     # LM build overrides (tpulint): forced attn_impl and/or seq length
     # apply only to transformer_lm* names and only for this one call
     global _LM_OVERRIDE

@@ -10,6 +10,8 @@ compiles, and (for LMs) continuous-batching KV-cache decode:
     bigdl-tpu serve transformer_lm --model ckpt_dir --slots 8 --bf16
     bigdl-tpu serve phi4_mini_flash --randomInit --bf16 --slots 64 \
         --buckets 1 --seq 256
+    bigdl-tpu serve smallthinker --randomInit --bf16 --slots 32 \
+        --buckets 1 --seq 256
     bigdl-tpu serve solar_open2 --randomInit --bf16 --slots 64 \
         --buckets 1 --seq 256
     curl -d '{"tokens": [3, 1, 4], "max_new_tokens": 8}' \
@@ -304,12 +306,16 @@ def build_app(args):
         groups = replica_device_groups(n_replicas, tp_k)
         mesh0 = serving_mesh(groups[0])
 
-    if getattr(model, "recurrent_state", False) and (
+    state = getattr(model, "recurrent_state", False)
+    ring = bool(getattr(model, "window", None))
+    if (state or ring) and (
             args.kvPageTokens or args.prefixCache or args.speculate
             or (getattr(args, "quantize", None) or "off") != "off"
             or strategy):
         raise SystemExit(
-            f"{name} keeps recurrent state in its decode slots"
+            f"{name} keeps "
+            + ("recurrent state" if state else "window rings")
+            + " in its decode slots"
             + (" and routed expert stacks in its layers"
                if getattr(model, "routed_experts", False) else "")
             + " and serves on the dense path only: --kvPageTokens, "

@@ -20,5 +20,5 @@ from bigdl_tpu.models.transformer_lm import (
 )
 from bigdl_tpu.models.sambay_lm import SambaYLM, sambay_lm
 from bigdl_tpu.models.hybrid_moe_lm import (
-    HybridMoELM, hybrid_moe_lm, solar_open2,
+    HybridMoELM, hybrid_moe_lm, smallthinker, solar_open2,
 )

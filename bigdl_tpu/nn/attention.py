@@ -41,6 +41,7 @@ __all__ = [
     "RMSNorm",
     "grouped_cache_attention",
     "MultiHeadAttention",
+    "CausalGQA",
     "GatedAttention",
     "PositionalEncoding",
     "TransformerEncoderLayer",
@@ -420,20 +421,35 @@ class MultiHeadAttention(SimpleModule):
                 {"k": kc, "v": vc})
 
 
-class GatedAttention(SimpleModule):
-    """Causal softmax GQA without positions and with an output gate
-    (Qwen3-Next's gated attention, as Solar Open 2's softmax layers use
-    it): ``out = (softmax(q k^T / sqrt(head_dim)) v * sigmoid(x Wg)) Wo``,
-    the gate elementwise on the concatenated heads; no bias, no rotation,
-    and ``num_heads * head_dim`` need not be ``d_model``. Prefill runs
-    ``attn_impl`` (``"flash"``: the Pallas forward kernel) on K/V copied
-    out to the query head count; a decode step writes its row with
-    ``write_rows`` and reads the cache grouped
-    (:func:`grouped_cache_attention`)."""
+class CausalGQA(SimpleModule):
+    """Causal softmax GQA with no bias and no q/k norm, ``num_heads *
+    head_dim`` free of ``d_model``, and three things a layer may or may
+    not have:
+
+    * ``gate``: an elementwise output gate (Qwen3-Next's gated attention,
+      as Solar Open 2's softmax layers use it): ``out = (softmax(q k^T /
+      sqrt(head_dim)) v * sigmoid(x Wg)) Wo``, the gate on the
+      concatenated heads;
+    * ``rope_theta``: q and k rotated at their absolute positions
+      (half-split pairing; angles and rotation in float32), None for no
+      positional encoding. K is cached **rotated**, so the order of rows
+      in a cache is free;
+    * ``window``: query ``i`` sees keys ``j`` with ``i - window < j <=
+      i``, and a slot holds a ring of ``min(window, max_len)`` rows,
+      position ``p`` at row ``p % ring``, of which the first ``min(p + 1,
+      ring)`` are live: a validity count is the whole mask.
+
+    Prefill runs ``attn_impl`` (``"flash"``: the Pallas forward kernel,
+    with its ``window`` where the prompt's bucket is longer than the
+    window; a mask on the dense attention otherwise) on K/V copied out to
+    the query head count; a decode step writes its row with ``write_rows``
+    and reads the cache grouped (``ops.cache_attention.attend_rows``)."""
 
     def __init__(self, d_model: int, num_heads: int, num_kv_heads: int,
                  head_dim: int, attn_impl: Optional[AttnFn | str] = None,
-                 init_std: float = 0.02, name: Optional[str] = None):
+                 init_std: float = 0.02, gate: bool = False,
+                 rope_theta: Optional[float] = None,
+                 window: Optional[int] = None, name: Optional[str] = None):
         super().__init__(name)
         if num_heads % num_kv_heads:
             raise ValueError(f"num_heads {num_heads} not divisible by "
@@ -441,6 +457,7 @@ class GatedAttention(SimpleModule):
         self.d_model, self.head_dim = d_model, head_dim
         self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
         self.init_std = init_std
+        self.gate, self.rope_theta, self.window = gate, rope_theta, window
         if attn_impl == "flash":
             from bigdl_tpu.ops import flash_attention
             attn_impl = flash_attention
@@ -451,63 +468,130 @@ class GatedAttention(SimpleModule):
         d, hq = self.d_model, self.num_heads * self.head_dim
         hkv = self.num_kv_heads * self.head_dim
         mk = lambda k, shape: self.init_std * jax.random.normal(k, shape)
-        return {"wq": mk(ks[0], (d, hq)), "wk": mk(ks[1], (d, hkv)),
-                "wv": mk(ks[2], (d, hkv)), "wg": mk(ks[3], (d, hq)),
-                "wo": mk(ks[4], (hq, d))}
+        out = {"wq": mk(ks[0], (d, hq)), "wk": mk(ks[1], (d, hkv)),
+               "wv": mk(ks[2], (d, hkv)), "wo": mk(ks[4], (hq, d))}
+        if self.gate:
+            out["wg"] = mk(ks[3], (d, hq))
+        return out
 
     def init_cache(self, batch: int, max_len: int, dtype=jnp.float32):
-        shape = (batch, self.num_kv_heads, max_len, self.head_dim)
+        rows = min(self.window, max_len) if self.window else max_len
+        shape = (batch, self.num_kv_heads, rows, self.head_dim)
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
     def _heads(self, x, n):
         b, s, _ = x.shape
         return x.reshape(b, s, n, self.head_dim).transpose(0, 2, 1, 3)
 
-    def _qkv(self, params, x):
+    def _rotate(self, x, pos):
+        """x (b, h, s, d) rotated at absolute positions ``pos`` (s,)."""
+        if self.rope_theta is None:
+            return x
+        half = self.head_dim // 2
+        inv = 1.0 / (self.rope_theta ** (
+            jnp.arange(0, self.head_dim, 2, dtype=jnp.float32)
+            / self.head_dim))
+        ang = pos.astype(jnp.float32)[:, None] * inv[None, :]
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        x1 = x[..., :half].astype(jnp.float32)
+        x2 = x[..., half:].astype(jnp.float32)
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                               axis=-1).astype(x.dtype)
+
+    def _qkv(self, params, x, pos):
+        """q, k (rotated at ``pos``) and v of x (b, s, d), by heads."""
         dt = x.dtype
-        return (self._heads(x @ params["wq"].astype(dt), self.num_heads),
-                self._heads(x @ params["wk"].astype(dt), self.num_kv_heads),
-                self._heads(x @ params["wv"].astype(dt), self.num_kv_heads))
+        q = self._heads(x @ params["wq"].astype(dt), self.num_heads)
+        k = self._heads(x @ params["wk"].astype(dt), self.num_kv_heads)
+        v = self._heads(x @ params["wv"].astype(dt), self.num_kv_heads)
+        return self._rotate(q, pos), self._rotate(k, pos), v
 
     def _out(self, params, x, a):
         """a (b, h, s, d) attention output -> gated, through Wo. The gate
         and its product in float32: one rounding on the way into Wo."""
         b, h, s, d = a.shape
         a = a.transpose(0, 2, 1, 3).reshape(b, s, h * d)
-        gate = jax.nn.sigmoid(jnp.dot(
-            x, params["wg"].astype(x.dtype),
-            preferred_element_type=jnp.float32))
-        a = (a.astype(jnp.float32) * gate).astype(x.dtype)
-        return a @ params["wo"].astype(x.dtype)
+        if self.gate:
+            gate = jax.nn.sigmoid(jnp.dot(
+                x, params["wg"].astype(x.dtype),
+                preferred_element_type=jnp.float32))
+            a = a.astype(jnp.float32) * gate
+        return a.astype(x.dtype) @ params["wo"].astype(x.dtype)
 
     def _attend_seq(self, params, x):
-        q, k, v = self._qkv(params, x)
+        s = x.shape[1]
+        q, k, v = self._qkv(params, x, jnp.arange(s))
         g = self.num_heads // self.num_kv_heads
-        a = self.attn_fn(q, jnp.repeat(k, g, axis=1),
-                         jnp.repeat(v, g, axis=1), causal=True, mask=None)
+        kq, vq = jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1)
+        if not self.window or s <= self.window:  # the band is the triangle
+            a = self.attn_fn(q, kq, vq, causal=True, mask=None)
+        elif self.attn_fn is dot_product_attention:
+            from bigdl_tpu.ops.attention_kernel import band_mask
+            a = self.attn_fn(q, kq, vq, mask=band_mask(s, s, self.window))
+        else:
+            a = self.attn_fn(q, kq, vq, causal=True, window=self.window)
         return self._out(params, x, a), k, v
 
     def _forward(self, params, x, *, training, rng):
         return self._attend_seq(params, x)[0]
 
-    def prefill(self, params, x, cache):
-        """Whole-prompt forward that also writes K/V rows 0..s-1 (rows of
-        a bucket's padding are overwritten by decode before they are
-        attended). Returns (out, cache)."""
+    def prefill(self, params, x, cache, last=None):
+        """Whole-prompt forward that also fills the slot's cache. A full
+        layer's K/V go to rows 0..s-1 (rows of a bucket's padding are
+        overwritten by decode before they are attended), and so does a
+        ring's while the bucket is no longer than the ring. A longer
+        bucket leaves in the ring the last rows up to ``last`` (traced;
+        default s-1): row r holds the largest position <= ``last`` that
+        is congruent to r, so a padded bucket's rows never enter it.
+        Returns (out, cache)."""
         out, k, v = self._attend_seq(params, x)
+        s, ring = x.shape[1], cache["k"].shape[2]
+        if s > ring:
+            last = s - 1 if last is None else last
+            src = jnp.clip(last - (last - jnp.arange(ring)) % ring, 0, s - 1)
+            return out, {n: jnp.take(t, src, axis=2).astype(cache[n].dtype)
+                         for n, t in (("k", k), ("v", v))}
         return out, {n: jax.lax.dynamic_update_slice(
             cache[n], t.astype(cache[n].dtype), (0, 0, 0, 0))
             for n, t in (("k", k), ("v", v))}
 
     def decode_step(self, params, x, cache, idx):
         """x (b, m, d) at absolute positions idx..idx+m-1 (m = 1 in the
-        engine's step): writes its K/V rows, then attends over 0..idx."""
+        engine's step, and for a ring always): writes its K/V rows (a
+        ring's at ``idx % ring``), then attends over what is live: rows
+        0..idx, or the ring's first min(idx + 1, ring)."""
         from bigdl_tpu.ops.cache_write import write_rows
-        q, k, v = self._qkv(params, x)
-        new = {"k": write_rows(cache["k"], k, idx),
-               "v": write_rows(cache["v"], v, idx)}
-        a = grouped_cache_attention(q, new["k"], new["v"], idx)
-        return self._out(params, x, a), new
+        m = x.shape[1]
+        q, k, v = self._qkv(params, x, idx + jnp.arange(m))
+        if not self.window:
+            new = {"k": write_rows(cache["k"], k, idx),
+                   "v": write_rows(cache["v"], v, idx)}
+            a = grouped_cache_attention(q, new["k"], new["v"], idx)
+            return self._out(params, x, a), new
+        if m != 1:
+            raise NotImplementedError(
+                "CausalGQA: a window layer's ring takes one row a step")
+        from bigdl_tpu.ops.cache_attention import attend_rows
+        ring = cache["k"].shape[2]
+        new = {"k": write_rows(cache["k"], k, idx % ring),
+               "v": write_rows(cache["v"], v, idx % ring)}
+        b, h, _, d = q.shape
+        a = attend_rows(q.reshape(b, self.num_kv_heads, -1, d), new["k"],
+                        new["v"], jnp.minimum(idx + 1, ring),
+                        1.0 / math.sqrt(d))
+        return self._out(params, x, a.reshape(b, h, 1, d)), new
+
+
+class GatedAttention(CausalGQA):
+    """:class:`CausalGQA` with the output gate, no positions and no
+    window: Solar Open 2's softmax layers."""
+
+    def __init__(self, d_model: int, num_heads: int, num_kv_heads: int,
+                 head_dim: int, attn_impl: Optional[AttnFn | str] = None,
+                 init_std: float = 0.02, name: Optional[str] = None):
+        super().__init__(d_model, num_heads, num_kv_heads, head_dim,
+                         attn_impl=attn_impl, init_std=init_std, gate=True,
+                         name=name)
 
 
 class PositionalEncoding(SimpleModule):

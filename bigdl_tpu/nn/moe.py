@@ -19,8 +19,9 @@ Two classes live here. :class:`MoE` is the MixtureTable parity and its
 sparse, capacity-bounded extension over any expert module: softmax gate,
 tokens above an expert's capacity dropped. :class:`RoutedFFN` is the
 routed feed-forward layer of the language models
-(``models/hybrid_moe_lm.py``): sigmoid scores with a selection bias, top-k
-with renormalised weights, a shared expert, no capacity and no dropped
+(``models/hybrid_moe_lm.py``): sigmoid scores with a selection bias or a
+softmax over the chosen logits, top-k with renormalised weights, SwiGLU or
+ReGLU experts, an optional shared expert, no capacity and no dropped
 token at any token count, and a layer that is told which experts it
 holds (one chip's share of an expert-parallel deployment).
 """
@@ -169,12 +170,20 @@ def _tile(n: int, choices) -> int:
     return next((t for t in choices if n % t == 0), n)
 
 
-def _routed_experts(x, local, wts, w13, w2):
+_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+# tokens a call of the grouped matmuls takes: a longer prompt is walked in
+# blocks of this many (the same numbers: a token's row depends on no
+# other), so the float32 temporaries of T * k rows stop growing with it
+ROW_BLOCK = 4096
+
+
+def _routed_experts(x, local, wts, w13, w2, act="silu"):
     """``x`` (T, d) tokens; ``local`` (T, k) int32 the picks as indices
     into the held stack, ``held`` itself for a pick on an absent expert;
     ``wts`` (T, k) float32; ``w13`` (held, d, 2 * width), ``w2`` (held,
     width, d). Returns (T, d) float32: ``sum_j wts[t, j] *
-    SwiGLU_{local[t, j]}(x[t])`` over the held picks.
+    GLU_{local[t, j]}(x[t])`` over the held picks, the gate's activation
+    ``act`` (``silu``: SwiGLU; ``relu``: ReGLU).
 
     Exact at every T with static shapes: the T * k picks are sorted by
     expert (absent ones last, in a group that is never computed), and one
@@ -185,6 +194,13 @@ def _routed_experts(x, local, wts, w13, w2):
 
     from bigdl_tpu.ops.attention_kernel import _interpret
     (T, d), k, held = x.shape, local.shape[1], w13.shape[0]
+    if T > ROW_BLOCK and T % ROW_BLOCK == 0:
+        blocks = lambda t: t.reshape((T // ROW_BLOCK, ROW_BLOCK)
+                                     + t.shape[1:])
+        out = jax.lax.map(
+            lambda b: _routed_experts(*b, w13, w2, act),
+            (blocks(x), blocks(local), blocks(wts)))
+        return out.reshape(T, d)
     width = w2.shape[1]
     m = T * k
     tm = 128 if m >= 128 else -(-m // 16) * 16
@@ -197,8 +213,9 @@ def _routed_experts(x, local, wts, w13, w2):
                              preferred_element_type=jnp.float32,
                              interpret=_interpret())
     gu = call(xs, w13.astype(x.dtype), tiling=(
-        tm, min(d, 1024), _tile(2 * width, (1280, 1024, 512, 256, 128))))
-    act = (jax.nn.silu(gu[:, :width]) * gu[:, width:]).astype(x.dtype)
+        tm, _tile(d, (1024, 1280, 512, 256, 128)),
+        _tile(2 * width, (1280, 1024, 512, 256, 128))))
+    act = (_ACTS[act](gu[:, :width]) * gu[:, width:]).astype(x.dtype)
     y = call(act, w2.astype(x.dtype), tiling=(
         tm, min(width, 1280), _tile(d, (1024, 512, 256, 128))))
     y = jnp.take(y, jnp.argsort(order)[:m], axis=0).reshape(T, k, d)
@@ -206,34 +223,53 @@ def _routed_experts(x, local, wts, w13, w2):
                              y * wts[..., None], 0.0), axis=1)
 
 
-@jax.custom_batching.custom_vmap
-def routed_experts(x, local, wts, w13, w2):
-    """:func:`_routed_experts`, with a batching rule of its own: under
-    ``vmap`` (``DecodeEngine``'s step is a ``vmap`` over slots of a
-    one-token model) the batch's tokens are one token axis of one grouped
-    product, so a step reads each chosen expert once for all slots."""
-    return _routed_experts(x, local, wts, w13, w2)
+@functools.lru_cache(maxsize=None)
+def _routed_op(act: str):
+    """:func:`_routed_experts` at one activation, with a batching rule of
+    its own: under ``vmap`` (``DecodeEngine``'s step is a ``vmap`` over
+    slots of a one-token model) the batch's tokens are one token axis of
+    one grouped product, so a step reads each chosen expert once for all
+    slots."""
+    op = jax.custom_batching.custom_vmap(
+        functools.partial(_routed_experts, act=act))
+
+    @op.def_vmap
+    def _rule(axis_size, in_batched, x, local, wts, w13, w2):
+        if in_batched[3] or in_batched[4]:
+            raise NotImplementedError(
+                "routed_experts: a batch of expert stacks")
+        x, local, wts = (
+            t if b else jnp.broadcast_to(t, (axis_size,) + t.shape)
+            for t, b in zip((x, local, wts), in_batched))
+        flat = lambda t: t.reshape((-1,) + t.shape[2:])
+        out = op(flat(x), flat(local), flat(wts), w13, w2)
+        return out.reshape(x.shape[:2] + out.shape[1:]), True
+
+    return op
 
 
-@routed_experts.def_vmap
-def _routed_experts_vmap(axis_size, in_batched, x, local, wts, w13, w2):
-    if in_batched[3] or in_batched[4]:
-        raise NotImplementedError("routed_experts: a batch of expert stacks")
-    x, local, wts = (t if b else jnp.broadcast_to(t, (axis_size,) + t.shape)
-                     for t, b in zip((x, local, wts), in_batched))
-    flat = lambda t: t.reshape((-1,) + t.shape[2:])
-    out = routed_experts(flat(x), flat(local), flat(wts), w13, w2)
-    return out.reshape(x.shape[:2] + out.shape[1:]), True
+def routed_experts(x, local, wts, w13, w2, act: str = "silu"):
+    """``sum_j wts[t, j] * GLU_{local[t, j]}(x[t])`` over the held picks
+    (:func:`_routed_experts`), batched as :func:`_routed_op` says."""
+    return _routed_op(act)(x, local, wts, w13, w2)
 
 
 class RoutedFFN(SimpleModule):
-    """The routed feed-forward layer of a mixture-of-experts LM (the
-    ``glm4_moe`` / DeepSeek-V3 router): ``s = sigmoid(x Wr)`` over all
-    ``num_experts``, in float32; the ``top_k`` chosen are the largest of
-    ``s + b`` (``b`` a selection bias used for the choice only); weights
-    ``s_e / sum_chosen s`` times ``scale``; ``out = sum_chosen w_e
-    SwiGLU_e(x) + Shared(x)``, the shared expert a SwiGLU of
-    ``shared_width``.
+    """The routed feed-forward layer of a mixture-of-experts LM. With
+    ``score="sigmoid"`` (the ``glm4_moe`` / DeepSeek-V3 router): ``s =
+    sigmoid(r Wr)`` over all ``num_experts``, in float32; the ``top_k``
+    chosen are the largest of ``s + b`` (``b`` a selection bias used for
+    the choice only); weights ``s_e / sum_chosen s`` times ``scale``. With
+    ``score="softmax_topk"`` (SmallThinker's): the ``top_k`` largest of
+    the logits ``r Wr`` themselves, no selection bias leaf, weights the
+    softmax over the chosen logits (the softmax over all, renormalised
+    over the chosen). ``out = sum_chosen w_e GLU_e(x) + Shared(x)``, the
+    gate's activation ``act`` (``silu``: SwiGLU; ``relu``: ReGLU), the
+    shared expert a GLU of ``shared_width`` (0: none).
+
+    ``r``, what the router reads, is ``x`` unless ``forward`` is given a
+    ``router_x`` of its own (a router placed before attention reads the
+    layer's input, the experts the normalised stream after it).
 
     The layer holds experts ``share * held .. share * held + held - 1``
     only (``held`` = ``num_experts``: all of them). Choice and weights are
@@ -248,7 +284,8 @@ class RoutedFFN(SimpleModule):
     def __init__(self, d_model: int, width: int, num_experts: int,
                  top_k: int, held: Optional[int] = None, share: int = 0,
                  shared_width: int = 0, scale: float = 1.0,
-                 init_std: float = 0.02, name: Optional[str] = None):
+                 init_std: float = 0.02, score: str = "sigmoid",
+                 act: str = "silu", name: Optional[str] = None):
         super().__init__(name)
         held = num_experts if held is None else held
         if not 0 < held <= num_experts or num_experts % held:
@@ -257,52 +294,67 @@ class RoutedFFN(SimpleModule):
         if not 0 <= share < num_experts // held:
             raise ValueError(f"share {share}: {num_experts // held} shares "
                              f"of {held} experts")
+        if score not in ("sigmoid", "softmax_topk") or act not in _ACTS:
+            raise ValueError(f"score {score!r} / act {act!r}: sigmoid or "
+                             f"softmax_topk, one of {sorted(_ACTS)}")
         self.d_model, self.width, self.num_experts = (d_model, width,
                                                       num_experts)
         self.top_k, self.held, self.share = top_k, held, share
         self.shared_width, self.scale = shared_width, scale
-        self.init_std = init_std
+        self.init_std, self.score, self.act = init_std, score, act
         self.words = -(-held // 32)
 
     def init(self, rng):
         ks = jax.random.split(rng, 5)
         d, w, sw = self.d_model, self.width, self.shared_width
         mk = lambda k, shape: self.init_std * jax.random.normal(k, shape)
-        out = {"router": {"weight": mk(ks[0], (d, self.num_experts)),
-                          "bias": jnp.zeros((self.num_experts,))},
+        out = {"router": {"weight": mk(ks[0], (d, self.num_experts))},
                "w13": mk(ks[1], (self.held, d, 2 * w)),
                "w2": mk(ks[2], (self.held, w, d))}
+        if self.score == "sigmoid":
+            out["router"]["bias"] = jnp.zeros((self.num_experts,))
         if sw:
             out["shared_w13"] = mk(ks[3], (d, 2 * sw))
             out["shared_w2"] = mk(ks[4], (sw, d))
         return out
 
     def scores(self, params, x):
-        """x (T, d) -> sigmoid scores (T, num_experts); the product in
-        float32 at ``highest`` precision whatever ``x``'s dtype."""
+        """x (T, d) -> what the choice is made on, (T, num_experts):
+        sigmoid scores, or under ``softmax_topk`` the logits; the product
+        in float32 at ``highest`` precision whatever ``x``'s dtype."""
         f32 = jnp.float32
-        return jax.nn.sigmoid(jnp.dot(
+        logits = jnp.dot(
             x.astype(f32), params["router"]["weight"].astype(f32),
-            precision=jax.lax.Precision.HIGHEST))
+            precision=jax.lax.Precision.HIGHEST)
+        return logits if self.score == "softmax_topk" else jax.nn.sigmoid(
+            logits)
 
     def weights(self, s, idx):
-        """The chosen experts' scores, renormalised, times ``scale``."""
+        """The chosen experts' scores, renormalised (under
+        ``softmax_topk``: the softmax of the chosen logits), times
+        ``scale``."""
         w = jnp.take_along_axis(s, idx, axis=-1)
+        if self.score == "softmax_topk":
+            return self.scale * jax.nn.softmax(w, axis=-1)
         return self.scale * w / jnp.sum(w, axis=-1, keepdims=True)
 
     def route(self, params, x):
         """x (T, d) -> (chosen expert ids (T, k) int32, weights (T, k)
         float32), over all ``num_experts``; scores and top-k in float32."""
         s = self.scores(params, x)
+        bias = params["router"].get("bias")
         _, idx = jax.lax.top_k(
-            s + params["router"]["bias"].astype(jnp.float32), self.top_k)
+            s if bias is None else s + bias.astype(jnp.float32), self.top_k)
         return idx, self.weights(s, idx)
 
-    def _forward(self, params, x, *, training, rng):
+    def forward(self, params, x, router_x=None):
+        """``(out, picked)`` of ``x`` (..., d); the router reads
+        ``router_x`` (..., d) where it is given, else ``x``."""
         lead, dt = x.shape[:-1], x.dtype
         x = x.reshape(-1, self.d_model)
         with jax.named_scope("moe_route"):
-            idx, wts = self.route(params, x)
+            idx, wts = self.route(params, x if router_x is None else
+                                  router_x.reshape(-1, self.d_model))
             local = idx - self.share * self.held
             local = jnp.where((local >= 0) & (local < self.held), local,
                               self.held)
@@ -313,14 +365,18 @@ class RoutedFFN(SimpleModule):
                 jnp.uint32(0))
             picked = jnp.sum(bits, axis=1, dtype=jnp.uint32)
         with jax.named_scope("moe_experts"):
-            out = routed_experts(x, local, wts, params["w13"], params["w2"])
+            out = routed_experts(x, local, wts, params["w13"], params["w2"],
+                                 self.act)
         if self.shared_width:
             with jax.named_scope("moe_shared"):
                 gu = jnp.dot(x, params["shared_w13"].astype(dt),
                              preferred_element_type=jnp.float32)
                 sw = self.shared_width
-                act = (jax.nn.silu(gu[:, :sw]) * gu[:, sw:]).astype(dt)
+                act = (_ACTS[self.act](gu[:, :sw]) * gu[:, sw:]).astype(dt)
                 out = out + jnp.dot(act, params["shared_w2"].astype(dt),
                                     preferred_element_type=jnp.float32)
         return (out.astype(dt).reshape(*lead, self.d_model),
                 picked.reshape(*lead, self.words))
+
+    def _forward(self, params, x, *, training, rng):
+        return self.forward(params, x)

@@ -36,7 +36,7 @@ import numpy as np
 
 from bigdl_tpu.nn import attention as _dense
 
-__all__ = ["flash_attention", "blockwise_attention",
+__all__ = ["flash_attention", "blockwise_attention", "band_mask",
            "online_softmax_update", "flash_block_plan",
            "kv_page_plan", "serving_prefill_buckets"]
 
@@ -187,9 +187,34 @@ def blockwise_attention(q, k, v, *, causal: bool = False,
     return out
 
 
+def _band_first(j, block_q, block_k, q_offset, window):
+    """The first K block that query block ``j`` can see under a window
+    (``j`` a Python int or a traced grid index)."""
+    first_key = q_offset + j * block_q - window + 1
+    if isinstance(j, int):
+        return max(first_key, 0) // block_k
+    return jnp.maximum(first_key, 0) // block_k
+
+
+def _band_last(j, block_q, block_k, q_offset):
+    """The last K block that query block ``j`` can see under causality."""
+    return (q_offset + (j + 1) * block_q - 1) // block_k
+
+
+def _band_width(sq, sk, bq, bk, q_offset, window) -> int:
+    """K blocks the inner grid axis walks under a window: the widest band
+    of any query block, ``ceil((window + bq) / bk)`` where the blocks are
+    aligned, fewer where the sequence is shorter."""
+    n_k = sk // bk
+    return max(min(_band_last(j, bq, bk, q_offset), n_k - 1)
+               - _band_first(j, bq, bk, q_offset, window) + 1
+               for j in range(sq // bq))
+
+
 def _block_valid(causal, q_ids, k_ids, bq, j, kk, block_q, block_k,
-                 q_offset):
-    """(bq, bk) bool validity tile combining the causal triangle and the
+                 q_offset, window=None):
+    """(bq, bk) bool validity tile combining the causal triangle (under a
+    ``window``, the band ``q_pos - window < k_pos <= q_pos``) and the
     segment equality mask; None when nothing is masked. Padded rows
     (segment 0) still attend segment-0 keys so no row is fully masked —
     the dense make_segment_mask kills them instead; those outputs are
@@ -202,6 +227,8 @@ def _block_valid(causal, q_ids, k_ids, bq, j, kk, block_q, block_k,
         k_pos = kk * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (bq, block_k), 1)
         valid = q_pos >= k_pos
+        if window is not None:
+            valid = valid & (q_pos - window < k_pos)
     if q_ids is not None:
         seg = q_ids == k_ids  # (bq, 1) == (1, bk) -> (bq, bk)
         valid = seg if valid is None else (valid & seg)
@@ -209,13 +236,20 @@ def _block_valid(causal, q_ids, k_ids, bq, j, kk, block_q, block_k,
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int, scale: float,
-                causal: bool, block_q: int, q_offset: int, has_seg: bool):
+                causal: bool, block_q: int, q_offset: int, has_seg: bool,
+                window: Optional[int] = None):
     """3-D grid (bh, q_blocks, k_blocks): K/V stream block-by-block from
     HBM (Pallas double-buffers across the innermost grid dim), online
     softmax state lives in VMEM scratch — O(block) VMEM regardless of
     sequence length, so 128k-token sequences fit. With ``has_seg`` two
     extra refs carry packed-document segment ids (q ids lane-replicated,
-    kv ids sublane-replicated — the official TPU kernel's layout)."""
+    kv ids sublane-replicated — the official TPU kernel's layout).
+
+    With a ``window`` (causal, forward only) the innermost grid dim walks
+    the band alone: step ``t`` of query block ``j`` is K block
+    ``_band_first(j) + t``, which the index map of ``_flash_fwd`` hands
+    it, so a K block no query of ``j`` can see is neither multiplied nor
+    copied."""
     from jax.experimental import pallas as pl
 
     if has_seg:
@@ -225,10 +259,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int, scale: float,
         qs_ref = ks_ref = None
 
     j = pl.program_id(1)
-    kk = pl.program_id(2)
+    kk = t = pl.program_id(2)
     n_k = pl.num_programs(2)
+    if window is not None:
+        kk = _band_first(j, block_q, block_k, q_offset, window) + t
 
-    @pl.when(kk == 0)
+    @pl.when(t == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -254,7 +290,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int, scale: float,
             causal,
             None if qs_ref is None else qs_ref[0][:, :1],
             None if ks_ref is None else ks_ref[0][:1, :],
-            bq, j, kk, block_q, block_k, q_offset)
+            bq, j, kk, block_q, block_k, q_offset, window)
         if valid is not None:
             s = jnp.where(valid, s, _NEG_INF)
         blk_max = jnp.max(s, axis=-1, keepdims=True)
@@ -271,7 +307,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int, scale: float,
             p.astype(vblk.dtype), vblk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(kk == n_k - 1)
+    @pl.when(t == n_k - 1)
     def _emit():
         l_safe = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
@@ -405,10 +441,12 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, *rest,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _live_block_pairs(sq, sk, bq, bk, causal, q_offset) -> int:
+def _live_block_pairs(sq, sk, bq, bk, causal, q_offset,
+                      window=None) -> int:
     """Exact number of (q-block, k-block) grid pairs whose matmuls run per
     (b, h) — the Python-side mirror of the kernels' ``live`` predicate
-    (fully-future K blocks are skipped under causal). Segment masking is
+    (fully-future K blocks are skipped under causal; under a ``window``
+    the blocks before the band are never visited). Segment masking is
     data-dependent and not reflected here."""
     n_q, n_k = sq // bq, sk // bk
     if not causal:
@@ -416,7 +454,10 @@ def _live_block_pairs(sq, sk, bq, bk, causal, q_offset) -> int:
     total = 0
     for j in range(n_q):
         q_end = q_offset + (j + 1) * bq - 1
-        total += min(n_k, max(0, q_end // bk + 1))
+        live = min(n_k, max(0, q_end // bk + 1))
+        if window is not None:
+            live = max(0, live - _band_first(j, bq, bk, q_offset, window))
+        total += live
     return total
 
 
@@ -613,11 +654,17 @@ def _seg_arrays(segments, sq, sk, bq):
 
 
 def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
-               segments=None):
+               segments=None, window: Optional[int] = None):
     """Pallas forward; returns (out, lse) with lse in (b*h, padded_sq).
     The kernel emits lse lane-replicated (see _LSE_LANES); the replica dim
     is squeezed off here so the custom_vjp residual stores 4 B/query, not
-    32 B — the backward re-broadcasts next to its delta broadcast."""
+    32 B — the backward re-broadcasts next to its delta broadcast.
+
+    With a ``window`` (causal, no segments) the call is named
+    ``flash_fwd_window``: its innermost grid dim is the band's width in K
+    blocks, and the K/V index map starts each query block at the first K
+    block it can see. A step past the block's last live K block names
+    that last block again, so nothing is copied for it."""
     from jax.experimental import pallas as pl
 
     b, h, s_q, d = q.shape
@@ -633,14 +680,26 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
     qf, pad_q = _pad_to(qf, bq, 1)
     sq, sk = qf.shape[1], kf.shape[1]
 
+    q_offset = s_k - s_q
     kernel = functools.partial(_fwd_kernel, block_k=bk, scale=scale,
                                causal=causal, block_q=bq,
-                               q_offset=s_k - s_q,
-                               has_seg=segments is not None)
+                               q_offset=q_offset,
+                               has_seg=segments is not None, window=window)
+    if window is None:
+        n_inner = sk // bk
+        kv_block = lambda i, j, kk: (i, kk, 0)
+    else:
+        n_inner = _band_width(sq, sk, bq, bk, q_offset, window)
+
+        def kv_block(i, j, t):
+            last = jnp.minimum(_band_last(j, bq, bk, q_offset),
+                               sk // bk - 1)
+            return (i, jnp.minimum(
+                _band_first(j, bq, bk, q_offset, window) + t, last), 0)
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0)),
-        pl.BlockSpec((1, bk, d), lambda i, j, kk: (i, kk, 0)),
-        pl.BlockSpec((1, bk, d), lambda i, j, kk: (i, kk, 0)),
+        pl.BlockSpec((1, bk, d), kv_block),
+        pl.BlockSpec((1, bk, d), kv_block),
     ]
     args = [qf, kf, vf]
     if segments is not None:
@@ -653,10 +712,10 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
                          lambda i, j, kk: (i // h, 0, kk)),
         ]
         args += [qs3, ks3]
-    n_pairs = _live_block_pairs(sq, sk, bq, bk, causal, s_k - s_q)
+    n_pairs = _live_block_pairs(sq, sk, bq, bk, causal, q_offset, window)
     out, lse = pl.pallas_call(
         kernel,
-        grid=(b * h, sq // bq, sk // bk),
+        grid=(b * h, sq // bq, n_inner),
         cost_estimate=_attn_cost(b * h, n_pairs, bq, bk, d,
                                  q.dtype.itemsize, units=2),
         in_specs=in_specs,
@@ -673,16 +732,21 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
             pltpu_scratch((bq, d)),
         ],
         interpret=_interpret(),
-        name="flash_fwd",
+        name="flash_fwd" if window is None else "flash_fwd_window",
     )(*args)
     o = out[:, :s_q] if pad_q else out
     return o.reshape(b, h, s_q, d), lse[..., 0]
 
 
 def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int,
-               block_k: int, segments=None):
+               block_k: int, segments=None, window: Optional[int] = None):
     """Pallas dq + dk/dv kernels over the recomputed probabilities."""
     from jax.experimental import pallas as pl
+
+    if window is not None:
+        raise NotImplementedError(
+            "flash_attention: the backward kernels have no window "
+            f"(window={window}); the windowed kernel is forward only")
 
     b, h, s_q, d = q.shape
     s_k = k.shape[-2]
@@ -819,6 +883,33 @@ def _flash_vjp_bwd(causal, block_q, block_k, res, g):
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_window(q, k, v, window, block_q, block_k):
+    return _flash_fwd(q, k, v, True, block_q, block_k, window=window)[0]
+
+
+def _flash_window_vjp_fwd(q, k, v, window, block_q, block_k):
+    out, lse = _flash_fwd(q, k, v, True, block_q, block_k, window=window)
+    return out, (q, k, v, out, lse)
+
+
+def _flash_window_vjp_bwd(window, block_q, block_k, res, g):
+    q, k, v, o, lse = res
+    return _flash_bwd(q, k, v, o, lse, g, True, block_q, block_k,
+                      window=window)
+
+
+_flash_window.defvjp(_flash_window_vjp_fwd, _flash_window_vjp_bwd)
+
+
+def band_mask(s_q: int, s_k: int, window: int):
+    """(s_q, s_k) bool: query ``i`` (the queries are the last ``s_q`` of
+    the ``s_k`` positions) sees keys ``j`` with ``i - window < j <= i``."""
+    i = jnp.arange(s_q)[:, None] + (s_k - s_q)
+    j = jnp.arange(s_k)[None, :]
+    return (j <= i) & (j > i - window)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def _flash_seg(q, k, v, segments, causal, block_q, block_k):
     return _flash_fwd(q, k, v, causal, block_q, block_k,
@@ -845,8 +936,16 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     mask: Optional[jax.Array] = None,
                     segments: Optional[jax.Array] = None,
                     block_q: Optional[int] = None,
-                    block_k: Optional[int] = None):
+                    block_k: Optional[int] = None,
+                    window: Optional[int] = None):
     """(b, h, s, d) attention via the Pallas online-softmax kernel.
+
+    ``window``: with ``causal=True``, query ``i`` sees keys ``j`` with
+    ``i - window < j <= i``. Forward only (the serving prefill): the
+    kernel's inner grid walks the K blocks of the band alone
+    (``flash_fwd_window``); differentiating it raises. No mask or
+    segments beside it; a shape the kernel cannot tile takes the dense
+    path under :func:`band_mask`. ``window=None`` is the call as it was.
 
     ``segments``: (b, s) int document ids for packed rows (see
     dataset.text.pack_sequences) — the block-diagonal mask is applied
@@ -868,6 +967,16 @@ def flash_attention(q, k, v, *, causal: bool = False,
     s_q, s_k = q.shape[-2], k.shape[-2]
     block_q, block_k = _resolve_blocks(s_q, s_k, q.shape[-1], causal,
                                        q.dtype, block_q, block_k)
+    if window is not None:
+        if not causal or mask is not None or segments is not None:
+            raise ValueError("flash_attention: window needs causal=True "
+                             "and neither mask nor segments")
+        if s_q % block_q or not _tileable(s_q, s_k, block_k):
+            return _dense.dot_product_attention(
+                q, k, v, mask=band_mask(s_q, s_k, window))
+        return shard_over_batch(
+            lambda q, k, v: _flash_window(q, k, v, int(window), block_q,
+                                          block_k))(q, k, v)
     if segments is not None:
         if mask is not None:
             raise ValueError("segments and mask are mutually exclusive")

@@ -143,6 +143,64 @@ _Flight = collections.namedtuple("_Flight", "toks advanced stats",
                                  defaults=(None,))
 
 
+def _dense_path_only(model, **asked) -> Optional[str]:
+    """What ``DecodeEngine`` refuses ``model``, from what the model
+    declares: ``recurrent_state`` (a slot holds a scan state, and a ring
+    beside it: only the dense slab carries those), ``window`` without it
+    (a slot holds rings of ``window`` K/V rows), ``routed_experts`` (a
+    stack no 8-bit form or tp layout knows). ``asked``: the engine's
+    options that are on. The message, or None where nothing asked for is
+    refused."""
+    state = getattr(model, "recurrent_state", False)
+    ring = bool(getattr(model, "window", None)) and not state
+    experts = getattr(model, "routed_experts", False)
+    quant_stack = "the routed expert stack"
+    mesh_stack = quant_stack + " and its exchange"
+    if state:
+        reasons = {
+            "kv_page_tokens": "page pools hold per-layer K/V rows only "
+                              "(serving/kv_pages.py)",
+            "prefix_cache": "a shared prefix is a page copy, and the state "
+                            "after the prefix is in no page "
+                            "(serving/prefix_cache.py)",
+            "speculate": "a rejected draft token cannot be taken out of a "
+                         "scan state (serving/spec_decode.py)",
+            "quantize": "no 8-bit form of the state-space weights or of "
+                        "the state" + (f", nor of {quant_stack}"
+                                       if experts else "")
+                        + " (serving/quant.py)",
+            "mesh": "no tp layout for the scan"
+                    + (f", nor for {mesh_stack}" if experts else "")
+                    + " (serving/sharding.py)"}
+        keeps = "recurrent state in its slots"
+    elif ring:
+        reasons = {
+            "kv_page_tokens": "a ring's rows are in no page: page pools "
+                              "hold max_len K/V rows a layer "
+                              "(serving/kv_pages.py)",
+            "prefix_cache": "a shared prefix is a page copy, and a ring's "
+                            "rows are in no page (serving/prefix_cache.py)",
+            "speculate": "a rejected draft token has overwritten the row "
+                         "of position pos - window in every ring "
+                         "(serving/spec_decode.py)"}
+        if experts:
+            reasons["quantize"] = (f"no 8-bit form of {quant_stack} "
+                                   "(serving/quant.py)")
+            reasons["mesh"] = (f"no tp layout for {mesh_stack} "
+                               "(serving/sharding.py)")
+        keeps = "window rings in its slots"
+    else:
+        return None
+    missing = [f"{name}: {reasons[name]}" for name, on in asked.items()
+               if on and name in reasons]
+    if not missing:
+        return None
+    return (f"{type(model).__name__} keeps {keeps}"
+            + (" and routed expert stacks in its layers" if experts else "")
+            + " and serves on the dense path only; not supported yet: "
+            + "; ".join(missing))
+
+
 class DecodeEngine:
     """Continuous-batching KV-cache decoder over a fixed slot count.
 
@@ -219,38 +277,12 @@ class DecodeEngine:
         self._worker_error: Optional[BaseException] = None
         self._last_beat = self.clock()
         self.model = model
-        if getattr(model, "recurrent_state", False):
-            # a slot of such a model holds a scan state and a ring beside
-            # its K/V rows; only the dense slab carries those. Routed
-            # experts are a stack no 8-bit form or tp layout knows
-            experts = getattr(model, "routed_experts", False)
-            missing = [why for on, why in (
-                (kv_page_tokens, "kv_page_tokens: page pools hold "
-                                 "per-layer K/V rows only "
-                                 "(serving/kv_pages.py)"),
-                (prefix_cache, "prefix_cache: a shared prefix is a page "
-                               "copy, and the state after the prefix is "
-                               "in no page (serving/prefix_cache.py)"),
-                (speculate, "speculate: a rejected draft token cannot "
-                            "be taken out of a scan state "
-                            "(serving/spec_decode.py)"),
-                (quantize not in (None, "off"),
-                 "quantize: no 8-bit form of the state-space weights or "
-                 "of the state" + (", nor of the routed expert stack"
-                                   if experts else "")
-                 + " (serving/quant.py)"),
-                (mesh is not None,
-                 "mesh: no tp layout for the scan"
-                 + (", nor for the routed expert stack and its exchange"
-                    if experts else "") + " (serving/sharding.py)"))
-                if on]
-            if missing:
-                raise ValueError(
-                    f"{type(model).__name__} keeps recurrent state in its "
-                    "slots" + (" and routed expert stacks in its layers"
-                               if experts else "")
-                    + " and serves on the dense path only; not "
-                    "supported yet: " + "; ".join(missing))
+        refused = _dense_path_only(
+            model, kv_page_tokens=kv_page_tokens, prefix_cache=prefix_cache,
+            speculate=speculate, quantize=quantize not in (None, "off"),
+            mesh=mesh is not None)
+        if refused:
+            raise ValueError(refused)
         # ---- quantized serving (ISSUE 17): weights go 8-bit BEFORE tp
         # placement so each scale vector ships to the mesh alongside its
         # weight (column-split weight -> split scale). Idempotent: trees
@@ -377,7 +409,7 @@ class DecodeEngine:
             self._m_live_pos = self._m_window_pos = None
             self._m_runahead = self._m_dropped = None
             self._m_prompt_tokens = self._m_rejected = None
-            self._m_bucket_tokens = None
+            self._m_bucket_tokens = self._m_window_tokens = None
             self._m_queued = self._m_queue_wait = None
             self._m_expired = self._m_dead = self._m_cancelled = None
             self._m_spec_prop = self._m_spec_acc = None
@@ -420,6 +452,12 @@ class DecodeEngine:
             "prefill_bucket_tokens_total",
             "positions prefilled, padding included (the bucket length "
             "of every prefill)")
+        self._m_window_tokens = metrics.counter(
+            "prefill_window_tokens_total",
+            "prompt tokens prefilled through the window kernel: tokens of "
+            "prompts whose bucket is longer than the model's window, so "
+            "that its window layers attend a band (0 for a model without "
+            "window layers)")
         self._m_queued = metrics.counter(
             "decode_queued_total",
             "generate requests installed after waiting for a slot")
@@ -1024,8 +1062,12 @@ class DecodeEngine:
             else:
                 padded = np.zeros((1, bucket), np.int32)
                 padded[0, :s] = req.tokens
-                logits_vec, cache1 = self._prefill_jit(
-                    self.params, jnp.asarray(padded), jnp.int32(s - 1))
+                # the prompt's length in the span's name: a reader of a
+                # profiler session sees names only, and the operations a
+                # prefill needs go by its real tokens, not its bucket's
+                with _obs_span(f"prefill_tokens_{s}"):
+                    logits_vec, cache1 = self._prefill_jit(
+                        self.params, jnp.asarray(padded), jnp.int32(s - 1))
                 if self.paged:
                     self._kv.pools = self._scatter_prefill(
                         self._kv.pools, cache1,
@@ -1051,6 +1093,8 @@ class DecodeEngine:
             self._m_prefills.inc()
             self._m_prompt_tokens.inc(s - n_pfx)
             self._m_bucket_tokens.inc(bucket)
+            if bucket > (getattr(self.model, "window", None) or bucket):
+                self._m_window_tokens.inc(s)
         if rt is not None:
             rt.note_prefill(
                 req.rid, t0_pf, rt.clock(), slot=slot,

@@ -1,6 +1,7 @@
 """The decode step's kernels compiled for a described v5e at the
 benchmark's real shapes (``cache_write_rows``, ``cache_attend_rows``, and
-the routed experts' grouped matmuls): what interpret mode cannot refuse
+the routed experts' grouped matmuls), and the windowed flash forward
+kernel of the long prefills: what interpret mode cannot refuse
 (tiling, fast memory, the alias). No chip is needed and nothing runs;
 where the topology cannot be described here the tests skip. The topology
 is described inside a
@@ -59,9 +60,12 @@ def test_the_kernel_compiles_in_place_for_v5e(one_chip, monkeypatch, shape,
 @pytest.mark.parametrize("shape,r", [((48, 2, 4096, 128), 12),
                                      ((64, 10, 4096, 128), 4),
                                      ((64, 10, 512, 128), 4),
-                                     ((64, 8, 4096, 128), 8)],
+                                     ((64, 8, 4096, 128), 8),
+                                     ((32, 4, 4096, 128), 7),
+                                     ((32, 4, 16384, 128), 7)],
                          ids=["starcoder2", "sambay_full", "sambay_ring",
-                              "solar_open2"])
+                              "solar_open2", "smallthinker_ring",
+                              "smallthinker_full"])
 def test_the_bounded_read_compiles_for_v5e(one_chip, monkeypatch, shape, r,
                                            dtype):
     """``cache_attend_rows`` at the cells' shapes: one Mosaic call that
@@ -99,3 +103,47 @@ def test_the_routed_experts_compile_for_v5e(one_chip, monkeypatch, tokens):
         'custom_call_target="tpu_custom_call"') == 2
     stacks = 40 * 3 * 4096 * 1280 * 2
     assert compiled.memory_analysis().temp_size_in_bytes < stacks // 10
+
+
+@pytest.mark.parametrize("tokens", [32, 4096], ids=["decode_32_slots",
+                                                    "prefill_row_block"])
+def test_the_reglu_experts_compile_for_v5e(one_chip, monkeypatch, tokens):
+    """The same grouped matmuls at SmallThinker's widths (64 experts of
+    width 768 over a stream of 2,560, six a token, ReGLU): the tiles
+    chosen for 2,560 and 1,536 columns fit."""
+    from bigdl_tpu.nn import moe
+    from bigdl_tpu.ops import attention_kernel
+    monkeypatch.setattr(attention_kernel, "_interpret", lambda: False)
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    compiled = jax.jit(functools.partial(
+        moe._routed_experts, act="relu")).lower(
+        sds((tokens, 2560), jnp.bfloat16), sds((tokens, 6), jnp.int32),
+        sds((tokens, 6), jnp.float32),
+        sds((64, 2560, 1536), jnp.bfloat16),
+        sds((64, 768, 2560), jnp.bfloat16)).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 2
+    # no copy of an expert stack (755 MB); a row block of 4,096 tokens
+    # has 24,576 rows of float32 temporaries (588 MB read), whatever the
+    # prompt's length
+    limit = {32: 64 << 20, 4096: 640 << 20}[tokens]
+    assert compiled.memory_analysis().temp_size_in_bytes < limit
+
+
+@pytest.mark.parametrize("s", [8192, 16384])
+def test_the_window_kernel_compiles_for_v5e(one_chip, monkeypatch, s):
+    """``flash_fwd_window`` at SmallThinker's heads and window: one Mosaic
+    call whose cost estimate counts the band and not the triangle."""
+    from bigdl_tpu.ops import attention_kernel as ak
+    monkeypatch.setattr(ak, "_interpret", lambda: False)
+    qkv = [jax.ShapeDtypeStruct((1, 28, s, 128), jnp.bfloat16,
+                                sharding=one_chip)] * 3
+    compiled = jax.jit(lambda q, k, v: ak.flash_attention(
+        q, k, v, causal=True, window=4096)).lower(*qkv).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "flash_fwd_window" in text
+    n = s // 512
+    assert ak._band_width(s, s, 512, 512, 0, 4096) == 9
+    assert ak._live_block_pairs(s, s, 512, 512, True, 0, 4096) == \
+        sum(min(j + 1, 9) for j in range(n))
