@@ -4,9 +4,19 @@ The reference has no attention at all (SURVEY.md §2.7); this kernel exists
 for the long-context path the new framework treats as first-class. Design
 per the TPU Pallas playbook:
 
-* grid = (batch*heads, q_blocks); each program owns one (BLOCK_Q, d) query
-  tile in VMEM and streams K/V tiles with an online (one-pass) softmax —
-  O(s) memory instead of materializing the (s, s) score matrix in HBM.
+* grid = (batch*heads, live block pairs); each run of steps owns one
+  (BLOCK_Q, d) query tile in VMEM and streams K/V tiles with an online
+  (one-pass) softmax — O(s) memory instead of materializing the (s, s)
+  score matrix in HBM. The pairs come from two scalar-prefetched tables
+  (:func:`_pair_table`), so a block pair no query can see is neither
+  stepped nor copied, and only a pair the mask can touch
+  (:func:`_pair_masked`) runs the masked body.
+* a program pays for a kernel once: the wrappers sit under an inner
+  ``jax.jit`` (one trace and one lowered kernel a signature, not one a
+  layer), a call whose table is all masked or all interior holds that one
+  body (:func:`_table_masked`), and a step's body walks a tile larger
+  than 512 x 1,024 in row chunks under a rolled loop
+  (:func:`_chunk_rows`).
 * scores accumulate in fp32 (``preferred_element_type``) on the MXU while
   inputs may be bf16 — the same numerics as the XLA dense path.
 * On non-TPU backends the kernel runs in interpret mode (tests), so one
@@ -15,9 +25,9 @@ per the TPU Pallas playbook:
 Backward: hand-tiled Pallas dq and dk/dv kernels (the standard flash
 backward split). The forward kernel emits the per-query logsumexp; the
 backward preprocesses ``delta = rowsum(do * o)`` in one cheap jnp pass,
-then dq runs on the forward's grid (one q tile per program, streaming K/V
-blocks) while dk/dv runs transposed (one k tile per program, streaming
-Q/dO blocks), both with causal block skipping. Probabilities are
+then dq runs on the forward's grid (one q tile per row of steps, streaming
+K/V blocks) while dk/dv runs transposed (one k tile per row, streaming
+Q/dO blocks), both over live pairs alone. Probabilities are
 recomputed from q,k,lse — O(seq) memory end to end. Non-tileable shapes
 fall back to :func:`blockwise_attention` (remat-scan) under one
 ``jax.custom_vjp``.
@@ -188,12 +198,8 @@ def blockwise_attention(q, k, v, *, causal: bool = False,
 
 
 def _band_first(j, block_q, block_k, q_offset, window):
-    """The first K block that query block ``j`` can see under a window
-    (``j`` a Python int or a traced grid index)."""
-    first_key = q_offset + j * block_q - window + 1
-    if isinstance(j, int):
-        return max(first_key, 0) // block_k
-    return jnp.maximum(first_key, 0) // block_k
+    """The first K block that query block ``j`` can see under a window."""
+    return max(q_offset + j * block_q - window + 1, 0) // block_k
 
 
 def _band_last(j, block_q, block_k, q_offset):
@@ -201,55 +207,199 @@ def _band_last(j, block_q, block_k, q_offset):
     return (q_offset + (j + 1) * block_q - 1) // block_k
 
 
-def _band_width(sq, sk, bq, bk, q_offset, window) -> int:
-    """K blocks the inner grid axis walks under a window: the widest band
-    of any query block, ``ceil((window + bq) / bk)`` where the blocks are
-    aligned, fewer where the sequence is shorter."""
-    n_k = sk // bk
-    return max(min(_band_last(j, bq, bk, q_offset), n_k - 1)
-               - _band_first(j, bq, bk, q_offset, window) + 1
-               for j in range(sq // bq))
+def _pair_live(causal, j, kk, block_q, block_k, q_offset):
+    """Whether any query of Q block ``j`` sees a key of K block ``kk``
+    (block indices as Python ints, or traced in a kernel)."""
+    return not causal or kk * block_k <= q_offset + (j + 1) * block_q - 1
 
 
-def _block_valid(causal, q_ids, k_ids, bq, j, kk, block_q, block_k,
-                 q_offset, window=None):
-    """(bq, bk) bool validity tile combining the causal triangle (under a
-    ``window``, the band ``q_pos - window < k_pos <= q_pos``) and the
-    segment equality mask; None when nothing is masked. Padded rows
-    (segment 0) still attend segment-0 keys so no row is fully masked —
-    the dense make_segment_mask kills them instead; those outputs are
-    loss-masked garbage either way, but a live softmax row keeps the
+# two int32 tables of this many pairs are half the chip's 1 MB of scalar
+# memory: 131,072 tokens at 512-blocks without a mask
+_MAX_PAIRS = 1 << 16
+
+
+def _pair_table(n_q, n_k, bq, bk, causal, q_offset, window=None,
+                by_key=False):
+    """The (Q block, K block) pairs the kernels' flattened grid walks, as
+    two int32 arrays: every Q block's visible K blocks in turn (under a
+    ``window``, its band's; ``by_key``: every K block's Q blocks, for
+    dk/dv), so a pair no query can see is neither stepped nor copied. A
+    row with no live pair (``s_q > s_k``) keeps one entry, which the
+    kernels' ``live`` test skips, so that its output is still written."""
+    pairs = []
+    if by_key:
+        for jk in range(n_k):
+            first = max((jk * bk - q_offset) // bq, 0) if causal else 0
+            pairs += [(qq, jk) for qq in range(min(first, n_q - 1), n_q)]
+    else:
+        for j in range(n_q):
+            first = 0 if window is None else _band_first(
+                j, bq, bk, q_offset, window)
+            last = n_k - 1 if not causal else min(
+                max(_band_last(j, bq, bk, q_offset), first), n_k - 1)
+            pairs += [(j, kk) for kk in range(first, last + 1)]
+    if len(pairs) > _MAX_PAIRS:
+        raise ValueError(
+            f"flash_attention: {len(pairs)} block pairs do not fit the "
+            f"kernels' scalar tables ({_MAX_PAIRS}); pass larger "
+            "block_q / block_k")
+    table = np.asarray(pairs, np.int32)
+    return table[:, 0], table[:, 1]
+
+
+def _pair_specs(bq, bk, d, h, has_seg):
+    """Block specs of a pair-table grid ``(bh, pairs)``: a ``(bq, d)``
+    block at the step's Q block, a ``(bk, d)`` block at its K block, a
+    per-query row block (lse, delta), and the two segment-id blocks (per
+    batch, so the grid's ``bh`` index divides out ``h``; none without
+    segments). Index maps see the grid indices, then the two tables."""
+    from jax.experimental import pallas as pl
+
+    at_q = lambda i, t, qt, kt: (i, qt[t], 0)
+    seg_specs = [
+        pl.BlockSpec((1, bq, _LSE_LANES),
+                     lambda i, t, qt, kt: (i // h, qt[t], 0)),
+        pl.BlockSpec((1, _LSE_LANES, bk),
+                     lambda i, t, qt, kt: (i // h, 0, kt[t])),
+    ] if has_seg else []
+    return (pl.BlockSpec((1, bq, d), at_q),
+            pl.BlockSpec((1, bk, d), lambda i, t, qt, kt: (i, kt[t], 0)),
+            pl.BlockSpec((1, bq, _LSE_LANES), at_q), seg_specs)
+
+
+def _row_edges(row_ref, t):
+    """Whether grid step ``t`` is the first / the last of its row of
+    steps (``row_ref`` names each step's row: the block whose scratch
+    accumulates)."""
+    from jax.experimental import pallas as pl
+
+    n = pl.num_programs(1)
+    row = row_ref[t]
+    return ((t == 0) | (row_ref[jnp.maximum(t - 1, 0)] != row),
+            (t == n - 1) | (row_ref[jnp.minimum(t + 1, n - 1)] != row))
+
+
+def _pair_masked(causal, has_seg, j, kk, block_q, block_k, q_offset,
+                 window=None):
+    """Whether block pair (Q block ``j``, K block ``kk``) holds an element
+    the mask can remove. A causal pair is *interior*, and not masked, when
+    its last key is visible to its first query and, under a ``window``,
+    its first key is inside its last query's band: integer arithmetic on
+    block indices (Python ints, or traced in a kernel). Segment ids are
+    data, so under them every pair is masked; with no mask none is."""
+    if has_seg or not causal:
+        return has_seg
+    crossed = kk * block_k + block_k - 1 > q_offset + j * block_q
+    if window is not None:
+        crossed |= kk * block_k <= q_offset + (j + 1) * block_q - 1 - window
+    return crossed
+
+
+def _table_masked(tables, causal, has_seg, block_q, block_k, q_offset,
+                  window=None) -> Optional[bool]:
+    """What :func:`_pair_masked` says of a whole pair table, where it says
+    one thing: True when every live pair is masked (a call of one block
+    pair, segment ids), False when none is (no mask at all). The kernel
+    then holds the one body its table uses. None: both bodies, chosen a
+    step."""
+    kinds = {bool(_pair_masked(causal, has_seg, j, kk, block_q, block_k,
+                               q_offset, window))
+             for j, kk in zip(*(t.tolist() for t in tables))
+             if _pair_live(causal, j, kk, block_q, block_k, q_offset)}
+    return None if len(kinds) == 2 else True in kinds
+
+
+def _on_live_pair(live, masked, step):
+    """Run ``step(masked)`` on a live block pair: a pair the mask can touch
+    under the mask, an interior pair in a body with no iota, compare or
+    select. Two ``pl.when`` bodies of the one ``step`` where ``masked`` is
+    decided a step, one where the table decided it (a Python bool)."""
+    from jax.experimental import pallas as pl
+
+    if isinstance(masked, bool):
+        pl.when(live)(functools.partial(step, masked))
+    else:
+        pl.when(live & masked)(functools.partial(step, True))
+        pl.when(live & ~masked)(functools.partial(step, False))
+
+
+# Mosaic unrolls a step's body over its score tile, so a program grows with
+# the tile: a body works at most this many scores at a time (half a
+# 1,024 x 1,024 tile's) and walks a larger tile's query rows under a rolled
+# loop. The chip set the size (bf16[48,4096,128], PERF.md §6, PR 38): 512
+# rows a chunk cost the forward 2.9%, dq 3.1% and dk/dv nothing against the
+# whole tile unrolled and take two fifths off the executable; 256 rows cost
+# 17%, 9% and 32% (each latched K or V tile then streams too few rows).
+_STEP_TILE = 512 * 1024
+
+
+def _chunk_rows(block_q: int, block_k: int) -> int:
+    """Query rows of a ``(block_q, block_k)`` score tile that a step's body
+    works at a time: 512 at 1,024-blocks; the whole tile up to 512 x 1,024,
+    and where the chunk does not divide the rows."""
+    rows = _STEP_TILE // block_k
+    return rows if block_q % rows == 0 else block_q
+
+
+def _over_row_chunks(block_q, rows, chunk):
+    """``chunk(r0)`` for every ``rows`` query rows of a step's tile, ``r0``
+    the chunk's first row within the step's blocks and row scratch. Rows
+    are independent in all a step does (dk/dv sums over them, into its
+    scratch), so a tile of one chunk is the plain body and a larger one
+    the same arithmetic under ``lax.fori_loop``: the grid step, its copies
+    and its fixed cost stay the large block's, the body a small tile's."""
+    from jax.experimental import pallas as pl
+
+    if rows == block_q:
+        chunk(0)
+        return
+
+    def body(c, carry):
+        chunk(pl.multiple_of(c * rows, rows))
+        return carry
+
+    jax.lax.fori_loop(0, block_q // rows, body, 0)
+
+
+def _block_valid(causal, q_ids, k_ids, rows, q_first, k_first, block_k,
+                 window=None):
+    """(rows, block_k) bool validity tile for the queries from position
+    ``q_first`` against the keys from ``k_first``, combining the causal
+    triangle (under a ``window``, the band ``q_pos - window < k_pos <=
+    q_pos``) and the segment equality mask; None when nothing is masked.
+    Padded rows (segment 0) still attend segment-0 keys so no row is fully
+    masked — the dense make_segment_mask kills them instead; those outputs
+    are loss-masked garbage either way, but a live softmax row keeps the
     backward finite."""
     valid = None
     if causal:
-        q_pos = (q_offset + j * block_q
-                 + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0))
-        k_pos = kk * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, block_k), 1)
+        q_pos = q_first + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block_k), 0)
+        k_pos = k_first + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block_k), 1)
         valid = q_pos >= k_pos
         if window is not None:
             valid = valid & (q_pos - window < k_pos)
     if q_ids is not None:
-        seg = q_ids == k_ids  # (bq, 1) == (1, bk) -> (bq, bk)
+        seg = q_ids == k_ids  # (rows, 1) == (1, bk) -> (rows, bk)
         valid = seg if valid is None else (valid & seg)
     return valid
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int, scale: float,
-                causal: bool, block_q: int, q_offset: int, has_seg: bool,
+def _fwd_kernel(qt_ref, kt_ref, q_ref, k_ref, v_ref, *rest, block_k: int,
+                scale: float, causal: bool, block_q: int, q_offset: int,
+                has_seg: bool, masked: Optional[bool], rows: int,
                 window: Optional[int] = None):
-    """3-D grid (bh, q_blocks, k_blocks): K/V stream block-by-block from
-    HBM (Pallas double-buffers across the innermost grid dim), online
+    """Grid (bh, block pairs): step ``t`` is Q block ``qt_ref[t]`` against
+    K block ``kt_ref[t]`` (:func:`_pair_table`). K/V stream block-by-block
+    from HBM (Pallas double-buffers across the innermost grid dim), online
     softmax state lives in VMEM scratch — O(block) VMEM regardless of
     sequence length, so 128k-token sequences fit. With ``has_seg`` two
     extra refs carry packed-document segment ids (q ids lane-replicated,
-    kv ids sublane-replicated — the official TPU kernel's layout).
-
-    With a ``window`` (causal, forward only) the innermost grid dim walks
-    the band alone: step ``t`` of query block ``j`` is K block
-    ``_band_first(j) + t``, which the index map of ``_flash_fwd`` hands
-    it, so a K block no query of ``j`` can see is neither multiplied nor
-    copied."""
+    kv ids sublane-replicated — the official TPU kernel's layout). With a
+    ``window`` (causal, forward only) a Q block's steps are its band's K
+    blocks alone. ``masked`` is :func:`_table_masked`'s word on the whole
+    table, ``rows`` :func:`_chunk_rows`'."""
     from jax.experimental import pallas as pl
 
     if has_seg:
@@ -258,56 +408,61 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int, scale: float,
         o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
         qs_ref = ks_ref = None
 
-    j = pl.program_id(1)
-    kk = t = pl.program_id(2)
-    n_k = pl.num_programs(2)
-    if window is not None:
-        kk = _band_first(j, block_q, block_k, q_offset, window) + t
+    t = pl.program_id(1)
+    j, kk = qt_ref[t], kt_ref[t]
+    first, last = _row_edges(qt_ref, t)
 
-    @pl.when(t == 0)
+    @pl.when(first)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    bq = q_ref.shape[1]
     # bottom-right aligned causal (matches dot_product_attention): query i
-    # sees keys <= (s_k - s_q) + i. Fully-future K blocks are skipped
-    # (grid step still runs, matmuls don't — half the causal FLOPs).
-    q_end = q_offset + (j + 1) * block_q - 1
-    live = True if not causal else kk * block_k <= q_end
+    # sees keys <= (s_k - s_q) + i. Fully-future K blocks are not in the
+    # pair table (half the causal FLOPs); ``live`` skips the one entry a
+    # row with no visible key keeps.
+    live = _pair_live(causal, j, kk, block_q, block_k, q_offset)
 
-    @pl.when(live)
-    def _step():
-        q = q_ref[0]  # (BQ, d) — input dtype on the MXU, fp32 accumulate
-        kblk = k_ref[0]
-        vblk = v_ref[0]
-        m, l = m_scr[...], l_scr[...]
-        s = jax.lax.dot_general(
-            q, kblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (BQ, BK)
-        valid = _block_valid(
-            causal,
-            None if qs_ref is None else qs_ref[0][:, :1],
-            None if ks_ref is None else ks_ref[0][:1, :],
-            bq, j, kk, block_q, block_k, q_offset, window)
-        if valid is not None:
-            s = jnp.where(valid, s, _NEG_INF)
-        blk_max = jnp.max(s, axis=-1, keepdims=True)
-        new_m = jnp.maximum(m, blk_max)
-        p = jnp.exp(s - new_m)
-        if valid is not None:
-            p = jnp.where(valid, p, 0.0)
-        corr = jnp.exp(m - new_m)
-        m_scr[...] = new_m
-        l_scr[...] = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        # p cast to V's dtype (flash convention): P@V is a bf16 MXU
-        # matmul with fp32 accumulation
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p.astype(vblk.dtype), vblk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def _step(masked):
+        def chunk(r0):
+            r = pl.ds(r0, rows)
+            q = q_ref[0, r, :]  # input dtype on the MXU, fp32 accumulate
+            kblk = k_ref[0]
+            vblk = v_ref[0]
+            m, l = m_scr[r, :], l_scr[r, :]
+            s = jax.lax.dot_general(
+                q, kblk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (rows, BK)
+            valid = _block_valid(
+                causal and masked,
+                None if qs_ref is None else qs_ref[0, r, :1],
+                None if ks_ref is None else ks_ref[0][:1, :],
+                rows, q_offset + j * block_q + r0, kk * block_k, block_k,
+                window)
+            if valid is not None:
+                s = jnp.where(valid, s, _NEG_INF)
+            blk_max = jnp.max(s, axis=-1, keepdims=True)
+            new_m = jnp.maximum(m, blk_max)
+            p = jnp.exp(s - new_m)
+            if valid is not None:
+                p = jnp.where(valid, p, 0.0)
+            corr = jnp.exp(m - new_m)
+            m_scr[r, :] = new_m
+            l_scr[r, :] = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+            # p cast to V's dtype (flash convention): P@V is a bf16 MXU
+            # matmul with fp32 accumulation
+            acc_scr[r, :] = acc_scr[r, :] * corr + jax.lax.dot_general(
+                p.astype(vblk.dtype), vblk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-    @pl.when(t == n_k - 1)
+        _over_row_chunks(q_ref.shape[1], rows, chunk)
+
+    _on_live_pair(live, _pair_masked(
+        causal, has_seg, j, kk, block_q, block_k, q_offset, window)
+        if masked is None else masked, _step)
+
+    @pl.when(last)
     def _emit():
         l_safe = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
@@ -322,9 +477,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, block_k: int, scale: float,
             m_scr[...] + jnp.log(l_safe), lse_ref.shape[1:])
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-               block_k: int, scale: float, causal: bool,
-               block_q: int, q_offset: int, has_seg: bool):
+def _dq_kernel(qt_ref, kt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+               delta_ref, *rest, block_k: int, scale: float, causal: bool,
+               block_q: int, q_offset: int, has_seg: bool,
+               masked: Optional[bool], rows: int):
     from jax.experimental import pallas as pl
 
     if has_seg:
@@ -333,53 +489,61 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         dq_ref, dq_scr = rest
         qs_ref = ks_ref = None
 
-    j = pl.program_id(1)
-    kk = pl.program_id(2)
-    n_k = pl.num_programs(2)
+    t = pl.program_id(1)
+    j, kk = qt_ref[t], kt_ref[t]
+    first, last = _row_edges(qt_ref, t)
 
-    @pl.when(kk == 0)
+    @pl.when(first)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    bq = q_ref.shape[1]
-    q_end = q_offset + (j + 1) * block_q - 1
-    live = True if not causal else kk * block_k <= q_end
+    live = _pair_live(causal, j, kk, block_q, block_k, q_offset)
 
-    @pl.when(live)
-    def _step():
-        q = q_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, :1]      # (BQ, 1) f32 (lanes replicated)
-        delta = delta_ref[0][:, :1]  # (BQ, 1) f32
-        kblk = k_ref[0]
-        vblk = v_ref[0]
-        s = jax.lax.dot_general(
-            q, kblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        p = jnp.exp(s - lse)  # rows already normalized via lse
-        valid = _block_valid(
-            causal,
-            None if qs_ref is None else qs_ref[0][:, :1],
-            None if ks_ref is None else ks_ref[0][:1, :],
-            bq, j, kk, block_q, block_k, q_offset)
-        if valid is not None:
-            p = jnp.where(valid, p, 0.0)
-        dp = jax.lax.dot_general(   # dO @ V^T  (BQ, BK)
-            do, vblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        dq_scr[...] += jax.lax.dot_general(  # dS @ K  (BQ, d)
-            ds.astype(kblk.dtype), kblk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def _step(masked):
+        def chunk(r0):
+            r = pl.ds(r0, rows)
+            q = q_ref[0, r, :]
+            do = do_ref[0, r, :]
+            lse = lse_ref[0, r, :1]      # (rows, 1) f32 (lanes replicated)
+            delta = delta_ref[0, r, :1]  # (rows, 1) f32
+            kblk = k_ref[0]
+            vblk = v_ref[0]
+            s = jax.lax.dot_general(
+                q, kblk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            p = jnp.exp(s - lse)  # rows already normalized via lse
+            valid = _block_valid(
+                causal and masked,
+                None if qs_ref is None else qs_ref[0, r, :1],
+                None if ks_ref is None else ks_ref[0][:1, :],
+                rows, q_offset + j * block_q + r0, kk * block_k, block_k)
+            if valid is not None:
+                p = jnp.where(valid, p, 0.0)
+            dp = jax.lax.dot_general(   # dO @ V^T  (rows, BK)
+                do, vblk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ds = p * (dp - delta)  # its scale waits for _emit
+            dq_scr[r, :] += jax.lax.dot_general(  # dS @ K  (rows, d)
+                ds.astype(kblk.dtype), kblk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-    @pl.when(kk == n_k - 1)
+        _over_row_chunks(q_ref.shape[1], rows, chunk)
+
+    _on_live_pair(live, _pair_masked(
+        causal, has_seg, j, kk, block_q, block_k, q_offset)
+        if masked is None else masked, _step)
+
+    @pl.when(last)
     def _emit():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+        # dS's scale is linear in the accumulator: applied here, in
+        # float32, once a block and not once a step
+        dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, *rest,
-                block_q: int, scale: float, causal: bool, block_k: int,
-                q_offset: int, has_seg: bool):
+def _dkv_kernel(qt_ref, kt_ref, k_ref, v_ref, q_ref, do_ref, lse_ref,
+                delta_ref, *rest, block_q: int, scale: float, causal: bool,
+                block_k: int, q_offset: int, has_seg: bool,
+                masked: Optional[bool], rows: int):
     from jax.experimental import pallas as pl
 
     if has_seg:
@@ -388,56 +552,60 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, *rest,
         dk_ref, dv_ref, dk_scr, dv_scr = rest
         qs_ref = ks_ref = None
 
-    j = pl.program_id(1)   # k-block index
-    qq = pl.program_id(2)  # q-block index (innermost: Q/dO stream)
-    n_q = pl.num_programs(2)
+    t = pl.program_id(1)
+    # the table runs by K block here: a row of steps owns K block j and
+    # streams its Q/dO blocks
+    qq, j = qt_ref[t], kt_ref[t]
+    first, last = _row_edges(kt_ref, t)
 
-    @pl.when(qq == 0)
+    @pl.when(first)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    bk = k_ref.shape[1]
-    # q block is live iff its last query can see this k block
-    q_last = q_offset + (qq + 1) * block_q - 1
-    live = True if not causal else q_last >= j * block_k
+    live = _pair_live(causal, qq, j, block_q, block_k, q_offset)
 
-    @pl.when(live)
-    def _step():
-        k = k_ref[0]  # (BK, d)
-        v = v_ref[0]
-        qblk = q_ref[0]
-        doblk = do_ref[0]
-        lse = lse_ref[0][:, :1]
-        delta = delta_ref[0][:, :1]
-        s = jax.lax.dot_general(  # Q @ K^T  (BQ, BK)
-            qblk, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        p = jnp.exp(s - lse)
-        # note the grid transpose: this program's q-block index is qq and
-        # its k-block index is j, so the roles swap vs _block_valid's
-        # forward-grid signature
-        valid = _block_valid(
-            causal,
-            None if qs_ref is None else qs_ref[0][:, :1],
-            None if ks_ref is None else ks_ref[0][:1, :],
-            block_q, qq, j, block_q, block_k, q_offset)
-        if valid is not None:
-            p = jnp.where(valid, p, 0.0)
-        dv_scr[...] += jax.lax.dot_general(  # P^T @ dO  (BK, d)
-            p.astype(doblk.dtype), doblk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(  # dO @ V^T  (BQ, BK)
-            doblk, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * scale).astype(qblk.dtype)
-        dk_scr[...] += jax.lax.dot_general(  # dS^T @ Q  (BK, d)
-            ds, qblk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def _step(masked):
+        def chunk(r0):
+            r = pl.ds(r0, rows)
+            k = k_ref[0]  # (BK, d)
+            v = v_ref[0]
+            qblk = q_ref[0, r, :]
+            doblk = do_ref[0, r, :]
+            lse = lse_ref[0, r, :1]
+            delta = delta_ref[0, r, :1]
+            s = jax.lax.dot_general(  # Q @ K^T  (rows, BK)
+                qblk, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            p = jnp.exp(s - lse)
+            valid = _block_valid(
+                causal and masked,
+                None if qs_ref is None else qs_ref[0, r, :1],
+                None if ks_ref is None else ks_ref[0][:1, :],
+                rows, q_offset + qq * block_q + r0, j * block_k, block_k)
+            if valid is not None:
+                p = jnp.where(valid, p, 0.0)
+            dv_scr[...] += jax.lax.dot_general(  # P^T @ dO  (BK, d)
+                p.astype(doblk.dtype), doblk, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(  # dO @ V^T  (rows, BK)
+                doblk, v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta)).astype(qblk.dtype)
+            dk_scr[...] += jax.lax.dot_general(  # dS^T @ Q  (BK, d)
+                ds, qblk, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-    @pl.when(qq == n_q - 1)
+        _over_row_chunks(q_ref.shape[1], rows, chunk)
+
+    _on_live_pair(live, _pair_masked(
+        causal, has_seg, qq, j, block_q, block_k, q_offset)
+        if masked is None else masked, _step)
+
+    @pl.when(last)
     def _emit():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        # dS's scale, as in the dq kernel; dv has none
+        dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
@@ -511,9 +679,20 @@ def _tileable(s_q, s_k, block_k) -> bool:
     return s_k % bk == 0
 
 
-_DEFAULT_BLOCK = 512  # per-program tile default (best at the benchmarked
-# 1k/16k shapes, PERF.md §8.2); mid sequences clamp down, the autotuner
-# overrides per shape
+_DEFAULT_BLOCK = 512  # per-program tile default; mid sequences clamp
+# down, the autotuner overrides per shape
+
+
+def _default_block(s_q: int, s_k: int, d: int) -> int:
+    """1,024-blocks where the chip showed them to win: heads of at most
+    128 over lengths that are multiples of 1,024. A step's softmax state
+    (the ``(bq, 1)`` max and sum, the accumulator's rescale) costs the
+    same whatever the K block's width, and that work, not the MXU, sets
+    the forward's pace: 37% off the forward, 11% off dq and 16% off dk/dv
+    at bf16[48,4096,128]; under 4% either way at heads of 256 (PERF.md
+    §6, PR 37). Other lengths keep 512 and its clamps."""
+    wide = d <= 128 and s_q % 1024 == 0 and s_k % 1024 == 0
+    return 1024 if wide else _DEFAULT_BLOCK
 
 
 def _clamp_block(block: int, s: int) -> int:
@@ -535,28 +714,35 @@ def _resolve_blocks(s_q: int, s_k: int, d: int, causal: bool, dtype,
                     ) -> "tuple[int, int]":
     """Static block-size resolution: explicit arguments win; otherwise
     consult the autotuner (bigdl_tpu.tuning, a no-op in off mode) and
-    fall back to the 512 defaults. Both dims are then clamped to a
+    fall back to :func:`_default_block`. Both dims are then clamped to a
     standard tiling that divides their sequence."""
     if block_q is None or block_k is None:
         tuned = None
         from bigdl_tpu import tuning
         if tuning.get_mode() != "off":
             tuned = tuning.flash_blocks(s_q, s_k, d, causal, dtype)
+        default = _default_block(s_q, s_k, d)
         if block_q is None:
-            block_q = tuned[0] if tuned else _DEFAULT_BLOCK
+            block_q = tuned[0] if tuned else default
         if block_k is None:
-            block_k = tuned[1] if tuned else _DEFAULT_BLOCK
+            block_k = tuned[1] if tuned else default
     return _clamp_block(block_q, s_q), _clamp_block(block_k, s_k)
 
 
 def flash_block_plan(s_q: int, s_k: int, d: int, causal: bool,
-                     dtype) -> dict:
+                     dtype, *, window: Optional[int] = None,
+                     block_q: Optional[int] = None,
+                     block_k: Optional[int] = None) -> dict:
     """Static view of what :func:`flash_attention` would do at this
     shape — the block metadata tpulint (bigdl_tpu.analysis) evaluates
     without tracing a kernel:
 
     * ``block_q``/``block_k`` — the resolved (autotuner-consulted,
       clamped) tile sizes;
+    * ``grid_steps``/``live_pairs``/``masked_pairs`` — per (row, head) of
+      the forward: the block pairs its grid steps through, those whose
+      matmuls run, and those that run the masked body (the diagonal's
+      and a window's far edge's; 0 each off the kernel);
     * ``kernel_ok`` — False when the ragged key length knocks the call
       off the Pallas kernel onto the remat-scan fallback;
     * ``q_pad``/``k_pad`` — rows a padded final block would add (the
@@ -564,13 +750,24 @@ def flash_block_plan(s_q: int, s_k: int, d: int, causal: bool,
     * ``clamped`` — blocks sit below the 512 default because the seq
       admits no larger divisor (fine, but worth a note).
     """
-    bq, bk = _resolve_blocks(int(s_q), int(s_k), int(d), bool(causal),
-                             dtype, None, None)
+    s_q, s_k, causal = int(s_q), int(s_k), bool(causal)
+    bq, bk = _resolve_blocks(s_q, s_k, int(d), causal, dtype, block_q,
+                             block_k)
+    kernel_ok = _tileable(s_q, s_k, bk)
+    q_offset = s_k - s_q
+    steps = list(zip(*_pair_table(-(-s_q // bq), s_k // bk, bq, bk, causal,
+                                  q_offset, window))) if kernel_ok else []
+    live = [pair for pair in steps
+            if _pair_live(causal, *pair, bq, bk, q_offset)]
     return {
         "block_q": bq, "block_k": bk,
-        "kernel_ok": _tileable(int(s_q), int(s_k), bk),
-        "q_pad": (-int(s_q)) % bq,
-        "k_pad": (-int(s_k)) % bk,
+        "grid_steps": len(steps), "live_pairs": len(live),
+        "masked_pairs": sum(bool(_pair_masked(
+            causal, False, *pair, bq, bk, q_offset, window))
+            for pair in live),
+        "kernel_ok": kernel_ok,
+        "q_pad": (-s_q) % bq,
+        "k_pad": (-s_k) % bk,
         "clamped": (bq < _DEFAULT_BLOCK and bq < s_q)
                    or (bk < _DEFAULT_BLOCK and bk < s_k),
     }
@@ -661,11 +858,25 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
     32 B — the backward re-broadcasts next to its delta broadcast.
 
     With a ``window`` (causal, no segments) the call is named
-    ``flash_fwd_window``: its innermost grid dim is the band's width in K
-    blocks, and the K/V index map starts each query block at the first K
-    block it can see. A step past the block's last live K block names
-    that last block again, so nothing is copied for it."""
+    ``flash_fwd_window`` and its pair table holds each query block's band
+    alone."""
+    return _fwd_program(q, k, v, segments, causal, block_q, block_k, window,
+                        _interpret())
+
+
+# One kernel a signature, not one a layer: a model builds its layers in a
+# Python loop, and a bare ``pallas_call`` is traced, lowered and verified
+# again by every one of them (0.6-0.9 s of host time for a program of 18-30
+# identical kernels, before the compile cache's key exists). Under ``jit``
+# the second layer finds the first one's trace, and the outer program's
+# module holds one kernel and a call a layer. No shardings of its own:
+# inside ``shard_map``, a sharded ``jit`` or ``jax.checkpoint`` it traces
+# as the bare call did. ``interpret`` is in the key because tests switch it.
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
+def _fwd_program(q, k, v, segments, causal, block_q, block_k, window,
+                 interpret):
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     b, h, s_q, d = q.shape
     s_k = k.shape[-2]
@@ -681,59 +892,39 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
     sq, sk = qf.shape[1], kf.shape[1]
 
     q_offset = s_k - s_q
-    kernel = functools.partial(_fwd_kernel, block_k=bk, scale=scale,
-                               causal=causal, block_q=bq,
-                               q_offset=q_offset,
-                               has_seg=segments is not None, window=window)
-    if window is None:
-        n_inner = sk // bk
-        kv_block = lambda i, j, kk: (i, kk, 0)
-    else:
-        n_inner = _band_width(sq, sk, bq, bk, q_offset, window)
-
-        def kv_block(i, j, t):
-            last = jnp.minimum(_band_last(j, bq, bk, q_offset),
-                               sk // bk - 1)
-            return (i, jnp.minimum(
-                _band_first(j, bq, bk, q_offset, window) + t, last), 0)
-    in_specs = [
-        pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0)),
-        pl.BlockSpec((1, bk, d), kv_block),
-        pl.BlockSpec((1, bk, d), kv_block),
-    ]
+    has_seg = segments is not None
+    tables = _pair_table(sq // bq, sk // bk, bq, bk, causal, q_offset,
+                         window)
+    kernel = functools.partial(
+        _fwd_kernel, block_k=bk, scale=scale, causal=causal, block_q=bq,
+        q_offset=q_offset, has_seg=has_seg, window=window,
+        masked=_table_masked(tables, causal, has_seg, bq, bk, q_offset,
+                             window),
+        rows=_chunk_rows(bq, bk))
+    q_spec, k_spec, row_spec, seg_specs = _pair_specs(bq, bk, d, h, has_seg)
     args = [qf, kf, vf]
-    if segments is not None:
-        qs3, ks3 = _seg_arrays(segments, sq, sk, bq)
-        # seg arrays are per-batch; grid dim 0 walks b*h -> divide out h
-        in_specs += [
-            pl.BlockSpec((1, bq, _LSE_LANES),
-                         lambda i, j, kk: (i // h, j, 0)),
-            pl.BlockSpec((1, _LSE_LANES, bk),
-                         lambda i, j, kk: (i // h, 0, kk)),
-        ]
-        args += [qs3, ks3]
+    if has_seg:
+        args += _seg_arrays(segments, sq, sk, bq)
     n_pairs = _live_block_pairs(sq, sk, bq, bk, causal, q_offset, window)
     out, lse = pl.pallas_call(
         kernel,
-        grid=(b * h, sq // bq, n_inner),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b * h, len(tables[0])),
+            in_specs=[q_spec, k_spec, k_spec] + seg_specs,
+            out_specs=[q_spec, row_spec],
+            scratch_shapes=[
+                pltpu_scratch((bq, 1)), pltpu_scratch((bq, 1)),
+                pltpu_scratch((bq, d)),
+            ]),
         cost_estimate=_attn_cost(b * h, n_pairs, bq, bk, d,
                                  q.dtype.itemsize, units=2),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0)),
-            pl.BlockSpec((1, bq, _LSE_LANES), lambda i, j, kk: (i, j, 0)),
-        ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, sq, _LSE_LANES), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu_scratch((bq, 1)), pltpu_scratch((bq, 1)),
-            pltpu_scratch((bq, d)),
-        ],
-        interpret=_interpret(),
+        interpret=interpret,
         name="flash_fwd" if window is None else "flash_fwd_window",
-    )(*args)
+    )(*tables, *args)
     o = out[:, :s_q] if pad_q else out
     return o.reshape(b, h, s_q, d), lse[..., 0]
 
@@ -741,12 +932,19 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
 def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int,
                block_k: int, segments=None, window: Optional[int] = None):
     """Pallas dq + dk/dv kernels over the recomputed probabilities."""
-    from jax.experimental import pallas as pl
-
     if window is not None:
         raise NotImplementedError(
             "flash_attention: the backward kernels have no window "
             f"(window={window}); the windowed kernel is forward only")
+    return _bwd_program(q, k, v, o, lse, g, segments, causal, block_q,
+                        block_k, _interpret())
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10))
+def _bwd_program(q, k, v, o, lse, g, segments, causal, block_q, block_k,
+                 interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     b, h, s_q, d = q.shape
     s_k = k.shape[-2]
@@ -772,81 +970,45 @@ def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int,
     lse = jnp.broadcast_to(lse[..., None], lse.shape + (_LSE_LANES,))
     sq, sk = qf.shape[1], kf.shape[1]
     q_offset = s_k - s_q
-    interpret = _interpret()
     has_seg = segments is not None
-    if has_seg:
-        qs3, ks3 = _seg_arrays(segments, sq, sk, bq)
 
-    dq_specs = [
-        pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0)),
-        pl.BlockSpec((1, bk, d), lambda i, j, kk: (i, kk, 0)),
-        pl.BlockSpec((1, bk, d), lambda i, j, kk: (i, kk, 0)),
-        pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0)),
-        pl.BlockSpec((1, bq, _LSE_LANES), lambda i, j, kk: (i, j, 0)),
-        pl.BlockSpec((1, bq, _LSE_LANES), lambda i, j, kk: (i, j, 0)),
-    ]
-    dq_args = [qf, kf, vf, dof, lse, delta]
-    if has_seg:
-        dq_specs += [
-            pl.BlockSpec((1, bq, _LSE_LANES),
-                         lambda i, j, kk: (i // h, j, 0)),
-            pl.BlockSpec((1, _LSE_LANES, bk),
-                         lambda i, j, kk: (i // h, 0, kk)),
-        ]
-        dq_args += [qs3, ks3]
+    q_spec, k_spec, row_spec, seg_specs = _pair_specs(bq, bk, d, h, has_seg)
+    seg_args = _seg_arrays(segments, sq, sk, bq) if has_seg else ()
     n_pairs = _live_block_pairs(sq, sk, bq, bk, causal, q_offset)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, block_k=bk, scale=scale,
-                          causal=causal, block_q=bq, q_offset=q_offset,
-                          has_seg=has_seg),
-        grid=(b * h, sq // bq, sk // bk),
-        cost_estimate=_attn_cost(b * h, n_pairs, bq, bk, d,
-                                 qf.dtype.itemsize, units=2),
-        in_specs=dq_specs,
-        out_specs=pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-        scratch_shapes=[pltpu_scratch((bq, d))],
-        interpret=interpret,
-        name="flash_dq",
-    )(*dq_args)
 
-    dkv_specs = [
-        pl.BlockSpec((1, bk, d), lambda i, j, qq: (i, j, 0)),
-        pl.BlockSpec((1, bk, d), lambda i, j, qq: (i, j, 0)),
-        pl.BlockSpec((1, bq, d), lambda i, j, qq: (i, qq, 0)),
-        pl.BlockSpec((1, bq, d), lambda i, j, qq: (i, qq, 0)),
-        pl.BlockSpec((1, bq, _LSE_LANES), lambda i, j, qq: (i, qq, 0)),
-        pl.BlockSpec((1, bq, _LSE_LANES), lambda i, j, qq: (i, qq, 0)),
-    ]
-    dkv_args = [kf, vf, qf, dof, lse, delta]
-    if has_seg:
-        dkv_specs += [
-            pl.BlockSpec((1, bq, _LSE_LANES),
-                         lambda i, j, qq: (i // h, qq, 0)),
-            pl.BlockSpec((1, _LSE_LANES, bk),
-                         lambda i, j, qq: (i // h, 0, j)),
-        ]
-        dkv_args += [qs3, ks3]
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, block_q=bq, scale=scale,
-                          causal=causal, block_k=bk, q_offset=q_offset,
-                          has_seg=has_seg),
-        grid=(b * h, sk // bk, sq // bq),
-        cost_estimate=_attn_cost(b * h, n_pairs, bq, bk, d,
-                                 kf.dtype.itemsize, units=2),
-        in_specs=dkv_specs,
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda i, j, qq: (i, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, j, qq: (i, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
-        ],
-        scratch_shapes=[pltpu_scratch((bk, d)), pltpu_scratch((bk, d))],
-        interpret=interpret,
-        name="flash_dkv",
-    )(*dkv_args)
+    def call(kernel, name, by_key, in_specs, out_spec, out_shapes, args):
+        tables = _pair_table(sq // bq, sk // bk, bq, bk, causal, q_offset,
+                             by_key=by_key)
+        return pl.pallas_call(
+            functools.partial(
+                kernel, block_q=bq, block_k=bk, scale=scale, causal=causal,
+                q_offset=q_offset, has_seg=has_seg,
+                masked=_table_masked(tables, causal, has_seg, bq, bk,
+                                     q_offset),
+                rows=_chunk_rows(bq, bk)),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(b * h, len(tables[0])),
+                in_specs=in_specs + seg_specs,
+                out_specs=[out_spec] * len(out_shapes),
+                scratch_shapes=[pltpu_scratch(out_spec.block_shape[1:])
+                                for _ in out_shapes]),
+            cost_estimate=_attn_cost(b * h, n_pairs, bq, bk, d,
+                                     qf.dtype.itemsize, units=2),
+            out_shape=out_shapes,
+            interpret=interpret,
+            name=name,
+        )(*tables, *args, *seg_args)
+
+    dq, = call(_dq_kernel, "flash_dq", False,
+               [q_spec, k_spec, k_spec, q_spec, row_spec, row_spec], q_spec,
+               [jax.ShapeDtypeStruct((b * h, sq, d), q.dtype)],
+               [qf, kf, vf, dof, lse, delta])
+    dk, dv = call(_dkv_kernel, "flash_dkv", True,
+                  [k_spec, k_spec, q_spec, q_spec, row_spec, row_spec],
+                  k_spec,
+                  [jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
+                   jax.ShapeDtypeStruct((b * h, sk, d), v.dtype)],
+                  [kf, vf, qf, dof, lse, delta])
 
     dq = (dq[:, :s_q] if pad_q else dq).reshape(b, h, s_q, d)
     return dq, dk.reshape(b, h, s_k, d), dv.reshape(b, h, s_k, d)

@@ -17,6 +17,16 @@ def _qkv(b=2, h=2, s=64, d=16, seed=0):
     return mk(), mk(), mk()
 
 
+@pytest.fixture
+def fresh_traces():
+    """The kernels sit under an inner ``jit`` (one trace a signature, not
+    one a layer), which knows nothing of what a test plants in the module:
+    such a test drops every cached trace before and after itself."""
+    jax.clear_caches()
+    yield jax.clear_caches
+    jax.clear_caches()
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_matches_dense(causal):
     q, k, v = _qkv()
@@ -412,7 +422,7 @@ def test_flash_segments_bf16():
                                np.asarray(want), atol=5e-2)
 
 
-def test_block_specs_satisfy_mosaic_tiling():
+def test_block_specs_satisfy_mosaic_tiling(fresh_traces):
     """Static Mosaic tiling lint, no TPU needed: intercept every
     pallas_call the flash kernels make and check each block's last two
     dims are (8k, 128k)-aligned or equal to the array dims — the exact
@@ -425,9 +435,9 @@ def test_block_specs_satisfy_mosaic_tiling():
     real_call = real_pl.pallas_call
 
     def spy(kernel, **kw):
-        specs = []
-        in_specs = kw.get("in_specs") or []
-        out_specs = kw.get("out_specs")
+        grid_spec = kw["grid_spec"]
+        in_specs = grid_spec.in_specs
+        out_specs = grid_spec.out_specs
         out_shape = kw.get("out_shape")
         outs = out_specs if isinstance(out_specs, (list, tuple)) \
             else [out_specs]
@@ -436,7 +446,9 @@ def test_block_specs_satisfy_mosaic_tiling():
         inner = real_call(kernel, **kw)
 
         def wrapped(*args):
-            for spec, arr in list(zip(in_specs, args)) + [
+            # the scalar-prefetched pair tables come first and have no spec
+            blocked = args[grid_spec.num_scalar_prefetch:]
+            for spec, arr in list(zip(in_specs, blocked)) + [
                     (s, sh) for s, sh in zip(outs, shapes)]:
                 if spec is None:
                     continue
@@ -547,3 +559,380 @@ def test_default_blocks_clamp_for_mid_sequences():
     padded = 2 * (2.0 * b * h * _live_block_pairs(1024, s, 512, 128,
                                                   True, 0) * 512 * 128 * d)
     assert abs(got - padded) / padded > 0.05
+
+
+# ---------------------------------------------------------------------------
+# the kernels follow the causal structure (PR 37): a flattened grid of the
+# live block pairs, a masked body only where the mask can touch a pair
+# ---------------------------------------------------------------------------
+_SHAPES = [(512, 512), (1024, 1024), (512, 1536), (256, 1024)]
+_BLOCKS = [(128, 128), (256, 256), (512, 512), (128, 512), (512, 128),
+           (256, 128)]
+# (causal, segments, window): the calls the kernels serve
+_MASKS = [(True, False, None), (False, False, None), (True, True, None),
+          (False, True, None), (True, False, 128), (True, False, 384)]
+
+
+# the row-chunk loop of a step's body: off (the tile is one chunk at these
+# blocks) and on, at 64 rows a chunk (``_STEP_TILE`` planted at 64 x bk)
+_LOOP_BLOCKS = [(128, 128), (256, 128), (128, 512)]
+
+
+def _pair_cases(masks):
+    cases = [(sq, sk, bq, bk, None) for sq, sk in _SHAPES
+             for bq, bk in _BLOCKS]
+    cases += [(sq, sk, bq, bk, 64) for sq, sk in _SHAPES
+              for bq, bk in _LOOP_BLOCKS]
+    # 1,024-blocks: 512 rows a chunk as shipped, and 64
+    cases += [(2048, 2048, 1024, 1024, None), (2048, 2048, 1024, 1024, 64)]
+    for sq, sk, bq, bk, rows in cases:
+        for causal, seg, window in masks:
+            if seg and sq != sk:
+                continue  # segments are self-attention's
+            yield pytest.param(
+                sq, sk, bq, bk, rows, causal, seg, window,
+                id=f"{sq}x{sk}-b{bq}x{bk}-"
+                   f"{'causal' if causal else 'full'}"
+                   f"{'-seg' if seg else ''}"
+                   f"{'' if window is None else f'-w{window}'}"
+                   f"{'' if rows is None else f'-rows{rows}'}")
+
+
+def _plant_rows(monkeypatch, ak, rows, bk):
+    if rows is not None:
+        monkeypatch.setattr(ak, "_STEP_TILE", rows * bk)
+
+
+def _pair_inputs(sq, sk, seg, dtype, d=64, h=1, seed=21):
+    """Heads of 64: the scale is 1/8, so no backend's contraction of
+    ``s * scale - m`` into one rounding can tell the two bodies apart."""
+    rs = np.random.RandomState(seed)
+    mk = lambda s: jnp.asarray(rs.randn(1, h, s, d), dtype)
+    segs = None
+    if seg:
+        cuts = [sq // 3, sq // 3 + 77]
+        segs = jnp.asarray(np.r_[[1] * cuts[0], [2] * cuts[1],
+                                 [3] * (sq - sum(cuts))][None])
+    return mk(sq), mk(sk), mk(sk), mk(sq), segs
+
+
+@pytest.mark.parametrize("sq,sk,bq,bk,rows,causal,seg,window",
+                         list(_pair_cases(_MASKS)))
+def test_forward_equals_a_body_that_masks_every_pair(
+        monkeypatch, fresh_traces, sq, sk, bq, bk, rows, causal, seg,
+        window):
+    """``out`` and ``lse`` bit for bit against the reference form, in which
+    ``_block_valid`` is applied on every live pair over the whole tile: an
+    interior pair's mask is all true, so leaving it out changes no bit,
+    and rows are independent, so walking them in chunks changes none."""
+    from bigdl_tpu.ops import attention_kernel as ak
+
+    q, k, v, _, segs = _pair_inputs(sq, sk, seg, jnp.bfloat16)
+    call = lambda: ak._flash_fwd(q, k, v, causal, bq, bk, segments=segs,
+                                 window=window)
+    with monkeypatch.context() as planted:
+        _plant_rows(planted, ak, rows, bk)
+        assert ak._chunk_rows(bq, bk) == (rows or min(bq, 512 * 1024 // bk))
+        out, lse = call()
+    fresh_traces()
+    monkeypatch.setattr(ak, "_pair_masked",
+                        lambda causal, has_seg, *a: causal or has_seg)
+    monkeypatch.setattr(ak, "_STEP_TILE", bq * bk)
+    ref_out, ref_lse = call()
+    assert np.array_equal(np.asarray(out, np.float32),
+                          np.asarray(ref_out, np.float32))
+    assert np.array_equal(np.asarray(lse), np.asarray(ref_lse))
+    # and the reference form is the dense path's numbers
+    mask = None
+    if seg:
+        from bigdl_tpu.nn.attention import make_segment_mask
+        mask = make_segment_mask(segs)
+    if window is not None:
+        from bigdl_tpu.ops.attention_kernel import band_mask
+        mask, causal = band_mask(sq, sk, window), False
+    want = dot_product_attention(*(x.astype(jnp.float32) for x in (q, k, v)),
+                                 causal=causal, mask=mask)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want), atol=5e-2)
+
+
+def test_forward_split_at_heads_of_128_is_within_one_rounding():
+    """At heads of 128 the scale is not a power of two; the CPU backend may
+    then round ``s * scale - m`` once in the unmasked body and twice in
+    the masked one. On the chip the two are bit-equal (PERF.md §6, PR 37)."""
+    from bigdl_tpu.ops import attention_kernel as ak
+
+    q, k, v, _, _ = _pair_inputs(512, 512, False, jnp.bfloat16, d=128)
+    out, lse = ak._flash_fwd(q, k, v, True, 128, 128)
+    want = dot_product_attention(*(x.astype(jnp.float32) for x in (q, k, v)),
+                                 causal=True)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want), atol=5e-2)
+    dense_lse = jax.nn.logsumexp(jnp.where(
+        jnp.tril(jnp.ones((512, 512), bool)),
+        jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                   k.astype(jnp.float32)) / np.sqrt(128.0), -jnp.inf), -1)
+    np.testing.assert_allclose(np.asarray(lse).reshape(1, 1, 512),
+                               np.asarray(dense_lse), atol=2e-6, rtol=2e-6)
+
+
+@pytest.mark.parametrize("sq,sk,bq,bk,rows,causal,seg,window",
+                         list(_pair_cases(_MASKS[:4])))
+def test_backward_over_block_pairs_matches_dense_grad(
+        monkeypatch, fresh_traces, sq, sk, bq, bk, rows, causal, seg,
+        window):
+    """dq, dk, dv (the scale applied once at ``_emit``; dk and dv summed
+    over a tile's row chunks) against ``jax.grad`` of the dense float32
+    reference."""
+    from bigdl_tpu.ops import attention_kernel as ak
+
+    _plant_rows(monkeypatch, ak, rows, bk)
+    q, k, v, g, segs = _pair_inputs(sq, sk, seg, jnp.float32)
+    mask = None
+    if seg:
+        from bigdl_tpu.nn.attention import make_segment_mask
+        mask = make_segment_mask(segs)
+
+    def scalar(f):
+        return lambda q, k, v: jnp.vdot(f(q, k, v), g)
+
+    got = jax.grad(scalar(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, segments=segs, block_q=bq, block_k=bk)),
+        argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(scalar(lambda q, k, v: dot_product_attention(
+        q, k, v, causal=causal, mask=mask)), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+
+
+def test_backward_bf16_at_heads_of_128_matches_dense_grad():
+    """bf16 at the benchmark's head size, where the scale at ``_emit`` is a
+    rounding of its own (1/sqrt(128) is no power of two)."""
+    q, k, v, g, _ = _pair_inputs(512, 1536, False, jnp.bfloat16, d=128)
+    f32 = lambda x: x.astype(jnp.float32)
+
+    def scalar(f):
+        return lambda q, k, v: jnp.vdot(f32(f(q, k, v)), f32(g))
+
+    got = jax.grad(scalar(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=256, block_k=512)),
+        argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(scalar(lambda q, k, v: dot_product_attention(
+        q, k, v, causal=True)), argnums=(0, 1, 2))(f32(q), f32(k), f32(v))
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b), atol=5e-2)
+
+
+@pytest.mark.parametrize("sq,sk,bq,bk,causal,window", [
+    (512, 512, 128, 128, True, None),
+    (1024, 1024, 256, 128, True, None),
+    (512, 1536, 128, 256, True, None),    # q_offset 1,024
+    (256, 1024, 128, 512, True, None),
+    (512, 256, 128, 128, True, None),     # s_q > s_k: rows with no key
+    (512, 512, 128, 256, False, None),
+    (1024, 1024, 128, 128, True, 384),
+    (512, 1536, 256, 128, True, 128),
+])
+def test_live_block_pairs_counts_body_executions(monkeypatch, fresh_traces,
+                                                 sq, sk, bq, bk, causal,
+                                                 window):
+    """``_live_block_pairs`` and the pair table against the kernels as they
+    run: every step body calls ``_block_valid`` once, and interpret mode
+    runs a host callback planted there."""
+    from bigdl_tpu.ops import attention_kernel as ak
+
+    ran = []
+    block_valid = ak._block_valid
+
+    def counted(*a, **kw):
+        jax.debug.callback(lambda: ran.append(1))
+        return block_valid(*a, **kw)
+
+    monkeypatch.setattr(ak, "_block_valid", counted)
+    q, k, v, g, _ = _pair_inputs(sq, sk, False, jnp.float32, d=16, h=2)
+    out, lse = ak._flash_fwd(q, k, v, causal, bq, bk, window=window)
+    jax.block_until_ready(out)
+    jax.effects_barrier()
+    pairs = ak._live_block_pairs(sq, sk, bq, bk, causal, sk - sq, window)
+    assert len(ran) == 2 * pairs  # two (batch, head) rows
+    qt, kt = ak._pair_table(sq // bq, sk // bk, bq, bk, causal, sk - sq,
+                            window)
+    dead = sum(1 for j in range(sq // bq)
+               if causal and sk - sq + (j + 1) * bq - 1 < 0)
+    assert len(qt) == pairs + dead
+    if window is None:
+        del ran[:]
+        jax.block_until_ready(ak._flash_bwd(q, k, v, out, lse, g, causal,
+                                            bq, bk))
+        jax.effects_barrier()
+        assert len(ran) == 2 * 2 * pairs  # dq and dk/dv, two rows each
+        by_key = ak._pair_table(sq // bq, sk // bk, bq, bk, causal, sk - sq,
+                                by_key=True)
+        live = lambda t: {(j, kk) for j, kk in zip(*t) if ak._pair_live(
+            causal, j, kk, bq, bk, sk - sq)}
+        assert live(by_key) == live((qt, kt)) and len(live(by_key)) == pairs
+
+
+@pytest.mark.parametrize("kw,want", [
+    (dict(block_q=512, block_k=512), (36, 36, 8)),   # the parent: 64/36/36
+    (dict(), (10, 10, 4)),                           # 1,024-blocks
+    (dict(causal=False), (16, 16, 0)),
+    (dict(s=8192, window=4096, block_q=512, block_k=512), (108, 108, 24)),
+    (dict(s=8192, window=4096), (30, 30, 12)),
+])
+def test_flash_block_plan_counts_steps_and_masked_pairs(kw, want):
+    """How often the mechanism engages, without tracing: the forward's grid
+    steps, live pairs and masked pairs per (row, head)."""
+    from bigdl_tpu.ops.attention_kernel import (_live_block_pairs,
+                                                flash_block_plan)
+
+    kw = dict(kw)
+    s, causal = kw.pop("s", 4096), kw.pop("causal", True)
+    plan = flash_block_plan(s, s, 128, causal, jnp.bfloat16, **kw)
+    assert (plan["grid_steps"], plan["live_pairs"],
+            plan["masked_pairs"]) == want
+    assert plan["live_pairs"] == _live_block_pairs(
+        s, s, plan["block_q"], plan["block_k"], causal, 0, kw.get("window"))
+
+
+@pytest.mark.parametrize("sq,sk,d,want", [
+    (4096, 4096, 128, (1024, 1024)),
+    (2048, 2048, 64, (1024, 1024)),
+    (1024, 3072, 128, (1024, 1024)),
+    (4096, 4096, 256, (512, 512)),    # wide heads: under 4% either way
+    (512, 2048, 128, (512, 512)),     # a suffix of 512 rows
+    (768, 768, 64, (256, 256)),       # mid sequences clamp as they did
+    (1536, 1536, 128, (512, 512)),
+])
+def test_default_blocks_are_1024_where_the_chip_showed_them_to_win(
+        sq, sk, d, want):
+    from bigdl_tpu.ops.attention_kernel import _resolve_blocks
+
+    assert _resolve_blocks(sq, sk, d, True, jnp.bfloat16, None, None) == want
+    # an explicit block still wins
+    assert _resolve_blocks(sq, sk, d, True, jnp.bfloat16, 128, 128) == \
+        (128, 128)
+
+
+def test_pair_table_refuses_what_scalar_memory_cannot_hold():
+    from bigdl_tpu.ops.attention_kernel import _MAX_PAIRS, _pair_table
+
+    qt, _ = _pair_table(256, 256, 512, 512, False, 0)
+    assert len(qt) == _MAX_PAIRS
+    with pytest.raises(ValueError, match="block pairs"):
+        _pair_table(257, 256, 512, 512, False, 0)
+
+
+# ---------------------------------------------------------------------------
+# what a program pays for the kernels (PR 38): only the bodies a call's pair
+# table uses, and one kernel a signature however many layers call it
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("sq,sk,kw,bodies", [
+    (512, 512, dict(causal=True), 1),               # one block pair: masked
+    (64, 64, dict(causal=True), 1),                 # a 64-row bucket
+    (1024, 1024, dict(causal=True, block_q=1024, block_k=256), 1),
+    (1024, 1024, dict(causal=True, segments=True, block_q=256,
+                      block_k=256), 1),             # packed: all masked
+    (1024, 1024, dict(causal=False, segments=True, block_q=256,
+                      block_k=256), 1),
+    (1024, 1024, dict(causal=False, block_q=256, block_k=256), 1),  # none
+    (1024, 1024, dict(causal=True, block_q=256, block_k=256), 2),
+    (256, 1024, dict(causal=True, block_q=256, block_k=256), 2),   # suffix
+    (2048, 2048, dict(causal=True), 2),             # 1,024-blocks
+], ids=["one_pair", "bucket_64", "all_diagonal", "packed", "packed_full",
+        "full", "causal", "suffix", "causal_1024"])
+def test_a_kernel_holds_only_the_bodies_its_table_uses(sq, sk, kw, bodies):
+    """The pair table is static: where every live pair is masked, or none
+    is, the kernel is lowered with the one ``_step`` body its table uses.
+    Counted in the jaxpr by its matmuls: two a body in ``flash_fwd``, three
+    in ``flash_dq``, four in ``flash_dkv``."""
+    kw = dict(kw)
+    q, k, v, g, segs = _pair_inputs(sq, sk, kw.pop("segments", False),
+                                    jnp.float32)
+    f = lambda q, k, v: flash_attention(q, k, v, segments=segs, **kw)
+    assert str(jax.make_jaxpr(f)(q, k, v)).count("dot_general") == 2 * bodies
+    back = jax.grad(lambda q, k, v: (f(q, k, v) * g).sum(),
+                    argnums=(0, 1, 2))
+    assert str(jax.make_jaxpr(back)(q, k, v)).count("dot_general") == \
+        9 * bodies
+
+
+@pytest.mark.parametrize("window,bodies", [(128, 1), (384, 2), (4096, 2)])
+def test_the_windowed_forward_holds_the_bodies_its_band_uses(window, bodies):
+    """A window of one block: every pair of the band is crossed by the
+    diagonal or the far edge, one body; a wider band has interior pairs."""
+    q, k, v, _, _ = _pair_inputs(1024, 1024, False, jnp.float32)
+    text = str(jax.make_jaxpr(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, block_q=128, block_k=128))(
+            q, k, v))
+    assert "flash_fwd_window" in text
+    assert text.count("dot_general") == 2 * bodies
+
+
+@pytest.mark.parametrize("sq,sk,bq,bk,causal,seg,window,want", [
+    (512, 512, 512, 512, True, False, None, True),
+    (1024, 1024, 256, 256, True, False, None, None),
+    (1024, 1024, 256, 256, False, False, None, False),
+    (1024, 1024, 256, 256, False, True, None, True),
+    (1024, 1024, 256, 256, True, True, None, True),
+    (512, 256, 128, 128, True, False, None, None),   # rows with no key
+    (1024, 1024, 128, 128, True, False, 128, True),  # a band of two edges
+    (1024, 1024, 128, 128, True, False, 384, None),
+])
+def test_table_masked_is_pair_masked_over_the_live_pairs(sq, sk, bq, bk,
+                                                         causal, seg, window,
+                                                         want):
+    from bigdl_tpu.ops import attention_kernel as ak
+
+    off = sk - sq
+    for by_key in (False, True) if window is None else (False,):
+        tables = ak._pair_table(sq // bq, sk // bk, bq, bk, causal, off,
+                                window, by_key=by_key)
+        kinds = {bool(ak._pair_masked(causal, seg, j, kk, bq, bk, off,
+                                      window))
+                 for j, kk in zip(*tables)
+                 if ak._pair_live(causal, j, kk, bq, bk, off)}
+        assert ak._table_masked(tables, causal, seg, bq, bk, off,
+                                window) is want
+        assert kinds == ({True, False} if want is None else {want})
+
+
+@pytest.mark.parametrize("layers", [1, 4])
+@pytest.mark.parametrize("wrap", ["plain", "remat", "grad"])
+def test_layers_share_one_trace_of_each_kernel(layers, wrap):
+    """A Python loop of layers traces each distinct kernel once: the
+    jaxpr names one ``_fwd_program`` closed jaxpr however many layers
+    call it, under ``jax.checkpoint`` and ``jax.grad`` as under nothing."""
+    q, k, v, g, _ = _pair_inputs(512, 512, False, jnp.float32)
+
+    def model(q, k, v):
+        x = q
+        for _ in range(layers):
+            x = flash_attention(x, k, v, causal=True, block_q=128,
+                                block_k=128)
+        return x
+
+    if wrap == "remat":
+        fn = jax.grad(lambda q, k, v: jnp.vdot(
+            jax.checkpoint(model)(q, k, v), g), argnums=(0, 1, 2))
+    elif wrap == "grad":
+        fn = jax.grad(lambda q, k, v: jnp.vdot(model(q, k, v), g),
+                      argnums=(0, 1, 2))
+    else:
+        fn = model
+    got = fn(q, k, v)
+    lowered = jax.jit(fn).lower(q, k, v).as_text()
+    # interpret mode lowers the kernel's body inline: one private function
+    # a kernel, a call a layer
+    n_fwd = lowered.count("func.func private @_fwd_program")
+    n_bwd = lowered.count("func.func private @_bwd_program")
+    assert n_fwd == 1 and n_bwd == (0 if wrap == "plain" else 1)
+    want = dot_product_attention(q, k, v, causal=True)
+    for _ in range(layers - 1):
+        want = dot_product_attention(want, k, v, causal=True)
+    if wrap == "plain":
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-4)
+    else:
+        assert all(np.isfinite(np.asarray(a)).all() for a in got)

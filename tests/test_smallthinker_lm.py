@@ -18,7 +18,7 @@ import pytest
 from bigdl_tpu import models, nn
 from bigdl_tpu.nn.attention import dot_product_attention
 from bigdl_tpu.ops import cache_write as cw
-from bigdl_tpu.ops.attention_kernel import (_band_width, _live_block_pairs,
+from bigdl_tpu.ops.attention_kernel import (_live_block_pairs, _pair_table,
                                             band_mask, flash_attention)
 from bigdl_tpu.serving import DecodeEngine, MetricsRegistry
 
@@ -163,18 +163,19 @@ def test_window_kernel_is_the_masked_softmax(s, bq, bk):
 
 
 def test_window_grid_walks_the_band_only():
-    """At s = 8 windows of 16 with blocks of 8 the inner grid axis is
-    (w + bq) / bk = 3 K blocks wide, not 16, and the cost counts the
-    band's block pairs."""
+    """At s = 8 windows of 16 with blocks of 8 a query block's steps are
+    the (w + bq) / bk = 3 K blocks of its band, not 16, and the grid and
+    the cost count the band's block pairs."""
     s = 8 * W
-    assert _band_width(s, s, 8, 8, 0, W) == 3
+    qt, kt = _pair_table(16, 16, 8, 8, True, 0, W)
+    assert max(np.bincount(qt)) == 3 and (qt - kt).max() == 2
     band = _live_block_pairs(s, s, 8, 8, True, 0, W)
-    assert band == 1 + 2 + 3 * 14
+    assert band == 1 + 2 + 3 * 14 == len(qt)
     assert _live_block_pairs(s, s, 8, 8, True, 0) == 16 * 17 // 2
     q, k, v = qkv(s)
     text = str(jax.make_jaxpr(lambda q, k, v: flash_attention(
         q, k, v, causal=True, window=W, block_q=8, block_k=8))(q, k, v))
-    assert "flash_fwd_window" in text and "grid=(2, 16, 3)" in text
+    assert "flash_fwd_window" in text and "grid=(2, 45)" in text
 
 
 def test_window_none_is_todays_call():
@@ -189,7 +190,7 @@ def test_window_none_is_todays_call():
                                            block_k=8)
     text = str(jax.make_jaxpr(plain)(q, k, v))
     assert text == str(jax.make_jaxpr(none)(q, k, v))
-    assert "flash_fwd_window" not in text and "grid=(2, 8, 8)" in text
+    assert "flash_fwd_window" not in text and "grid=(2, 36)" in text
     wide = flash_attention(q, k, v, causal=True, window=64, block_q=8,
                            block_k=8)
     assert (np.asarray(wide) == np.asarray(plain(q, k, v))).all()
