@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import collections
 import inspect
+import logging
 import threading
 import time
 from typing import Callable, Optional
@@ -56,6 +57,8 @@ from bigdl_tpu.serving.reqtrace import get as _get_reqtracer
 
 __all__ = ["AdmissionError", "DeadlineExceeded", "WorkerDied",
            "MicroBatcher"]
+
+logger = logging.getLogger(__name__)
 
 
 class AdmissionError(RuntimeError):
@@ -72,24 +75,49 @@ class WorkerDied(RuntimeError):
     fast instead of waiting out its timeout (HTTP 503)."""
 
 
+_CALLBACKS = threading.Lock()  # every future's callback list
+
+
 class _Future:
     """Minimal thread-safe future (no concurrent.futures executor to
     own it — the batcher resolves it from its worker thread)."""
 
-    __slots__ = ("_event", "_value", "_exc")
+    __slots__ = ("_event", "_value", "_exc", "_callbacks")
 
     def __init__(self):
         self._event = threading.Event()
         self._value = None
         self._exc = None
+        self._callbacks = []  # None once resolved
 
     def set_result(self, v) -> None:
         self._value = v
-        self._event.set()
+        self._resolve()
 
     def set_exception(self, e: BaseException) -> None:
         self._exc = e
+        self._resolve()
+
+    def _resolve(self) -> None:
         self._event.set()
+        with _CALLBACKS:
+            callbacks, self._callbacks = self._callbacks, None
+        for fn in callbacks or ():
+            try:
+                fn(self)
+            except Exception:  # the resolving thread must keep running
+                logger.exception("future done-callback failed")
+
+    def add_done_callback(self, fn) -> None:
+        """Call ``fn(future)`` once, when the future resolves: on the
+        resolving thread and under whatever lock it holds (the decode
+        engine's, for a request's future), so ``fn`` must not block; at
+        once on this thread if it has resolved already."""
+        with _CALLBACKS:
+            if self._callbacks is not None:
+                self._callbacks.append(fn)
+                return
+        fn(self)
 
     def done(self) -> bool:
         return self._event.is_set()

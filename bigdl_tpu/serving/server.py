@@ -3,7 +3,10 @@
 BigDL 2.0's Cluster Serving put a full streaming stack (Redis + Flink)
 in front of the model; the TPU-native equivalent starts smaller and
 honest: a ``ThreadingHTTPServer`` (one thread per connection, fine at
-micro-batcher concurrency levels) exposing
+micro-batcher concurrency levels; a streamed ``/generate``'s thread
+admits the request, sends the headers and then sleeps until its stream
+ends, while one stream writer thread an app sends every stream's SSE
+frames, a decode round's frames in one pass) exposing
 
 * ``POST /predict``  — ``{"inputs": [...]}`` -> argmax predictions
   (scores on request), routed through the dynamic micro-batcher so
@@ -45,10 +48,11 @@ throughout so the process is drained, not killed.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import json
 import logging
-import queue as _queue_mod
+import selectors
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -67,25 +71,288 @@ logger = logging.getLogger(__name__)
 __all__ = ["ServingApp", "make_server", "run_server"]
 
 _MAX_BODY = 64 * 1024 * 1024  # refuse absurd payloads before np.asarray
+_POLL_S = 0.005  # how often the writer retries a client that reads nothing
+_GRACE_S = 2.0  # how long unsent bytes may hold a stream that must end
+
+
+def _sse(obj: dict) -> bytes:
+    return b"data: " + json.dumps(obj).encode() + b"\n\n"
+
+
+def _chunk(data: bytes) -> bytes:
+    """One HTTP/1.1 chunked-transfer frame (``b""`` terminates)."""
+    return b"%x\r\n" % len(data) + data + b"\r\n"
 
 
 class _GenerateStream:
-    """Handle for one streamed /generate (ISSUE 18): the per-request
-    emit queue the decode loop feeds (``(tokens, done)`` per emitting
-    round), the request future (error surface for terminations that
-    never emit — expiry in the waiting queue, shutdown), the decoder
-    that owns the slot (the disconnect path calls ``cancel`` on exactly
-    this one, which matters under dp routing), and the prompt length for
-    the final frame."""
+    """Handle for one streamed /generate: the request future
+    (error surface for terminations that never emit — expiry in the
+    waiting queue, cancel, shutdown), the decoder that owns the slot
+    (the disconnect path calls ``cancel`` on exactly this one, which
+    matters under dp routing), and the prompt length for the final
+    frame. The rest is the stream writer's, touched by its
+    thread alone: the socket once attached, the bytes not yet sent, and
+    what has gone out; the handler thread sleeps on ``first`` (the first
+    tokens frame is out, or the stream ended without one) and ``ended``
+    (the terminating chunk is out, or the stream was given up)."""
 
-    __slots__ = ("rid", "queue", "future", "decoder", "prompt_len")
+    __slots__ = ("rid", "future", "decoder", "prompt_len", "sock", "out",
+                 "sent", "first_at", "n_out", "closed", "ok", "first_out",
+                 "first", "ended")
 
-    def __init__(self, rid, queue, future, decoder, prompt_len):
+    def __init__(self, rid, decoder, prompt_len):
         self.rid = rid
-        self.queue = queue
-        self.future = future
+        self.future = None  # set once submit returns
         self.decoder = decoder
         self.prompt_len = prompt_len
+        self.sock = None
+        self.out = bytearray()
+        self.sent = 0
+        self.first_at = None  # `sent` once the first tokens frame is out
+        self.n_out = 0
+        self.closed = False  # the terminating chunk is queued
+        self.ok = False  # the done frame is queued
+        self.first_out = False
+        self.first = threading.Event()
+        self.ended = threading.Event()
+
+
+_TOKENS, _END, _ATTACH, _DROP = range(4)
+
+
+class _StreamWriter:
+    """The one thread that writes every streamed /generate response of
+    an app. The decode loop's emit appends ``(stream, tokens,
+    done)`` to one deque and wakes the thread only if it sleeps, so the
+    64 emits of a round cost one wake-up, or a few, and never block the
+    engine lock they run under. Each pass drains the deque, encodes the
+    frames and sends each stream's bytes in one non-blocking ``send``;
+    what a client does not take waits in its stream's buffer and goes
+    out when its socket is writable, so one slow client stalls no other.
+    A failed send cancels the request once and ends the stream.
+
+    Counters: ``stream_frames_total`` (SSE frames written),
+    ``stream_writer_passes_total`` (passes that wrote one or more), and
+    ``stream_writer_cpu_seconds_total`` (the thread's CPU over its
+    passes: one clock pair a pass, never a frame)."""
+
+    def __init__(self, metrics):
+        self._items = collections.deque()
+        self._wake = threading.Event()
+        self._lock = threading.Lock()  # the thread's start and stop
+        self._thread = None
+        self._closing = False
+        self._stopped = False
+        # the writer thread's own
+        self._open = set()  # attached streams not yet ended
+        self._blocked = set()  # streams whose socket is in the selector
+        self._sel = None
+        self._frames = metrics.counter(
+            "stream_frames_total",
+            "SSE frames written to streamed /generate responses")
+        self._passes = metrics.counter(
+            "stream_writer_passes_total",
+            "stream writer passes that wrote at least one frame")
+        self._cpu = (None, metrics.counter(
+            "stream_writer_cpu_seconds_total",
+            "stream writer thread CPU seconds over its passes"))
+
+    # ------------------------------------------------ from other threads
+    def _put(self, stream, kind, arg=None) -> None:
+        self._items.append((stream, kind, arg))
+        if not self._wake.is_set():
+            self._wake.set()
+
+    def sink(self, stream):
+        """The emit sink for ``decoder.submit`` (never blocks: it runs
+        under the engine lock), and the thread started if it is not."""
+        with self._lock:
+            if self._thread is None and not self._stopped:
+                self._thread = threading.Thread(
+                    target=self._run, name="stream-writer", daemon=True)
+                self._thread.start()
+        return lambda new, done: self._put(stream, _TOKENS, (new, done))
+
+    def watch(self, stream) -> None:
+        """End the stream with an error frame if its future resolves
+        before a done frame was queued: deadline expiry, cancel,
+        shutdown (after a done frame the item is dropped)."""
+        stream.future.add_done_callback(
+            lambda _fut: self._put(stream, _END))
+
+    def attach(self, stream, sock) -> None:
+        """The headers are out: from here the writer owns the socket
+        (non-blocking), and sends what it has held for the stream."""
+        with self._lock:
+            if not self._stopped:
+                self._put(stream, _ATTACH, sock)
+                return
+        stream.first.set()
+        stream.ended.set()
+
+    def end(self, stream, error: str) -> None:
+        """Queue ``{"error": error}`` and the terminating chunk, unless
+        the stream's last frame is queued already."""
+        self._put(stream, _END, error)
+
+    def drop(self, stream) -> None:
+        """Give the stream up, its unsent bytes with it."""
+        with self._lock:
+            if not self._stopped:
+                self._put(stream, _DROP)
+                return
+        stream.ended.set()
+
+    def close(self) -> None:
+        """Finish the streams open now (their requests should be ended
+        already: ``ServingApp.close`` closes the decoder first), for at
+        most ``_GRACE_S``, then stop the thread."""
+        with self._lock:
+            self._closing = True
+            t = self._thread
+            if t is None:
+                self._stopped = True
+        self._wake.set()
+        if t is not None:
+            t.join(2 * _GRACE_S)
+
+    # -------------------------------------------------- the writer thread
+    def _run(self) -> None:
+        self._sel = selectors.DefaultSelector()
+        give_up = None
+        try:
+            while True:
+                self._wake.wait(_POLL_S if self._blocked or give_up
+                                else None)
+                self._wake.clear()
+                try:
+                    with _obs_span("stream_write", self._cpu):
+                        frames = self._pass()
+                except Exception:  # the thread every stream depends on
+                    logger.exception("stream writer pass failed")
+                    frames = 0
+                if frames:
+                    self._passes.inc()
+                    self._frames.inc(frames)
+                if self._closing:
+                    if give_up is None:
+                        give_up = time.monotonic() + _GRACE_S
+                    if not self._open or time.monotonic() > give_up:
+                        break
+        finally:
+            with self._lock:
+                self._stopped = True
+            self._pass()  # what was put before the stop
+            for s in list(self._open):
+                self._release(s)
+            self._sel.close()
+
+    def _pass(self) -> int:
+        frames = 0
+        touched = {}  # streams in the order the pass met them
+        while True:
+            try:
+                stream, kind, arg = self._items.popleft()
+            except IndexError:
+                break
+            try:
+                frames += self._take(stream, kind, arg)
+            except Exception:  # one bad item must not end every stream
+                logger.exception("stream writer failed (rid=%s)",
+                                 stream.rid)
+                self._fail(stream)
+            touched[stream] = None
+        for s in touched:
+            if s.sock is not None and s.out:
+                self._send(s)
+        if self._blocked:
+            for key, _ in self._sel.select(0):
+                self._send(key.data)
+        return frames
+
+    def _take(self, s, kind, arg) -> int:
+        """Queue one item's bytes in its stream; the frames it made."""
+        if kind == _ATTACH:
+            s.sock = arg
+            self._open.add(s)
+            return 0
+        if kind == _DROP:
+            self._release(s)
+            return 0
+        if s.closed:  # nothing follows the terminating chunk
+            return 0
+        if kind == _TOKENS:
+            toks, done = arg
+            s.out += _chunk(_sse({"tokens": toks}))
+            if s.first_at is None:
+                s.first_at = s.sent + len(s.out)
+            s.n_out += len(toks)
+            if not done:
+                return 1
+            s.out += _chunk(_sse({"done": True,
+                                  "prompt_len": s.prompt_len,
+                                  "tokens_out": s.n_out})) + _chunk(b"")
+            s.closed = s.ok = True
+            return 2
+        if arg is None:  # the future resolved with no done frame queued
+            try:
+                s.future.result(0)
+                arg = "stream ended without tokens"
+            except Exception as e:
+                arg = str(e)
+        s.out += _chunk(_sse({"error": arg})) + _chunk(b"")
+        s.closed = True
+        return 1
+
+    def _send(self, s) -> None:
+        if s.first_at is not None and not s.first_out:
+            rt = _reqtrace.get()
+            if rt is not None and s.sent == 0:
+                # the first byte is about to hit the wire: THIS is the
+                # TTFT the client feels, and what --slo judges
+                rt.note_first_byte(s.rid)
+        try:
+            n = s.sock.send(s.out)
+        except BlockingIOError:
+            n = 0
+        except OSError:
+            # the client went away mid-stream: free the slot and its KV
+            # page reservation NOW instead of decoding into a dead pipe
+            self._fail(s)
+            return
+        del s.out[:n]
+        s.sent += n
+        if (not s.first_out and s.first_at is not None
+                and s.sent >= s.first_at):
+            s.first_out = True
+            s.first.set()
+        if s.out:
+            if s not in self._blocked:
+                self._sel.register(s.sock, selectors.EVENT_WRITE, s)
+                self._blocked.add(s)
+        elif s.closed:
+            self._release(s)
+        elif s in self._blocked:
+            self._sel.unregister(s.sock)
+            self._blocked.discard(s)
+
+    def _fail(self, s) -> None:
+        if s.sock is not None or not s.closed:  # not released yet: once
+            s.decoder.cancel(s.rid)
+        s.ok = False
+        self._release(s)
+
+    def _release(self, s) -> None:
+        """The stream is over for the writer: wake its handler."""
+        if s in self._blocked:
+            self._sel.unregister(s.sock)
+            self._blocked.discard(s)
+        s.sock = None
+        s.out.clear()
+        s.closed = True
+        self._open.discard(s)
+        s.first.set()
+        s.ended.set()
 
 
 class ServingApp:
@@ -161,13 +428,14 @@ class ServingApp:
         self._m_injected = metrics.counter(
             "faults_injected_requests_total",
             "requests failed by an installed --faultPlan")
-        # what a decode round's wake-ups cost the handlers (ISSUE 39):
-        # blocked in queue.get a handler costs none, so this is the frame
-        # encoding and the socket writes, beside the decode loop's CPU
+        # the handlers' CPU of streamed requests: admission, headers and
+        # two wake-ups; the frames are the stream writer's, whose CPU its
+        # own counter takes, and the two together are what streams cost
         self._c_stream = (None, metrics.counter(
             "stream_handler_cpu_seconds_total",
             "handler-thread CPU seconds of streamed /generate requests, "
             "admission to the terminating chunk"))
+        self._writer = _StreamWriter(metrics)
         self._m_latency = {
             ep: metrics.histogram(f"latency_{ep}_ms",
                                   f"/{ep} request latency (receipt to "
@@ -339,9 +607,9 @@ class ServingApp:
                               rid: Optional[str] = None):
         """Admission for a streamed /generate (ISSUE 18): same shed /
         validation / error ladder as :meth:`dispatch_post`, but instead
-        of blocking on the future it submits with a queue-backed emit
-        sink and returns ``(200, _GenerateStream)`` for the HTTP handler
-        to drain. Every pre-stream failure returns a plain
+        of blocking on the future it submits with the stream writer's
+        emit sink and returns ``(200, _GenerateStream)`` for the HTTP
+        handler to attach. Every pre-stream failure returns a plain
         ``(status, body)`` — errors before the first byte stay ordinary
         JSON responses."""
         rt = _reqtrace.get()
@@ -369,7 +637,6 @@ class ServingApp:
                 rt.finish(rid, "bad_request", status=400, error=err)
             return 400, {"error": err}
         tokens, o = parsed
-        q: _queue_mod.Queue = _queue_mod.Queue()
         try:
             _fault_hook("request")  # no-op unless --faultPlan installed
             _, _, decoder = self._route("generate", rid)
@@ -381,13 +648,14 @@ class ServingApp:
                     rt.finish(rid, "bad_request", status=400, error=err)
                 return 400, {"error": err}
             # emit runs under the engine lock: only hand the round's
-            # tokens to the drain thread, never block
-            fut = decoder.submit(
+            # tokens to the stream writer, never block
+            stream = _GenerateStream(rid, decoder, len(tokens))
+            stream.future = decoder.submit(
                 tokens, o["max_new"], o["temperature"], o["stop"],
                 deadline=self._deadline_from(payload),
                 top_k=o["top_k"], top_p=o["top_p"], seed=o["seed"],
-                rid=rid,
-                emit=lambda new, done: q.put((list(new), done)))
+                rid=rid, emit=self._writer.sink(stream))
+            self._writer.watch(stream)
         except ValueError as e:
             self._m_errors.inc()
             if rt is not None:
@@ -424,7 +692,7 @@ class ServingApp:
                 rt.finish(rid, "error", status=500,
                           error=f"{type(e).__name__}: {e}")
             return 500, {"error": f"{type(e).__name__}: {e}"}
-        return 200, _GenerateStream(rid, q, fut, decoder, len(tokens))
+        return 200, stream
 
     def finish_generate_stream(self, rid: Optional[str], ok: bool,
                                t0: float) -> None:
@@ -577,6 +845,7 @@ class ServingApp:
             self.decoder.close()
         if self.replicas is not None:
             self.replicas.close()
+        self._writer.close()  # the streams' error frames, then the thread
         rt = _reqtrace.get()
         if rt is not None:
             rt.close()  # flush the access log
@@ -673,15 +942,6 @@ class _Handler(BaseHTTPRequestHandler):
         self._send_json(status, body, rid=rid)
 
     # ------------------------------------------------------------- streaming
-    def _write_chunk(self, data: bytes) -> None:
-        """One HTTP/1.1 chunked-transfer frame (``b""`` terminates)."""
-        self.wfile.write(b"%x\r\n" % len(data) + data + b"\r\n")
-        self.wfile.flush()
-
-    @staticmethod
-    def _sse(obj: dict) -> bytes:
-        return b"data: " + json.dumps(obj).encode() + b"\n\n"
-
     def _stream_generate(self, payload: dict, rid: str) -> None:
         """Streamed /generate (ISSUE 18): chunked-transfer SSE frames,
         one per emitting decode round (only ACCEPTED tokens under
@@ -689,7 +949,9 @@ class _Handler(BaseHTTPRequestHandler):
         the buffered response), a final ``{"done": true}`` frame, and
         client-disconnect detection — a failed write cancels the slot
         mid-decode, releasing its paged-KV pages back to the
-        allocator."""
+        allocator. This thread admits and sends the headers; the app's
+        stream writer sends the frames, and this thread
+        returns once the terminating chunk is out."""
         app = self.app
         t0 = time.perf_counter()
         with _obs_span("generate_admit", rid=rid):
@@ -698,10 +960,7 @@ class _Handler(BaseHTTPRequestHandler):
             self._send_json(status, obj, rid=rid)
             return
         stream: _GenerateStream = obj
-        rt = _reqtrace.get()
-        ok = False
-        first = True
-        n_out = 0
+        writer = app._writer
         deadline = time.monotonic() + app.request_timeout_s
         # the span that is open: the wait for the first token, from
         # submit's return; then one span from the first frame to the last
@@ -719,53 +978,23 @@ class _Handler(BaseHTTPRequestHandler):
             self.send_header("Connection", "close")
             self.end_headers()
             self.close_connection = True
-            while True:
-                try:
-                    toks, done = stream.queue.get(timeout=0.05)
-                except _queue_mod.Empty:
-                    if stream.future.done() and stream.queue.empty():
-                        # terminated without a final emit: deadline
-                        # expiry, cancel, or shutdown — surface the
-                        # error as the last frame
-                        try:
-                            stream.future.result(0)
-                            err = "stream ended without tokens"
-                        except Exception as e:
-                            err = str(e)
-                        self._write_chunk(self._sse({"error": err}))
-                        break
-                    if time.monotonic() > deadline:
-                        stream.decoder.cancel(
-                            rid, reason="server stream timeout")
-                        self._write_chunk(
-                            self._sse({"error": "stream timeout"}))
-                        break
-                    continue
-                if first:
-                    phase.close()
-                    phase.enter_context(_obs_span("generate_stream",
-                                                  rid=rid))
-                    if rt is not None:
-                        # first byte is about to hit the wire: THIS is
-                        # the TTFT the client feels, and what --slo judges
-                        rt.note_first_byte(rid)
-                self._write_chunk(self._sse({"tokens": toks}))
-                first = False
-                n_out += len(toks)
-                if done:
-                    self._write_chunk(self._sse(
-                        {"done": True, "prompt_len": stream.prompt_len,
-                         "tokens_out": n_out}))
-                    ok = True
-                    break
-            self._write_chunk(b"")  # terminating chunk
-        except (BrokenPipeError, ConnectionResetError, OSError):
-            # the client went away mid-stream: free the slot and its KV
-            # page reservation NOW instead of decoding into a dead pipe
+            self.connection.setblocking(False)
+            writer.attach(stream, self.connection)
+            if stream.first.wait(app.request_timeout_s) and stream.first_out:
+                phase.close()
+                phase.enter_context(_obs_span("generate_stream", rid=rid))
+            if not stream.ended.wait(max(0.0, deadline - time.monotonic())):
+                writer.end(stream, "stream timeout")
+                stream.decoder.cancel(rid, reason="server stream timeout")
+                if not stream.ended.wait(_GRACE_S):  # a client that reads
+                    writer.drop(stream)  # nothing keeps no thread
+                    stream.ended.wait(_GRACE_S)
+        except OSError:
+            # the client went away before the headers were out
             stream.decoder.cancel(rid)
         finally:
             phase.close()
-            app.finish_generate_stream(rid, ok, t0)
+            app.finish_generate_stream(rid, stream.ok, t0)
 
     def log_message(self, fmt, *args):  # route access logs to logging
         logger.debug("%s - %s", self.address_string(), fmt % args)

@@ -3,7 +3,8 @@ counters adds its wall seconds and its thread-CPU seconds to them, and
 one without reads no clock; the decode engine counts its rounds and the
 wall and CPU of each round, its program calls and its wait for the
 device, plain and speculative, through ``step()`` and through the loop
-thread; the HTTP front counts its stream handlers' CPU. Toy sizes, CPU."""
+thread; the HTTP front counts its stream handlers' CPU and its stream
+writer's frames, passes and CPU. Toy sizes, CPU."""
 
 import http.client
 import json
@@ -173,15 +174,15 @@ def test_engine_without_a_registry_adds_no_counter(clocks):
 
 
 # ---------------------------------------------------------------- the front
-def test_streamed_generate_counts_the_handlers_cpu():
+def stream_once(reg):
+    """One streamed /generate through the real engine and HTTP front;
+    the app, for its counters."""
     model, params = lm()
-    reg = MetricsRegistry()
     de = DecodeEngine(model, params, slots=2, max_len=64, metrics=reg)
     app = ServingApp(name="toy", metrics=reg, decoder=de)
     srv = make_server(app, "127.0.0.1", 0)
     de.start()
     threading.Thread(target=srv.serve_forever, daemon=True).start()
-    cpu = reg.counter("stream_handler_cpu_seconds_total")
     try:
         conn = http.client.HTTPConnection("127.0.0.1",
                                           srv.server_address[1], timeout=120)
@@ -190,18 +191,39 @@ def test_streamed_generate_counts_the_handlers_cpu():
         body = conn.getresponse().read().decode()
         conn.close()
         assert '"done": true' in body
+        cpu = reg.counter("stream_handler_cpu_seconds_total")
         deadline = time.monotonic() + 30  # the handler ends after the frame
         while cpu.value == 0.0 and time.monotonic() < deadline:
             time.sleep(0.01)
-        assert cpu.value > 0.0
-        text = app.metrics.render()
-        for name in ("stream_handler_cpu_seconds_total",
-                     "decode_rounds_total",
-                     *(f"decode_{p}_{k}seconds_total" for p in PAIRS
-                       for k in ("", "cpu_"))):
-            assert name in text
+        return app.metrics.render()
     finally:
         app.close()
         srv.shutdown()
         srv.server_close()
         de.close()
+
+
+def test_streamed_generate_counts_the_handlers_cpu():
+    reg = MetricsRegistry()
+    text = stream_once(reg)
+    assert reg.counter("stream_handler_cpu_seconds_total").value > 0.0
+    for name in ("stream_handler_cpu_seconds_total",
+                 "decode_rounds_total",
+                 *(f"decode_{p}_{k}seconds_total" for p in PAIRS
+                   for k in ("", "cpu_"))):
+        assert name in text
+
+
+def test_streamed_generate_counts_the_stream_writers_work():
+    """The writer's three counters move on one streamed request: five
+    tokens in one to five frames, and the done frame; each pass that
+    wrote a frame counted, and its CPU."""
+    reg = MetricsRegistry()
+    text = stream_once(reg)
+    frames = reg.counter("stream_frames_total").value
+    passes = reg.counter("stream_writer_passes_total").value
+    assert 2 <= frames <= 6 and 1 <= passes <= frames
+    assert reg.counter("stream_writer_cpu_seconds_total").value > 0.0
+    for name in ("stream_frames_total", "stream_writer_passes_total",
+                 "stream_writer_cpu_seconds_total"):
+        assert name in text
