@@ -210,6 +210,14 @@ def build_model(name: str, class_num: int = 1000, seq_len=None,
         # published widths, 8 of 52 layers (3.97B parameters: serve it
         # with --bf16)
         "smallthinker": lambda: _hybrid_moe("smallthinker", max_len=16384),
+        # the same class under its third configuration, which trains:
+        # GLM-4.7-Flash's published widths (latent attention, a leading
+        # dense layer, sigmoid top-4 of 64 experts with a shared one, the
+        # MTP module and its loss) as one chip's share of an eight-way
+        # expert-parallel job: 5 of 47 layers + MTP, 8 experts a layer,
+        # 19,360 of 154,880 vocabulary rows (706.5M parameters)
+        "glm4_moe_lite": lambda: _hybrid_moe("glm4_moe_lite",
+                                             max_len=seq_len or 4096),
     }
     if name not in table:
         raise SystemExit(f"unknown model {name}; choose from {list(table)}")
@@ -225,7 +233,8 @@ def build_model(name: str, class_num: int = 1000, seq_len=None,
             "phi4_mini_flash": (seq_len or 4096,),
             "hybrid_moe_lm": (seq_len or 512,),
             "solar_open2": (seq_len or 4096,),
-            "smallthinker": (seq_len or 16384,)}.get(name, (224, 224, 3))
+            "smallthinker": (seq_len or 16384,),
+            "glm4_moe_lite": (seq_len or 4096,)}.get(name, (224, 224, 3))
     # LM build overrides (tpulint): forced attn_impl and/or seq length
     # apply only to transformer_lm* names and only for this one call
     global _LM_OVERRIDE
@@ -757,14 +766,18 @@ def _run_timed(model_name: str, batch: int, iterations: int, data_type: str,
         model, in_shape = build_model(model_name, seq_len=seq_len,
                                       lm_attn_impl=lm_attn)
         _common.apply_fused_bn(model, fused_bn)
-        is_lm = model_name.startswith("transformer_lm")
+        is_lm = hasattr(model, "vocab")  # token ids in, not images
         if strat_name == "sp" and in_shape[0] % mesh_axes["seq"]:
             raise SystemExit(
                 f"--strategy sp: sequence length {in_shape[0]} must be "
                 f"divisible by the seq axis ({mesh_axes['seq']}); "
                 "shrink/resize with --seq")
-        crit = (nn.TimeDistributedCriterion(nn.ClassNLLCriterion())
-                if is_lm else nn.ClassNLLCriterion())
+        if not is_lm:
+            crit = nn.ClassNLLCriterion()
+        elif hasattr(model, "criterion"):  # the model's own loss (MTP)
+            crit = model.criterion()
+        else:
+            crit = nn.TimeDistributedCriterion(nn.ClassNLLCriterion())
         opt = SGD(learning_rate=0.01, momentum=0.9)
 
         rng = np.random.RandomState(0)
@@ -772,9 +785,9 @@ def _run_timed(model_name: str, batch: int, iterations: int, data_type: str,
             if dtype == jnp.bfloat16:
                 model.compute_dtype = dtype  # cast lives after the
                 # embedding
-            x_host = rng.randint(0, _LM_VOCAB,
+            x_host = rng.randint(0, model.vocab,
                                  (batch, *in_shape)).astype(np.int32)
-            y_host = rng.randint(0, _LM_VOCAB,
+            y_host = rng.randint(0, model.vocab,
                                  (batch, *in_shape)).astype(np.int32)
         elif data_type == "constant":
             x_host = np.ones((batch, *in_shape), np.float32)
@@ -818,7 +831,8 @@ def _run_timed(model_name: str, batch: int, iterations: int, data_type: str,
                                                        jnp.floating) else x
                 out, ms = model.apply(p, mod_state, xc, training=True,
                                       rng=rng)
-                return crit(out.astype(jnp.float32), y), ms
+                return crit(jax.tree_util.tree_map(
+                    lambda o: o.astype(jnp.float32), out), y), ms
 
             (loss, ms), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)

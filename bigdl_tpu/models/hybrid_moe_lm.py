@@ -23,6 +23,20 @@ whole prefix otherwise. The router reads the layer's input, before
 attention (``router_input="layer_input"``); the ``top_k`` largest logits,
 softmax over the chosen; ReGLU experts, no shared expert.
 
+``model_type: glm4_moe_lite`` (GLM-4.7-Flash), chosen by ``mixer="mla"``:
+every mixer is multi-head latent attention
+(:class:`bigdl_tpu.nn.LatentAttention`), the first ``first_k_dense``
+layers have a dense SwiGLU FFN of ``dense_width``
+(:class:`bigdl_tpu.nn.DenseGLU`) and the rest the sigmoid router's FFN
+with one shared expert and ``routed_scale``. With
+``mtp_layers=1`` a multi-token prediction module follows (DeepSeek-V3's,
+arXiv:2412.19437 section 2.2): ``h' = Block(W_eh [RMSNorm_e(Emb(t_{i+1}))
+; RMSNorm_h(h_i)])``, ``h_i`` the last layer's output, ``Block`` a whole
+MLA + routed layer, and ``Head(RMSNorm(h'))`` predicts ``t_{i+2}`` with
+the main model's embedding and head. It trains (``apply(...,
+training=True)`` and :meth:`HybridMoELM.criterion`) and does not serve:
+no latent cache is built.
+
 The model is built as one chip's share of an expert-parallel deployment:
 it holds ``experts_held`` of the ``num_experts`` routed experts of each
 layer (share ``share``) and ``vocab`` rows of embedding and head, routes
@@ -47,11 +61,12 @@ import numpy as np
 from bigdl_tpu import nn
 from bigdl_tpu.core.module import Module
 
-__all__ = ["HybridMoELM", "hybrid_moe_lm", "solar_open2", "smallthinker"]
+__all__ = ["HybridMoELM", "hybrid_moe_lm", "solar_open2", "smallthinker",
+           "glm4_moe_lite"]
 
 # mixer kind -> jax.named_scope of its ops
 _SCOPES = {"gqa": "attn_gated", "kda": "kda", "window": "attn_window",
-           "global": "attn_global"}
+           "global": "attn_global", "mla": "mla"}
 # mixer kind -> kind of the slot's K/V leaves (cache_bytes_by_kind)
 _KV_KINDS = {"gqa": "kv_full", "global": "kv_full", "window": "kv_window"}
 
@@ -64,7 +79,8 @@ class HybridMoELM(Module):
     the layers hold expert stacks, which paging, prefix sharing,
     speculation, quantization and tp serving cannot carry yet. What the
     engine refuses follows from ``recurrent_state``, ``window`` (the
-    rings' rows; None without window layers) and ``routed_experts``."""
+    rings' rows; None without window layers), ``routed_experts`` and
+    ``latent_cache`` (MLA layers: it trains and does not serve)."""
 
     routed_experts = True
     # what a decode step counts on the device, summed over layers; the
@@ -76,6 +92,15 @@ class HybridMoELM(Module):
                                 "(computed by this chip)",
         "moe_experts_touched_total": "held experts with at least one live "
                                      "token, a decode step and layer"}
+    # what a training step counts on the device, summed over its routed
+    # layers (the MTP module's included); ``apply(..., training=True)``
+    # returns them in the state under "train_counts", and the Optimizer
+    # fetches them with the loss
+    train_counters = {
+        "moe_train_held_picks_total": "token-expert pairs on experts held "
+                                      "here: the rows of the grouped matmuls",
+        "moe_train_busiest_rows_total": "the busiest held expert's rows, "
+                                        "summed over routed layers"}
 
     def __init__(self, vocab: int, d_model: int = 256, num_layers: int = 4,
                  num_heads: int = 4, num_kv_heads: int = 2,
@@ -90,7 +115,14 @@ class HybridMoELM(Module):
                  rope_layout: Optional[Sequence[int]] = None,
                  window: Optional[int] = None, rope_theta: float = 10000.0,
                  router_score: str = "sigmoid", expert_act: str = "silu",
-                 router_input: str = "ffn_norm",
+                 router_input: str = "ffn_norm", mixer: Optional[str] = None,
+                 q_lora_rank: Optional[int] = None,
+                 kv_lora_rank: Optional[int] = None,
+                 qk_nope_dim: Optional[int] = None,
+                 qk_rope_dim: Optional[int] = None,
+                 v_head_dim: Optional[int] = None, first_k_dense: int = 0,
+                 dense_width: Optional[int] = None, mtp_layers: int = 0,
+                 mtp_weight: float = 0.3,
                  attn_impl: Optional[str] = None, remat: bool = False,
                  compute_dtype=None, init_std: float = 0.02,
                  name: Optional[str] = None):
@@ -117,30 +149,48 @@ class HybridMoELM(Module):
         if layouts[0] is not None and any(layouts[0]) and not window:
             raise ValueError("sliding_window_layout names window layers: "
                              "give window")
+        if mixer not in (None, "mla") or mtp_layers not in (0, 1) or (
+                mtp_layers and mixer != "mla"):
+            raise ValueError(f"mixer {mixer!r}, mtp_layers {mtp_layers}: "
+                             "mixer None or 'mla'; one MTP module, after "
+                             "MLA layers")
+        self.num_layers, self.first_k_dense = num_layers, first_k_dense
+        self.mtp_layers, self.mtp_weight = mtp_layers, mtp_weight
         self.kinds, self.mixers, self.ffns, self.norms = [], [], [], []
-        for l in range(num_layers):
-            if layouts[0] is not None:
+        # the MTP module's block is built as layer ``num_layers``
+        for l in range(num_layers + mtp_layers):
+            if mixer == "mla":
+                kind = "mla"
+            elif layouts[0] is not None:
                 kind = "window" if layouts[0][l] else "global"
             else:
                 kind = "kda" if l % (gqa_interval + 1) else "gqa"
             self.kinds.append(kind)
-            if kind in ("window", "global"):
-                mixer = nn.CausalGQA(
+            if kind == "mla":
+                mixer_l = nn.LatentAttention(
+                    d_model, num_heads, q_lora_rank, kv_lora_rank,
+                    qk_nope_dim, qk_rope_dim, v_head_dim,
+                    rope_theta=rope_theta, eps=rms_eps, attn_impl=attn_impl,
+                    init_std=init_std)
+            elif kind in ("window", "global"):
+                mixer_l = nn.CausalGQA(
                     d_model, num_heads, num_kv_heads, head_dim,
                     attn_impl=attn_impl, init_std=init_std,
                     rope_theta=rope_theta if layouts[1][l] else None,
                     window=window if kind == "window" else None)
             elif kind == "gqa":
-                mixer = nn.GatedAttention(d_model, num_heads, num_kv_heads,
-                                          head_dim, attn_impl=attn_impl,
-                                          init_std=init_std)
+                mixer_l = nn.GatedAttention(
+                    d_model, num_heads, num_kv_heads, head_dim,
+                    attn_impl=attn_impl, init_std=init_std)
             else:
-                mixer = nn.KDA(d_model, kda_heads or num_heads,
-                               kda_head_dim or head_dim, conv=conv_kernel,
-                               gate_rank=gate_rank, eps=rms_eps,
-                               init_std=init_std)
-            self.mixers.append(mixer)
-            self.ffns.append(nn.RoutedFFN(
+                mixer_l = nn.KDA(d_model, kda_heads or num_heads,
+                                 kda_head_dim or head_dim, conv=conv_kernel,
+                                 gate_rank=gate_rank, eps=rms_eps,
+                                 init_std=init_std)
+            self.mixers.append(mixer_l)
+            self.ffns.append(nn.DenseGLU(
+                d_model, dense_width, init_std=init_std, act=expert_act)
+                if l < first_k_dense else nn.RoutedFFN(
                 d_model, expert_width, num_experts, top_k,
                 held=experts_held, share=share,
                 shared_width=shared_experts * expert_width,
@@ -149,29 +199,52 @@ class HybridMoELM(Module):
             self.norms.append((nn.RMSNorm(d_model, rms_eps),
                                nn.RMSNorm(d_model, rms_eps)))
         self.ln_f = nn.RMSNorm(d_model, rms_eps)
+        # the MTP module's own norms: of the embedding, of the hidden
+        # state, and before the shared head
+        self.mtp_norms = tuple(nn.RMSNorm(d_model, rms_eps)
+                               for _ in range(3 * mtp_layers))
         # what the engine's guard and counters read
         self.recurrent_state = "kda" in self.kinds
         self.window = window if "window" in self.kinds else None
+        # a slot of an MLA model would hold the latent cache, not built
+        self.latent_cache = "mla" in self.kinds
 
     def children(self):
         return (*self.mixers, *self.ffns,
-                *(n for pair in self.norms for n in pair), self.ln_f)
+                *(n for pair in self.norms for n in pair), self.ln_f,
+                *self.mtp_norms)
+
+    def _layer_params(self, l, rng):
+        ks = jax.random.split(jax.random.fold_in(rng, l), 2)
+        return {"ln1": self.norms[l][0].init(None),
+                "mixer": self.mixers[l].init(ks[0]),
+                "ln2": self.norms[l][1].init(None),
+                "ffn": self.ffns[l].init(ks[1])}
 
     def init(self, rng):
-        layers = {}
-        for l, (mixer, ffn) in enumerate(zip(self.mixers, self.ffns)):
-            ks = jax.random.split(jax.random.fold_in(rng, l), 2)
-            layers[str(l)] = {"ln1": self.norms[l][0].init(None),
-                              "mixer": mixer.init(ks[0]),
-                              "ln2": self.norms[l][1].init(None),
-                              "ffn": ffn.init(ks[1])}
+        layers = {str(l): self._layer_params(l, rng)
+                  for l in range(self.num_layers)}
         ks = jax.random.split(jax.random.fold_in(rng, len(layers)), 2)
         shape = (self.vocab, self.d_model)
-        return {"emb": {"weight": self.init_std * jax.random.normal(
-                    ks[0], shape)},
-                "layers": layers, "ln_f": self.ln_f.init(None),
-                "head": {"weight": self.init_std * jax.random.normal(
-                    ks[1], shape)}}
+        out = {"emb": {"weight": self.init_std * jax.random.normal(
+                   ks[0], shape)},
+               "layers": layers, "ln_f": self.ln_f.init(None),
+               "head": {"weight": self.init_std * jax.random.normal(
+                   ks[1], shape)}}
+        if self.mtp_layers:
+            k = jax.random.fold_in(rng, self.num_layers + 1)
+            d = self.d_model
+            out["mtp"] = {
+                "ln_e": self.mtp_norms[0].init(None),
+                "ln_h": self.mtp_norms[1].init(None),
+                "w_eh": self.init_std * jax.random.normal(k, (2 * d, d)),
+                "layer": self._layer_params(self.num_layers, rng),
+                "ln_f": self.mtp_norms[2].init(None)}
+        return out
+
+    def init_state(self):
+        return {"train_counts": {name: jnp.zeros((), jnp.int32)
+                                 for name in self.train_counters}}
 
     # -------------------------------------------------------------- cache
     def init_cache(self, batch: int, max_len: int, dtype=jnp.float32):
@@ -180,8 +253,8 @@ class HybridMoELM(Module):
         max_len)`` (window layers), ``{"s", "conv"}`` (KDA)."""
         return {str(l): (mixer.init_cache(batch, dtype) if kind == "kda"
                          else mixer.init_cache(batch, max_len, dtype))
-                for l, (kind, mixer) in enumerate(zip(self.kinds,
-                                                      self.mixers))}
+                for l, (kind, mixer) in enumerate(zip(
+                    self.kinds[:self.num_layers], self.mixers))}
 
     def cache_bytes_by_kind(self, cache) -> dict:
         """Resident bytes of a cache pytree by kind of leaf, the kinds
@@ -219,46 +292,117 @@ class HybridMoELM(Module):
         return h @ params["head"]["weight"].astype(h.dtype).T
 
     def _layer(self, l, p, x, cache, last, pos):
-        """Layer ``l`` on x (b, s, d): the prompt's rows up to ``last``
-        when ``pos`` is None (prefill; s = the bucket), else one token at
-        position ``pos``. Returns (x, this layer's cache, picked)."""
+        """Layer ``l`` on x (b, s, d): the whole sequence with no cache
+        when ``cache`` is None; the prompt's rows up to ``last`` when
+        ``pos`` is None (prefill; s = the bucket), else one token at
+        position ``pos``. Returns (x, this layer's cache, picked; None for
+        a dense layer)."""
         kind, mixer = self.kinds[l], self.mixers[l]
         # a router placed before attention reads the layer's raw input
         early = x if self.router_input == "layer_input" else None
         h = self.norms[l][0].forward(p["ln1"], x)
         with jax.named_scope(_SCOPES[kind]):
-            if pos is None:  # a state, or a ring, stops at ``last``
+            if cache is None:
+                h = mixer.forward(p["mixer"], h)
+            elif pos is None:  # a state, or a ring, stops at ``last``
                 h, cache = mixer.prefill(p["mixer"], h, cache, last)
             elif kind == "kda":
                 h, cache = mixer.decode_step(p["mixer"], h, cache)
             else:
                 h, cache = mixer.decode_step(p["mixer"], h, cache, pos)
         x = x + h
-        h, picked = self.ffns[l].forward(
-            p["ffn"], self.norms[l][1].forward(p["ln2"], x), early)
+        ffn, h = self.ffns[l], self.norms[l][1].forward(p["ln2"], x)
+        if l < self.first_k_dense:
+            with jax.named_scope("dense_ffn"):
+                return x + ffn.forward(p["ffn"], h), cache, None
+        h, picked = ffn.forward(p["ffn"], h, early)
         return x + h, cache, picked
 
     def _run(self, params, h, cache, last=None, pos=None):
+        """Every layer of the main model; ``cache`` None: no cache, the
+        whole sequence. Returns (h, the new cache, the routed layers'
+        bit sets stacked on axis -2)."""
         new, picked = {}, []
-        for l in range(len(self.kinds)):
+        for l in range(self.num_layers):
             fn = lambda p, x, c, l=l: self._layer(l, p, x, c, last, pos)
             if self.remat and pos is None:
                 fn = jax.checkpoint(fn)
             k = str(l)
-            h, new[k], pk = fn(params["layers"][k], h, cache[k])
-            picked.append(pk)
+            h, new[k], pk = fn(params["layers"][k], h,
+                               None if cache is None else cache[k])
+            if pk is not None:
+                picked.append(pk)
         return h, new, jnp.stack(picked, axis=-2)
 
     def logits(self, params, tokens):
         """(b, s) token ids -> (b, s, vocab) float32 logits: the whole
-        forward with no cache to keep."""
-        b, s = tokens.shape
+        forward with no cache."""
         h = self._embed(params, tokens)
-        h, _, _ = self._run(params, h, self.init_cache(b, s, h.dtype))
+        h, _, _ = self._run(params, h, None)
         return self._logits(params, h).astype(jnp.float32)
 
+    def _mtp_logits(self, params, h, tokens):
+        """The MTP module on the main model's last hidden rows ``h`` (b, s,
+        d) of ``tokens`` (b, s): (b, s, vocab) logits, row ``i`` for
+        the token after next, read from ``h_i`` and the embedding of
+        ``tokens[:, i + 1]`` (the last row reads ``tokens[:, 0]``: causal,
+        it moves no other row, and it is no row's target), and the block's
+        bit sets (b, s, words)."""
+        p, (ln_e, ln_h, ln_f) = params["mtp"], self.mtp_norms
+        e = ln_e.forward(p["ln_e"], self._embed(
+            params, jnp.roll(tokens, -1, axis=1)))
+        x = jnp.concatenate([e, ln_h.forward(p["ln_h"], h)], axis=-1)
+        x = x @ p["w_eh"].astype(x.dtype)
+        fn = lambda q, x: self._layer(self.num_layers, q, x, None, None,
+                                      None)
+        if self.remat:
+            fn = jax.checkpoint(fn)
+        x, _, picked = fn(p["layer"], x)
+        x = ln_f.forward(p["ln_f"], x)
+        return x @ params["head"]["weight"].astype(x.dtype).T, picked
+
+    def train_logits(self, params, tokens):
+        """The training forward of ``tokens`` (b, s): (next-token logits
+        (b, s, vocab), the MTP module's (b, s, vocab) or None, the routed
+        layers' bit sets (b, s, routed layers, words), the MTP block's
+        last)."""
+        h = self._embed(params, tokens)
+        h, _, picked = self._run(params, h, None)
+        if not self.mtp_layers:
+            return self._logits(params, h), None, picked
+        with jax.named_scope("mtp"):
+            z, pk = self._mtp_logits(params, h, tokens)
+        return (self._logits(params, h), z,
+                jnp.concatenate([picked, pk[..., None, :]], axis=-2))
+
     def apply(self, params, state, x, *, training=False, rng=None):
-        return jax.nn.log_softmax(self.logits(params, x), axis=-1), state
+        """``training=False``: (b, s, vocab) log-probs. ``training=True``:
+        the same, or with the MTP module ``{"main": ..., "mtp": ...}``
+        (:meth:`criterion` takes it), and the state's ``train_counts`` of
+        this step."""
+        if not training:
+            return jax.nn.log_softmax(self.logits(params, x), axis=-1), state
+        logp = lambda z: jax.nn.log_softmax(z.astype(jnp.float32), axis=-1)
+        main, mtp, picked = self.train_logits(params, x)
+        out = logp(main) if mtp is None else {"main": logp(main),
+                                              "mtp": logp(mtp)}
+        return out, {"train_counts": self._train_counts(picked)}
+
+    def criterion(self):
+        """The training loss of :meth:`apply`'s output: next-token NLL,
+        plus ``mtp_weight`` times the MTP module's."""
+        if self.mtp_layers:
+            return nn.MultiTokenNLLCriterion(self.mtp_weight)
+        return nn.TimeDistributedCriterion(nn.ClassNLLCriterion())
+
+    def _train_counts(self, picked):
+        """``train_counters`` of one training step, from the routed
+        layers' bit sets ``picked`` (b, s, routed layers, words)."""
+        e = jnp.arange(self.experts_held)
+        bits = (picked[..., e // 32] >> (e % 32).astype(jnp.uint32)) & 1
+        rows = jnp.sum(bits, axis=(0, 1), dtype=jnp.int32)  # (layers, held)
+        return {"moe_train_held_picks_total": jnp.sum(rows),
+                "moe_train_busiest_rows_total": jnp.sum(jnp.max(rows, -1))}
 
     # --------------------------------------------- autoregressive decoding
     def prefill_logits(self, params, tokens, cache, last=None):
@@ -338,3 +482,23 @@ def smallthinker(vocab: int = 151936, num_layers: int = 8,
                        shared_experts=0, router_score="softmax_topk",
                        expert_act="relu", router_input="layer_input",
                        rms_eps=1e-6, max_len=max_len, **kw)
+
+
+def glm4_moe_lite(vocab: int = 19360, num_layers: int = 5,
+                  experts_held: int = 8, share: int = 0,
+                  **kw) -> HybridMoELM:
+    """GLM-4.7-Flash at its published widths, as one chip's share of an
+    eight-way expert-parallel training job: MLA at 20 heads (q rank 768,
+    kv rank 512, 192 + 64 wide keys, 256 wide values), the dense layer 0
+    (SwiGLU 10,240), then routed layers of ``experts_held`` of the 64
+    experts (top-4 by sigmoid scores with a selection bias, weights times
+    1.8, one shared expert, all 1,536 wide), ``vocab`` of the 154,880
+    rows, ``num_layers`` of the 47 layers and the MTP module."""
+    return HybridMoELM(vocab, d_model=2048, num_layers=num_layers,
+                       num_heads=20, mixer="mla", q_lora_rank=768,
+                       kv_lora_rank=512, qk_nope_dim=192, qk_rope_dim=64,
+                       v_head_dim=256, first_k_dense=1, dense_width=10240,
+                       num_experts=64, experts_held=experts_held,
+                       share=share, top_k=4, expert_width=1536,
+                       shared_experts=1, routed_scale=1.8, rope_theta=1e6,
+                       rms_eps=1e-5, mtp_layers=1, **kw)

@@ -42,7 +42,7 @@ __all__ = [
     "grouped_cache_attention",
     "MultiHeadAttention",
     "CausalGQA",
-    "GatedAttention",
+    "GatedAttention", "LatentAttention",
     "PositionalEncoding",
     "TransformerEncoderLayer",
     "TransformerEncoder",
@@ -592,6 +592,103 @@ class GatedAttention(CausalGQA):
         super().__init__(d_model, num_heads, num_kv_heads, head_dim,
                          attn_impl=attn_impl, init_std=init_std, gate=True,
                          name=name)
+
+
+class LatentAttention(SimpleModule):
+    """Multi-head latent attention (DeepSeek-V2's MLA, as GLM-4.7-Flash's
+    ``glm4_moe_lite`` layers have it), the whole sequence at once, for
+    training:
+
+    * ``c_q = RMSNorm(x W_qa)`` (``q_lora_rank``), ``q_h = c_q W_qb[h] =
+      [q_nope | q_rope]``;
+    * ``[c_kv | k_rope] = x W_kva``, ``c_kv = RMSNorm(c_kv)``
+      (``kv_lora_rank``); ``k_rope`` (``rope_dim``) is one rotated key
+      that every head shares; ``[k_nope_h | v_h] = c_kv W_kvb[h]``;
+    * head ``h`` attends causally with ``q = [q_nope | rope(q_rope)]``
+      and ``k = [k_nope_h | rope(k_rope)]`` at the scale of their width,
+      ``(nope_dim + rope_dim)^-1/2``; ``out = concat_h(o_h) W_o``.
+
+    Rotation at absolute positions, theta ``rope_theta``, half-split
+    pairing of the ``rope_dim`` channels, angles and rotation in float32.
+    The norm scales are ``ln_q`` and ``ln_kv``. ``attn_impl="flash"``
+    runs the Pallas kernels (forward, dq, dk/dv) on the (b, heads, s,
+    width) rows; it needs ``v_dim == nope_dim + rope_dim``. There is no
+    serving cache: a latent cache is not built."""
+
+    def __init__(self, d_model: int, num_heads: int, q_lora_rank: int,
+                 kv_lora_rank: int, nope_dim: int, rope_dim: int,
+                 v_dim: int, rope_theta: float = 10000.0,
+                 eps: float = 1e-5, attn_impl: Optional[AttnFn | str] = None,
+                 init_std: float = 0.02, name: Optional[str] = None):
+        super().__init__(name)
+        if rope_dim % 2:
+            raise ValueError(f"rope_dim {rope_dim}: the pairs need an even "
+                             "width")
+        self.d_model, self.num_heads = d_model, num_heads
+        self.q_lora_rank, self.kv_lora_rank = q_lora_rank, kv_lora_rank
+        self.nope_dim, self.rope_dim, self.v_dim = nope_dim, rope_dim, v_dim
+        self.rope_theta, self.init_std = rope_theta, init_std
+        self.ln_q, self.ln_kv = RMSNorm(q_lora_rank, eps), RMSNorm(
+            kv_lora_rank, eps)
+        if attn_impl == "flash":
+            from bigdl_tpu.ops import flash_attention
+            attn_impl = flash_attention
+        self.attn_fn: AttnFn = attn_impl or dot_product_attention
+
+    def init(self, rng):
+        ks = jax.random.split(rng, 5)
+        d, h = self.d_model, self.num_heads
+        qk = self.nope_dim + self.rope_dim
+        mk = lambda k, shape: self.init_std * jax.random.normal(k, shape)
+        return {"wq_a": mk(ks[0], (d, self.q_lora_rank)),
+                "ln_q": self.ln_q.init(None),
+                "wq_b": mk(ks[1], (self.q_lora_rank, h * qk)),
+                "wkv_a": mk(ks[2], (d, self.kv_lora_rank + self.rope_dim)),
+                "ln_kv": self.ln_kv.init(None),
+                "wkv_b": mk(ks[3], (self.kv_lora_rank,
+                                    h * (self.nope_dim + self.v_dim))),
+                "wo": mk(ks[4], (h * self.v_dim, d))}
+
+    def _rotate(self, x, pos):
+        """x (..., s, rope_dim) rotated at absolute positions ``pos``."""
+        half = self.rope_dim // 2
+        inv = 1.0 / (self.rope_theta ** (
+            jnp.arange(0, self.rope_dim, 2, dtype=jnp.float32)
+            / self.rope_dim))
+        ang = pos.astype(jnp.float32)[:, None] * inv[None, :]
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        x1 = x[..., :half].astype(jnp.float32)
+        x2 = x[..., half:].astype(jnp.float32)
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                               axis=-1).astype(x.dtype)
+
+    def qkv(self, params, x):
+        """q, k (b, heads, s, nope + rope) and v (b, heads, s, v_dim) of
+        x (b, s, d), rotated at positions 0..s-1."""
+        dt, (b, s, _) = x.dtype, x.shape
+        h, nope = self.num_heads, self.nope_dim
+        w = lambda n: params[n].astype(dt)
+        c_q = self.ln_q.forward(params["ln_q"], x @ w("wq_a"))
+        q = (c_q @ w("wq_b")).reshape(b, s, h, -1).transpose(0, 2, 1, 3)
+        kv = x @ w("wkv_a")
+        c_kv = self.ln_kv.forward(params["ln_kv"], kv[..., :self.kv_lora_rank])
+        pos = jnp.arange(s)
+        k_rope = self._rotate(kv[:, None, :, self.kv_lora_rank:], pos)
+        kv = (c_kv @ w("wkv_b")).reshape(b, s, h, -1).transpose(0, 2, 1, 3)
+        q = jnp.concatenate([q[..., :nope], self._rotate(q[..., nope:], pos)],
+                            axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_rope, (b, h, s,
+                                                       self.rope_dim))],
+            axis=-1)
+        return q, k, kv[..., nope:]
+
+    def _forward(self, params, x, *, training, rng):
+        b, s, _ = x.shape
+        q, k, v = self.qkv(params, x)
+        a = self.attn_fn(q, k, v, causal=True, mask=None)
+        a = a.transpose(0, 2, 1, 3).reshape(b, s, -1)
+        return a.astype(x.dtype) @ params["wo"].astype(x.dtype)
 
 
 class PositionalEncoding(SimpleModule):

@@ -24,7 +24,7 @@ __all__ = [
     "MultiCriterion", "ParallelCriterion", "MultiLabelMarginCriterion",
     "MultiLabelSoftMarginCriterion", "MultiMarginCriterion",
     "SmoothL1Criterion", "SoftMarginCriterion", "L1Cost", "L1Penalty",
-    "TimeDistributedCriterion",
+    "TimeDistributedCriterion", "MultiTokenNLLCriterion",
 ]
 
 
@@ -389,3 +389,27 @@ class L1Penalty(Criterion):
     def forward(self, input, target=None):
         del target
         return self.l1weight * jnp.sum(jnp.abs(input))
+
+
+class MultiTokenNLLCriterion(Criterion):
+    """The loss of a language model with one multi-token prediction
+    module (DeepSeek-V3's MTP, arXiv:2412.19437 section 2.2): ``CE_main +
+    weight * CE_mtp``. Input ``{"main": (B, T, C), "mtp": (B, T, C)}``
+    log-probs; target (B, T), the next token of every position. The main
+    term is over every position; the MTP module's position ``t`` predicts
+    the token after next, ``target[:, t + 1]``, so its term is over
+    positions 0..T-2 (its last row is ignored). Each term is a mean over
+    its positions."""
+
+    def __init__(self, weight: float):
+        super().__init__()
+        self.weight = weight
+
+    @staticmethod
+    def _nll(logp, target):
+        t = target.astype(jnp.int32)[..., None]
+        return -jnp.mean(jnp.take_along_axis(logp, t, axis=-1))
+
+    def forward(self, input, target):
+        return (self._nll(input["main"], target) + self.weight
+                * self._nll(input["mtp"][:, :-1], target[:, 1:]))

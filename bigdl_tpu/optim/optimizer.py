@@ -372,8 +372,9 @@ class Optimizer:
             def loss_fn(p):
                 out, new_ms = model.apply(p, mod_state, x,
                                           training=True, rng=rng)
-                if dtype is not None:
-                    out = out.astype(jnp.float32)  # fp32 loss/softmax
+                if dtype is not None:  # fp32 loss/softmax
+                    out = jax.tree_util.tree_map(
+                        lambda o: o.astype(jnp.float32), out)
                 loss = criterion(out, y)
                 if aux_w:
                     loss = loss + aux_w * sum_aux_losses(new_ms)
@@ -563,10 +564,20 @@ class Optimizer:
         _end = object()  # end-of-epoch sentinel (None could be a real batch)
         last_log_t = time.time()
         fetch_accum = 0.0
+        # a model that declares training counters returns this step's in
+        # its state ("train_counts"); they ride with the loss in one fetch
+        # and are added to the shared registry
+        declared = getattr(self.model, "train_counters", None)
+        if declared:
+            from bigdl_tpu.obs.metrics import get_registry
+            counters = {name: get_registry().counter(name, help)
+                        for name, help in declared.items()}
 
-        def after_dispatch(n_rec, n_iters, t0, loss):
+        def after_dispatch(n_rec, n_iters, t0, loss, state):
             """Advance counters and emit the log point after one dispatch
-            (one step, or a steps_per_dispatch chunk of n_iters steps)."""
+            (one step, or a steps_per_dispatch chunk of n_iters steps);
+            at a log point the model's declared counters count the step
+            that ended there."""
             nonlocal last_log_t, fetch_accum, records_this_epoch
             prev_it = driver["iteration"]
             driver["prev_iteration"] = prev_it
@@ -581,6 +592,11 @@ class Optimizer:
             if driver["iteration"] // self.log_every != prev_it // self.log_every:
                 t_w = time.perf_counter()
                 with _span("loss_fetch"):
+                    if declared:
+                        loss, counts = jax.device_get(
+                            (loss, state["train_counts"]))
+                        for name, n in counts.items():
+                            counters[name].inc(int(n))
                     loss_f = float(loss)
                 self._obs_phase("device", time.perf_counter() - t_w)
                 driver["loss"] = loss_f
@@ -703,7 +719,7 @@ class Optimizer:
                             raise
                         self._obs_phase("dispatch", time.perf_counter() - t_d)
                         after_dispatch(sum(len(bx) for bx, _ in buf), K, t0,
-                                       loss)
+                                       loss, mod_state)
                         self._maybe_validate(eval_fn, params, mod_state,
                                              driver)
                         self._maybe_checkpoint(params, mod_state, opt_state,
@@ -746,7 +762,7 @@ class Optimizer:
                             _obs_mem.handle_oom(e, "train_dispatch")
                             raise
                         self._obs_phase("dispatch", time.perf_counter() - t_d)
-                        after_dispatch(len(x), 1, t0, loss)
+                        after_dispatch(len(x), 1, t0, loss, mod_state)
                         self._maybe_validate(eval_fn, params, mod_state,
                                              driver)
                         self._maybe_checkpoint(params, mod_state, opt_state,

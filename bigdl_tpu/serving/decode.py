@@ -150,7 +150,14 @@ def _dense_path_only(model, **asked) -> Optional[str]:
     (a slot holds rings of ``window`` K/V rows), ``routed_experts`` (a
     stack no 8-bit form or tp layout knows). ``asked``: the engine's
     options that are on. The message, or None where nothing asked for is
-    refused."""
+    refused. A model that declares ``latent_cache`` (attention through a
+    low-rank latent) is refused whatever is asked: its slots would hold
+    the latent cache, which is not built."""
+    if getattr(model, "latent_cache", False):
+        return (f"{type(model).__name__} attends through a low-rank latent "
+                "(MLA) and trains only: a slot would hold the latent cache "
+                "(c_kv and the shared rotated key a layer), which is not "
+                "built; not supported yet: serving")
     state = getattr(model, "recurrent_state", False)
     ring = bool(getattr(model, "window", None)) and not state
     experts = getattr(model, "routed_experts", False)

@@ -1,7 +1,8 @@
 """The decode step's kernels compiled for a described v5e at the
 benchmark's real shapes (``cache_write_rows``, ``cache_attend_rows``, and
-the routed experts' grouped matmuls), and the windowed flash forward
-kernel of the long prefills: what interpret mode cannot refuse
+the routed experts' grouped matmuls), the windowed flash forward kernel
+of the long prefills, and the training step's flash kernels and routed
+gradient at latent attention's widths: what interpret mode cannot refuse
 (tiling, fast memory, the alias). No chip is needed and nothing runs;
 where the topology cannot be described here the tests skip. The topology
 is described inside a
@@ -128,6 +129,49 @@ def test_the_reglu_experts_compile_for_v5e(one_chip, monkeypatch, tokens):
     # prompt's length
     limit = {32: 64 << 20, 4096: 640 << 20}[tokens]
     assert compiled.memory_analysis().temp_size_in_bytes < limit
+
+
+def test_the_routed_experts_train_for_v5e(one_chip, monkeypatch):
+    """The routed op's gradient at GLM-4.7-Flash's widths and a training
+    step's 2 x 4,096 tokens (top-4, 8 held experts): the forward's two
+    grouped matmuls and the backward's ``gmm`` (dx) and ``tgmm`` (dW) of
+    each, in one block of 32,768 picks."""
+    from bigdl_tpu.nn import moe
+    from bigdl_tpu.ops import attention_kernel
+    monkeypatch.setattr(attention_kernel, "_interpret", lambda: False)
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    loss = lambda x, wts, w13, w2, local: moe.routed_experts(
+        x, local, wts, w13, w2).sum()
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        sds((8192, 2048), jnp.bfloat16), sds((8192, 4), jnp.float32),
+        sds((8, 2048, 3072), jnp.bfloat16), sds((8, 1536, 2048),
+                                                jnp.bfloat16),
+        sds((8192, 4), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 6
+    assert "tgmm" in text
+    # one block: 1.41 GB of temporaries; through the serving prefill's
+    # row-block map of 4,096 tokens the same gradient holds 2.47 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.8e9
+
+
+def test_the_flash_kernels_train_at_latent_heads_for_v5e(one_chip,
+                                                         monkeypatch):
+    """``flash_fwd``, ``flash_dq`` and ``flash_dkv`` at GLM-4.7-Flash's
+    latent attention, 20 heads of 256 over 2 x 4,096 tokens, with the
+    512-blocks ``_resolve_blocks`` picks past heads of 128."""
+    from bigdl_tpu.ops import attention_kernel as ak
+    monkeypatch.setattr(ak, "_interpret", lambda: False)
+    qkv = [jax.ShapeDtypeStruct((2, 20, 4096, 256), jnp.bfloat16,
+                                sharding=one_chip)] * 3
+    assert ak.flash_block_plan(4096, 4096, 256, True, jnp.bfloat16)[
+        "block_k"] == 512
+    fn = jax.grad(lambda q, k, v: ak.flash_attention(
+        q, k, v, causal=True).astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(*qkv).compile().as_text()
+    assert all(name in text for name in ("flash_fwd", "flash_dq",
+                                         "flash_dkv"))
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
 
 
 @pytest.mark.parametrize("s", [8192, 16384])
