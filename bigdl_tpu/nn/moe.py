@@ -190,17 +190,25 @@ def _routed_experts(x, local, wts, w13, w2, act="silu",
     expert (absent ones last, in a group that is never computed), and one
     grouped matmul a projection (``megablox.gmm``, rows of a group against
     that expert's matrix) walks the tiles of the groups that have rows, so
-    an expert nobody chose is never read. A ``T`` past ``row_block``
-    (None: no limit) is walked in blocks of ``row_block`` tokens."""
+    an expert nobody chose is never read. The rows go to the sorted order
+    and back through two Pallas kernels (``ops/moe_rows.py``), each the
+    other's transpose, which never move an absent pick's row. A ``T``
+    past ``row_block`` (None: no limit) is walked in blocks of
+    ``row_block`` tokens."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
+    from bigdl_tpu.ops import moe_rows
     from bigdl_tpu.ops.attention_kernel import _interpret
     (T, d), k, held = x.shape, local.shape[1], w13.shape[0]
     if row_block and T > row_block and T % row_block == 0:
         blocks = lambda t: t.reshape((T // row_block, row_block)
                                      + t.shape[1:])
+        # the barrier keeps a block's sums apart from the loop's stacked
+        # output: fused into it, they ask for more fast memory than v5e
+        # has (SmallThinker's 8,192- and 16,384-token prefills)
         out = jax.lax.map(
-            lambda b: _routed_experts(*b, w13, w2, act, row_block),
+            lambda b: jax.lax.optimization_barrier(
+                _routed_experts(*b, w13, w2, act, row_block)),
             (blocks(x), blocks(local), blocks(wts)))
         return out.reshape(T, d)
     width = w2.shape[1]
@@ -210,7 +218,8 @@ def _routed_experts(x, local, wts, w13, w2, act="silu",
     key = jnp.pad(local.reshape(m), (0, m_pad - m), constant_values=held)
     order = jnp.argsort(key, stable=True)
     sizes = jnp.bincount(key, length=held + 1).astype(jnp.int32)
-    xs = jnp.take(x, jnp.minimum(order // k, T - 1), axis=0)
+    pos, n = jnp.argsort(order)[:m].reshape(T, k), jnp.sum(sizes[:held])
+    xs = moe_rows.dispatch_rows(x, order, pos, n, _interpret())
     call = functools.partial(gmm, group_sizes=sizes,
                              preferred_element_type=jnp.float32,
                              interpret=_interpret())
@@ -220,9 +229,7 @@ def _routed_experts(x, local, wts, w13, w2, act="silu",
     act = (_ACTS[act](gu[:, :width]) * gu[:, width:]).astype(x.dtype)
     y = call(act, w2.astype(x.dtype), tiling=(
         tm, min(width, 1280), _tile(d, (1024, 512, 256, 128))))
-    y = jnp.take(y, jnp.argsort(order)[:m], axis=0).reshape(T, k, d)
-    return jnp.sum(jnp.where((local < held)[..., None],
-                             y * wts[..., None], 0.0), axis=1)
+    return moe_rows.combine_rows(y, order, pos, wts, n, _interpret())
 
 
 @functools.lru_cache(maxsize=None)

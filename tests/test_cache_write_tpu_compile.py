@@ -1,6 +1,6 @@
 """The decode step's kernels compiled for a described v5e at the
 benchmark's real shapes (``cache_write_rows``, ``cache_attend_rows``, and
-the routed experts' grouped matmuls), the windowed flash forward kernel
+the routed experts' grouped matmuls and row kernels), the windowed flash forward kernel
 of the long prefills, and the training step's flash kernels and routed
 gradient at latent attention's widths: what interpret mode cannot refuse
 (tiling, fast memory, the alias). No chip is needed and nothing runs;
@@ -90,7 +90,8 @@ def test_the_bounded_read_compiles_for_v5e(one_chip, monkeypatch, shape, r,
 def test_the_routed_experts_compile_for_v5e(one_chip, monkeypatch, tokens):
     """The routed layer's grouped matmuls (``nn/moe.py``, jax's
     ``megablox.gmm``) at Solar-Open2-250B's widths and this chip's 40
-    held experts: two Mosaic calls, and no copy of an expert stack."""
+    held experts: two Mosaic calls, one a row kernel each way
+    (``ops/moe_rows.py``), and no copy of an expert stack."""
     from bigdl_tpu.nn import moe
     from bigdl_tpu.ops import attention_kernel
     monkeypatch.setattr(attention_kernel, "_interpret", lambda: False)
@@ -101,17 +102,21 @@ def test_the_routed_experts_compile_for_v5e(one_chip, monkeypatch, tokens):
         sds((40, 4096, 2560), jnp.bfloat16),
         sds((40, 1280, 4096), jnp.bfloat16)).compile()
     assert compiled.as_text().count(
-        'custom_call_target="tpu_custom_call"') == 2
+        'custom_call_target="tpu_custom_call"') == 4
     stacks = 40 * 3 * 4096 * 1280 * 2
     assert compiled.memory_analysis().temp_size_in_bytes < stacks // 10
 
 
-@pytest.mark.parametrize("tokens", [32, 4096], ids=["decode_32_slots",
-                                                    "prefill_row_block"])
+@pytest.mark.parametrize("tokens", [32, 4096, 8192],
+                         ids=["decode_32_slots", "prefill_row_block",
+                              "prefill_two_row_blocks"])
 def test_the_reglu_experts_compile_for_v5e(one_chip, monkeypatch, tokens):
     """The same grouped matmuls at SmallThinker's widths (64 experts of
     width 768 over a stream of 2,560, six a token, ReGLU): the tiles
-    chosen for 2,560 and 1,536 columns fit."""
+    chosen for 2,560 and 1,536 columns fit, beside the two row kernels,
+    inside the row-block loop of a longer prompt too (where XLA, left to
+    fuse the sums into the loop's stacked output, runs out of fast
+    memory)."""
     from bigdl_tpu.nn import moe
     from bigdl_tpu.ops import attention_kernel
     monkeypatch.setattr(attention_kernel, "_interpret", lambda: False)
@@ -123,19 +128,21 @@ def test_the_reglu_experts_compile_for_v5e(one_chip, monkeypatch, tokens):
         sds((64, 2560, 1536), jnp.bfloat16),
         sds((64, 768, 2560), jnp.bfloat16)).compile()
     assert compiled.as_text().count(
-        'custom_call_target="tpu_custom_call"') == 2
+        'custom_call_target="tpu_custom_call"') == 4
     # no copy of an expert stack (755 MB); a row block of 4,096 tokens
     # has 24,576 rows of float32 temporaries (588 MB read), whatever the
     # prompt's length
-    limit = {32: 64 << 20, 4096: 640 << 20}[tokens]
+    limit = {32: 64 << 20, 4096: 640 << 20, 8192: 640 << 20}[tokens]
     assert compiled.memory_analysis().temp_size_in_bytes < limit
 
 
 def test_the_routed_experts_train_for_v5e(one_chip, monkeypatch):
     """The routed op's gradient at GLM-4.7-Flash's widths and a training
     step's 2 x 4,096 tokens (top-4, 8 held experts): the forward's two
-    grouped matmuls and the backward's ``gmm`` (dx) and ``tgmm`` (dW) of
-    each, in one block of 32,768 picks."""
+    grouped matmuls and its dispatch of the rows, the backward's ``gmm``
+    (dx) and ``tgmm`` (dW) of each and the two row kernels' transposes,
+    in one block of 32,768 picks (the sum's gradient never reads the
+    forward's combine)."""
     from bigdl_tpu.nn import moe
     from bigdl_tpu.ops import attention_kernel
     monkeypatch.setattr(attention_kernel, "_interpret", lambda: False)
@@ -148,11 +155,47 @@ def test_the_routed_experts_train_for_v5e(one_chip, monkeypatch):
                                                 jnp.bfloat16),
         sds((8192, 4), jnp.int32)).compile()
     text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 6
+    assert text.count('custom_call_target="tpu_custom_call"') == 9
     assert "tgmm" in text
+    assert "moe_dispatch_rows" in text and "moe_combine_rows" in text
     # one block: 1.41 GB of temporaries; through the serving prefill's
     # row-block map of 4,096 tokens the same gradient holds 2.47 GB
     assert compiled.memory_analysis().temp_size_in_bytes < 1.8e9
+
+
+@pytest.mark.parametrize("T,k,d", [(8192, 4, 2048), (4096, 6, 2560),
+                                   (32, 6, 2560), (64, 8, 4096)],
+                         ids=["glm_train_32768", "smallthinker_prefill_24576",
+                              "smallthinker_decode_192", "solar_decode_512"])
+def test_the_row_kernels_compile_for_v5e(one_chip, T, k, d):
+    """``moe_dispatch_rows`` and ``moe_combine_rows`` at the cells' rows
+    (``T * k`` picks of ``d``), forward and each one's transpose: four
+    Mosaic calls (more where the token rows outgrow what is held in
+    fast memory, one a chunk) that fit the chip, and no temporaries of their own beyond the
+    source rows' float32 copy and the picks' small vectors."""
+    from bigdl_tpu.ops import moe_rows as mr
+    m = T * k
+    m_pad = -(-m // 128) * 128 if m >= 128 else -(-m // 16) * 16
+    sds = lambda s, dt=jnp.float32: jax.ShapeDtypeStruct(
+        s, dt, sharding=one_chip)
+
+    def both(x, y, wts, gx, g, order, pos, n):
+        xs, to_x = jax.vjp(lambda x: mr.dispatch_rows(x, order, pos, n,
+                                                      False), x)
+        out, to_y = jax.vjp(lambda y, w: mr.combine_rows(y, order, pos, w,
+                                                         n, False), y, wts)
+        return xs, out, to_x(gx), to_y(g)
+
+    compiled = jax.jit(both).lower(
+        sds((T, d), jnp.bfloat16), sds((m_pad, d)), sds((T, k)),
+        sds((m_pad, d), jnp.bfloat16), sds((T, d)), sds((m_pad,), jnp.int32),
+        sds((T, k), jnp.int32), sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    chunks = -(-T * d * 4 // mr._RESIDENT_BYTES)
+    assert text.count('custom_call_target="tpu_custom_call"') == 4 * chunks
+    assert "moe_dispatch_rows" in text and "moe_combine_rows" in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= (
+        2 * T * d * 4 + m_pad * 16 + (1 << 20))
 
 
 def test_the_flash_kernels_train_at_latent_heads_for_v5e(one_chip,

@@ -227,6 +227,10 @@ def test_the_serving_step_is_still_one_grouped_product_for_all_slots():
                                  jnp.zeros((slots, 1, k)))
     calls = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name ==
              "pallas_call"]
+    rows = [str(e.params["name"]) for e in calls
+            if str(e.params["name"]).startswith("moe_")]
+    assert rows == ["moe_dispatch_rows", "moe_combine_rows"]
+    calls = [e for e in calls if not str(e.params["name"]).startswith("moe_")]
     assert len(calls) == 2
     # slots x k = 32 picks: one call takes the rows of all of them
     assert (slots * k, d) in [v.aval.shape for v in calls[0].invars]

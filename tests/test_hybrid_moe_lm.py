@@ -177,7 +177,8 @@ def test_no_token_is_dropped_when_all_pick_the_same_experts():
 def test_batching_rule_makes_one_grouped_product_of_the_slots():
     """Under ``vmap`` (the engine's step) the slots' tokens are one token
     axis: the same numbers as the flat call, and one ``pallas_call`` a
-    projection, not one a slot."""
+    projection and one a row kernel (``ops/moe_rows.py``), not one a
+    slot."""
     layer, p = ffn()
     x = jax.random.normal(jax.random.PRNGKey(1), (6, 1, 1, 64))
     step = jax.vmap(lambda row: layer.forward(p, row))
@@ -186,7 +187,9 @@ def test_batching_rule_makes_one_grouped_product_of_the_slots():
     np.testing.assert_allclose(out.reshape(6, 64), flat, atol=1e-6)
     assert (picked.reshape(6, 1) == flat_picked).all()
     text = str(jax.make_jaxpr(step)(x))
-    assert text.count("pallas_call") == 2
+    assert text.count("pallas_call") == 4
+    assert text.count("name=moe_dispatch_rows") == 1
+    assert text.count("name=moe_combine_rows") == 1
 
 
 def test_the_shares_add_up_to_the_uncut_layer_of_the_reference():
