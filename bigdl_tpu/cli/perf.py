@@ -218,6 +218,16 @@ def build_model(name: str, class_num: int = 1000, seq_len=None,
         # 19,360 of 154,880 vocabulary rows (706.5M parameters)
         "glm4_moe_lite": lambda: _hybrid_moe("glm4_moe_lite",
                                              max_len=seq_len or 4096),
+        # the same class under its fourth configuration: parallel blocks
+        # under one bias-free LayerNorm, three GPT-J-rotated window layers
+        # before each NoPE global layer at 128 query heads, a bias-free
+        # sigmoid router with four averaged shared experts, a tied head.
+        # Command A+'s published widths as one chip's share of an
+        # eight-way expert-parallel deployment: 4 of 32 layers, 16 of 128
+        # experts a layer, 32,768 of 262,144 vocabulary rows (4.73B
+        # parameters: serve it with --bf16)
+        "command_a_plus": lambda: _hybrid_moe("command_a_plus",
+                                              max_len=seq_len or 9216),
     }
     if name not in table:
         raise SystemExit(f"unknown model {name}; choose from {list(table)}")
@@ -234,7 +244,8 @@ def build_model(name: str, class_num: int = 1000, seq_len=None,
             "hybrid_moe_lm": (seq_len or 512,),
             "solar_open2": (seq_len or 4096,),
             "smallthinker": (seq_len or 16384,),
-            "glm4_moe_lite": (seq_len or 4096,)}.get(name, (224, 224, 3))
+            "glm4_moe_lite": (seq_len or 4096,),
+            "command_a_plus": (seq_len or 9216,)}.get(name, (224, 224, 3))
     # LM build overrides (tpulint): forced attn_impl and/or seq length
     # apply only to transformer_lm* names and only for this one call
     global _LM_OVERRIDE

@@ -14,6 +14,8 @@ compiles, and (for LMs) continuous-batching KV-cache decode:
         --buckets 1 --seq 256
     bigdl-tpu serve solar_open2 --randomInit --bf16 --slots 64 \
         --buckets 1 --seq 256
+    bigdl-tpu serve command_a_plus --randomInit --bf16 --slots 32 \
+        --buckets 1 --seq 256
     curl -d '{"tokens": [3, 1, 4], "max_new_tokens": 8}' \
         localhost:8000/generate
 

@@ -20,5 +20,6 @@ from bigdl_tpu.models.transformer_lm import (
 )
 from bigdl_tpu.models.sambay_lm import SambaYLM, sambay_lm
 from bigdl_tpu.models.hybrid_moe_lm import (
-    HybridMoELM, glm4_moe_lite, hybrid_moe_lm, smallthinker, solar_open2,
+    HybridMoELM, command_a_plus, glm4_moe_lite, hybrid_moe_lm, smallthinker,
+    solar_open2,
 )

@@ -1,9 +1,13 @@
 """Language models of mixed mixers with a routed feed-forward layer in every
-block, one class under two configurations.
+block, one class under four configurations.
 
 Every layer is ``h += Mixer_l(RMSNorm1_l(h)); h += FFN_l(RMSNorm2_l(h))``,
 the FFN routed (:class:`bigdl_tpu.nn.RoutedFFN`), no bias but a router's
-selection bias, the output head untied.
+selection bias, the output head untied, unless three per-layer keys say
+otherwise: ``block="parallel"`` (one norm a layer, ``h += Mixer_l(n) +
+FFN_l(n)`` with ``n = Norm_l(h)``), ``norm="layer"`` (every norm a
+mean-subtracted LayerNorm with a weight and no bias) and
+``tie_embeddings`` (the head is the embedding's rows).
 
 ``model_type: solar_open2`` (Solar-Open2-250B; the mixers are Kimi
 Linear's, arXiv:2510.26692, the router ``glm4_moe``'s): layer ``l`` is a
@@ -22,6 +26,14 @@ giving the two layout lists: every mixer is plain causal GQA
 whole prefix otherwise. The router reads the layer's input, before
 attention (``router_input="layer_input"``); the ``top_k`` largest logits,
 softmax over the chosen; ReGLU experts, no shared expert.
+
+``model_type: cohere2_moe`` (Command A+), chosen by the two layout lists
+with the three keys above: three window layers whose q and k turn in
+GPT-J's interleaved pairs (``rope_pairing="interleaved"``), then a global
+layer without positions; 128 query heads over 8 KV heads; the router
+``sigmoid`` without a selection bias (``selection_bias=False``) and four
+shared experts averaged (``shared_combine="mean"``): ``h += Attn(n) +
+sum_chosen w_e GLU_e(n) + 1/4 sum_j GLU^s_j(n)``.
 
 ``model_type: glm4_moe_lite`` (GLM-4.7-Flash), chosen by ``mixer="mla"``:
 every mixer is multi-head latent attention
@@ -62,7 +74,7 @@ from bigdl_tpu import nn
 from bigdl_tpu.core.module import Module
 
 __all__ = ["HybridMoELM", "hybrid_moe_lm", "solar_open2", "smallthinker",
-           "glm4_moe_lite"]
+           "glm4_moe_lite", "command_a_plus"]
 
 # mixer kind -> jax.named_scope of its ops
 _SCOPES = {"gqa": "attn_gated", "kda": "kda", "window": "attn_window",
@@ -122,7 +134,10 @@ class HybridMoELM(Module):
                  qk_rope_dim: Optional[int] = None,
                  v_head_dim: Optional[int] = None, first_k_dense: int = 0,
                  dense_width: Optional[int] = None, mtp_layers: int = 0,
-                 mtp_weight: float = 0.3,
+                 mtp_weight: float = 0.3, block: str = "sequential",
+                 norm: str = "rms", tie_embeddings: bool = False,
+                 rope_pairing: str = "half", selection_bias: bool = True,
+                 shared_combine: str = "sum",
                  attn_impl: Optional[str] = None, remat: bool = False,
                  compute_dtype=None, init_std: float = 0.02,
                  name: Optional[str] = None):
@@ -154,8 +169,19 @@ class HybridMoELM(Module):
             raise ValueError(f"mixer {mixer!r}, mtp_layers {mtp_layers}: "
                              "mixer None or 'mla'; one MTP module, after "
                              "MLA layers")
+        if (block not in ("sequential", "parallel")
+                or norm not in ("rms", "layer")
+                or shared_combine not in ("sum", "mean")):
+            raise ValueError(f"block {block!r}, norm {norm!r}, "
+                             f"shared_combine {shared_combine!r}: sequential "
+                             "or parallel, rms or layer, sum or mean")
         self.num_layers, self.first_k_dense = num_layers, first_k_dense
         self.mtp_layers, self.mtp_weight = mtp_layers, mtp_weight
+        self.parallel = block == "parallel"
+        self.tie_embeddings = tie_embeddings
+        # every norm of the model: rms_eps is its epsilon whatever its kind
+        make_norm = (lambda: nn.RMSNorm(d_model, rms_eps)) if norm == "rms" \
+            else (lambda: nn.LayerNorm(d_model, rms_eps, bias=False))
         self.kinds, self.mixers, self.ffns, self.norms = [], [], [], []
         # the MTP module's block is built as layer ``num_layers``
         for l in range(num_layers + mtp_layers):
@@ -177,7 +203,8 @@ class HybridMoELM(Module):
                     d_model, num_heads, num_kv_heads, head_dim,
                     attn_impl=attn_impl, init_std=init_std,
                     rope_theta=rope_theta if layouts[1][l] else None,
-                    window=window if kind == "window" else None)
+                    window=window if kind == "window" else None,
+                    rope_pairing=rope_pairing)
             elif kind == "gqa":
                 mixer_l = nn.GatedAttention(
                     d_model, num_heads, num_kv_heads, head_dim,
@@ -195,14 +222,15 @@ class HybridMoELM(Module):
                 held=experts_held, share=share,
                 shared_width=shared_experts * expert_width,
                 scale=routed_scale, init_std=init_std, score=router_score,
-                act=expert_act))
-            self.norms.append((nn.RMSNorm(d_model, rms_eps),
-                               nn.RMSNorm(d_model, rms_eps)))
-        self.ln_f = nn.RMSNorm(d_model, rms_eps)
+                act=expert_act, selection_bias=selection_bias,
+                shared_scale=1.0 / shared_experts
+                if shared_combine == "mean" and shared_experts else 1.0))
+            self.norms.append(tuple(
+                make_norm() for _ in range(1 if self.parallel else 2)))
+        self.ln_f = make_norm()
         # the MTP module's own norms: of the embedding, of the hidden
         # state, and before the shared head
-        self.mtp_norms = tuple(nn.RMSNorm(d_model, rms_eps)
-                               for _ in range(3 * mtp_layers))
+        self.mtp_norms = tuple(make_norm() for _ in range(3 * mtp_layers))
         # what the engine's guard and counters read
         self.recurrent_state = "kda" in self.kinds
         self.window = window if "window" in self.kinds else None
@@ -216,10 +244,12 @@ class HybridMoELM(Module):
 
     def _layer_params(self, l, rng):
         ks = jax.random.split(jax.random.fold_in(rng, l), 2)
-        return {"ln1": self.norms[l][0].init(None),
-                "mixer": self.mixers[l].init(ks[0]),
-                "ln2": self.norms[l][1].init(None),
-                "ffn": self.ffns[l].init(ks[1])}
+        out = {"ln1": self.norms[l][0].init(None),
+               "mixer": self.mixers[l].init(ks[0])}
+        if not self.parallel:
+            out["ln2"] = self.norms[l][1].init(None)
+        out["ffn"] = self.ffns[l].init(ks[1])
+        return out
 
     def init(self, rng):
         layers = {str(l): self._layer_params(l, rng)
@@ -228,9 +258,10 @@ class HybridMoELM(Module):
         shape = (self.vocab, self.d_model)
         out = {"emb": {"weight": self.init_std * jax.random.normal(
                    ks[0], shape)},
-               "layers": layers, "ln_f": self.ln_f.init(None),
-               "head": {"weight": self.init_std * jax.random.normal(
-                   ks[1], shape)}}
+               "layers": layers, "ln_f": self.ln_f.init(None)}
+        if not self.tie_embeddings:
+            out["head"] = {"weight": self.init_std * jax.random.normal(
+                ks[1], shape)}
         if self.mtp_layers:
             k = jax.random.fold_in(rng, self.num_layers + 1)
             d = self.d_model
@@ -287,9 +318,13 @@ class HybridMoELM(Module):
         return h if self.compute_dtype is None else h.astype(
             self.compute_dtype)
 
+    def _head(self, params):
+        """The output head's (vocab, d) rows: the embedding's when tied."""
+        return params["emb" if self.tie_embeddings else "head"]["weight"]
+
     def _logits(self, params, h):
         h = self.ln_f.forward(params["ln_f"], h)
-        return h @ params["head"]["weight"].astype(h.dtype).T
+        return h @ self._head(params).astype(h.dtype).T
 
     def _layer(self, l, p, x, cache, last, pos):
         """Layer ``l`` on x (b, s, d): the whole sequence with no cache
@@ -300,22 +335,24 @@ class HybridMoELM(Module):
         kind, mixer = self.kinds[l], self.mixers[l]
         # a router placed before attention reads the layer's raw input
         early = x if self.router_input == "layer_input" else None
-        h = self.norms[l][0].forward(p["ln1"], x)
+        n = self.norms[l][0].forward(p["ln1"], x)
         with jax.named_scope(_SCOPES[kind]):
             if cache is None:
-                h = mixer.forward(p["mixer"], h)
+                h = mixer.forward(p["mixer"], n)
             elif pos is None:  # a state, or a ring, stops at ``last``
-                h, cache = mixer.prefill(p["mixer"], h, cache, last)
+                h, cache = mixer.prefill(p["mixer"], n, cache, last)
             elif kind == "kda":
-                h, cache = mixer.decode_step(p["mixer"], h, cache)
+                h, cache = mixer.decode_step(p["mixer"], n, cache)
             else:
-                h, cache = mixer.decode_step(p["mixer"], h, cache, pos)
+                h, cache = mixer.decode_step(p["mixer"], n, cache, pos)
         x = x + h
-        ffn, h = self.ffns[l], self.norms[l][1].forward(p["ln2"], x)
+        if not self.parallel:  # a parallel block's FFN reads attention's n
+            n = self.norms[l][1].forward(p["ln2"], x)
+        ffn = self.ffns[l]
         if l < self.first_k_dense:
             with jax.named_scope("dense_ffn"):
-                return x + ffn.forward(p["ffn"], h), cache, None
-        h, picked = ffn.forward(p["ffn"], h, early)
+                return x + ffn.forward(p["ffn"], n), cache, None
+        h, picked = ffn.forward(p["ffn"], n, early)
         return x + h, cache, picked
 
     def _run(self, params, h, cache, last=None, pos=None):
@@ -359,7 +396,7 @@ class HybridMoELM(Module):
             fn = jax.checkpoint(fn)
         x, _, picked = fn(p["layer"], x)
         x = ln_f.forward(p["ln_f"], x)
-        return x @ params["head"]["weight"].astype(x.dtype).T, picked
+        return x @ self._head(params).astype(x.dtype).T, picked
 
     def train_logits(self, params, tokens):
         """The training forward of ``tokens`` (b, s): (next-token logits
@@ -502,3 +539,29 @@ def glm4_moe_lite(vocab: int = 19360, num_layers: int = 5,
                        share=share, top_k=4, expert_width=1536,
                        shared_experts=1, routed_scale=1.8, rope_theta=1e6,
                        rms_eps=1e-5, mtp_layers=1, **kw)
+
+
+def command_a_plus(vocab: int = 32768, num_layers: int = 4,
+                   experts_held: int = 16, share: int = 0,
+                   max_len: int = 9216, **kw) -> HybridMoELM:
+    """Command A+ (``cohere2_moe``) at its published widths, as one chip's
+    share of an eight-way expert-parallel deployment: parallel blocks
+    under one bias-free LayerNorm; 128 query heads over 8 KV heads of
+    128, three window layers of 4,096 positions rotated in GPT-J pairs
+    (theta 50,000) before each global layer without positions;
+    ``experts_held`` of the 128 routed experts a layer (top-8 by sigmoid
+    scores, no selection bias, all 4,096 wide) beside four shared experts
+    averaged; ``vocab`` of the 262,144 rows, tied; ``num_layers`` of the
+    32 layers."""
+    period = [1, 1, 1, 0]  # local_attn_first
+    layout = (period * -(-num_layers // 4))[:num_layers]
+    return HybridMoELM(vocab, d_model=4096, num_layers=num_layers,
+                       num_heads=128, num_kv_heads=8, head_dim=128,
+                       sliding_window_layout=layout, rope_layout=layout,
+                       window=4096, rope_theta=5e4,
+                       rope_pairing="interleaved", num_experts=128,
+                       experts_held=experts_held, share=share, top_k=8,
+                       expert_width=4096, shared_experts=4,
+                       shared_combine="mean", selection_bias=False,
+                       block="parallel", norm="layer", tie_embeddings=True,
+                       rms_eps=1e-5, max_len=max_len, **kw)

@@ -119,25 +119,31 @@ class LayerNorm(SimpleModule):
 
     Not in the reference (its normalizations are batch/spatial —
     nn/BatchNormalization.scala); required substrate for transformers.
-    Statistics in fp32, output cast back to the input dtype.
+    Statistics in fp32, output cast back to the input dtype. ``bias=False``:
+    a weight and no bias leaf (Cohere's LayerNorm).
     """
 
-    def __init__(self, dim: int, eps: float = 1e-5,
+    def __init__(self, dim: int, eps: float = 1e-5, bias: bool = True,
                  name: Optional[str] = None):
         super().__init__(name)
         self.dim = dim
         self.eps = eps
+        self.bias = bias
 
     def init(self, rng):
         del rng
-        return {"weight": jnp.ones((self.dim,)),
-                "bias": jnp.zeros((self.dim,))}
+        out = {"weight": jnp.ones((self.dim,))}
+        if self.bias:
+            out["bias"] = jnp.zeros((self.dim,))
+        return out
 
     def _forward(self, params, x, *, training, rng):
         xf = x.astype(jnp.float32)
         mean = jnp.mean(xf, axis=-1, keepdims=True)
         var = jnp.var(xf, axis=-1, keepdims=True)
         y = (xf - mean) * jax.lax.rsqrt(var + self.eps)
+        if not self.bias:
+            return (y * params["weight"]).astype(x.dtype)
         y = y * params["weight"] + params["bias"]
         return y.astype(x.dtype)
 
@@ -431,9 +437,11 @@ class CausalGQA(SimpleModule):
       sqrt(head_dim)) v * sigmoid(x Wg)) Wo``, the gate on the
       concatenated heads;
     * ``rope_theta``: q and k rotated at their absolute positions
-      (half-split pairing; angles and rotation in float32), None for no
-      positional encoding. K is cached **rotated**, so the order of rows
-      in a cache is free;
+      (angles and rotation in float32), None for no positional encoding;
+      ``rope_pairing`` ``"half"`` turns dims ``(i, i + head_dim / 2)``
+      together (NeoX), ``"interleaved"`` dims ``(2i, 2i + 1)`` (GPT-J), pair
+      ``i`` at ``theta^(-2i / head_dim)`` either way. K is cached
+      **rotated**, so the order of rows in a cache is free;
     * ``window``: query ``i`` sees keys ``j`` with ``i - window < j <=
       i``, and a slot holds a ring of ``min(window, max_len)`` rows,
       position ``p`` at row ``p % ring``, of which the first ``min(p + 1,
@@ -449,15 +457,20 @@ class CausalGQA(SimpleModule):
                  head_dim: int, attn_impl: Optional[AttnFn | str] = None,
                  init_std: float = 0.02, gate: bool = False,
                  rope_theta: Optional[float] = None,
-                 window: Optional[int] = None, name: Optional[str] = None):
+                 window: Optional[int] = None, rope_pairing: str = "half",
+                 name: Optional[str] = None):
         super().__init__(name)
         if num_heads % num_kv_heads:
             raise ValueError(f"num_heads {num_heads} not divisible by "
                              f"num_kv_heads {num_kv_heads}")
+        if rope_pairing not in ("half", "interleaved"):
+            raise ValueError(f"rope_pairing {rope_pairing!r}: half or "
+                             "interleaved")
         self.d_model, self.head_dim = d_model, head_dim
         self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
         self.init_std = init_std
         self.gate, self.rope_theta, self.window = gate, rope_theta, window
+        self.rope_pairing = rope_pairing
         if attn_impl == "flash":
             from bigdl_tpu.ops import flash_attention
             attn_impl = flash_attention
@@ -493,6 +506,11 @@ class CausalGQA(SimpleModule):
             / self.head_dim))
         ang = pos.astype(jnp.float32)[:, None] * inv[None, :]
         cos, sin = jnp.cos(ang), jnp.sin(ang)
+        if self.rope_pairing == "interleaved":
+            pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], half, 2)
+            x1, x2 = pairs[..., 0], pairs[..., 1]
+            return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                             axis=-1).reshape(x.shape).astype(x.dtype)
         x1 = x[..., :half].astype(jnp.float32)
         x2 = x[..., half:].astype(jnp.float32)
         return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],

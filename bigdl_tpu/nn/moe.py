@@ -285,9 +285,13 @@ class RoutedFFN(SimpleModule):
     ``score="softmax_topk"`` (SmallThinker's): the ``top_k`` largest of
     the logits ``r Wr`` themselves, no selection bias leaf, weights the
     softmax over the chosen logits (the softmax over all, renormalised
-    over the chosen). ``out = sum_chosen w_e GLU_e(x) + Shared(x)``, the
+    over the chosen). ``out = sum_chosen w_e GLU_e(x) + c Shared(x)``, the
     gate's activation ``act`` (``silu``: SwiGLU; ``relu``: ReGLU), the
-    shared expert a GLU of ``shared_width`` (0: none).
+    shared expert a GLU of ``shared_width`` (0: none). Several shared
+    experts are one GLU as wide as all of them, which is their sum; ``c`` =
+    ``shared_scale`` (1; ``1 / n`` averages n of them). Under ``sigmoid``,
+    ``selection_bias=False`` leaves the bias out: the top ``top_k`` of ``s``
+    itself.
 
     ``r``, what the router reads, is ``x`` unless ``forward`` is given a
     ``router_x`` of its own (a router placed before attention reads the
@@ -307,7 +311,8 @@ class RoutedFFN(SimpleModule):
                  top_k: int, held: Optional[int] = None, share: int = 0,
                  shared_width: int = 0, scale: float = 1.0,
                  init_std: float = 0.02, score: str = "sigmoid",
-                 act: str = "silu", name: Optional[str] = None):
+                 act: str = "silu", selection_bias: bool = True,
+                 shared_scale: float = 1.0, name: Optional[str] = None):
         super().__init__(name)
         held = num_experts if held is None else held
         if not 0 < held <= num_experts or num_experts % held:
@@ -324,6 +329,8 @@ class RoutedFFN(SimpleModule):
         self.top_k, self.held, self.share = top_k, held, share
         self.shared_width, self.scale = shared_width, scale
         self.init_std, self.score, self.act = init_std, score, act
+        self.selection_bias = selection_bias and score == "sigmoid"
+        self.shared_scale = shared_scale
         self.words = -(-held // 32)
 
     def init(self, rng):
@@ -333,7 +340,7 @@ class RoutedFFN(SimpleModule):
         out = {"router": {"weight": mk(ks[0], (d, self.num_experts))},
                "w13": mk(ks[1], (self.held, d, 2 * w)),
                "w2": mk(ks[2], (self.held, w, d))}
-        if self.score == "sigmoid":
+        if self.selection_bias:
             out["router"]["bias"] = jnp.zeros((self.num_experts,))
         if sw:
             out["shared_w13"] = mk(ks[3], (d, 2 * sw))
@@ -391,8 +398,11 @@ class RoutedFFN(SimpleModule):
                                  self.act)
         if self.shared_width:
             with jax.named_scope("moe_shared"):
-                out = out + glu(x, params["shared_w13"], params["shared_w2"],
-                                self.act)
+                shared = glu(x, params["shared_w13"], params["shared_w2"],
+                             self.act)
+                if self.shared_scale != 1.0:
+                    shared = self.shared_scale * shared
+                out = out + shared
         return (out.astype(dt).reshape(*lead, self.d_model),
                 picked.reshape(*lead, self.words))
 

@@ -70,7 +70,7 @@ def test_latent_attention_mixer_is_the_references(glm):
     p = params["layers"]["1"]["mixer"]
     x = jax.random.normal(jax.random.PRNGKey(3), (2, S, 64))
     with jax.default_matmul_precision("highest"):
-        got = model.mixers[1].forward(p, x)
+        got = jax.jit(model.mixers[1].forward)(p, x)
     mla_dims = ref._dims(ARGS)[0]
     for b in range(2):
         want = ref._mla(p, x[b], mla_dims)
@@ -84,7 +84,8 @@ def test_latent_attention_mixer_is_the_references(glm):
 def test_both_heads_are_the_references(glm):
     model, params, toks, _ = glm
     with jax.default_matmul_precision("highest"):
-        main, mtp, picked = model.train_logits(params, jnp.asarray(toks))
+        main, mtp, picked = jax.jit(model.train_logits)(params,
+                                                        jnp.asarray(toks))
     want_main, want_mtp = ref.heads(params, ARGS, toks[0])
     assert rel(main[0], want_main) < 1e-5
     # MTP row i predicts token i + 2; the last row is no one's
@@ -107,7 +108,7 @@ def test_loss_and_every_leafs_gradient_are_the_references(glm):
         return ref._nll(main, t) + ARGS["mtp_weight"] * ref._nll(mtp, t[1:])
 
     with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(ours)(params)
+        loss, grads = jax.jit(jax.value_and_grad(ours))(params)
     want_loss, want = jax.value_and_grad(theirs)(params)
     assert float(loss) == pytest.approx(
         ref.mean_nll(params, ARGS, toks[0], targets[0]), rel=1e-6)
@@ -143,9 +144,9 @@ def test_the_references_block_by_block_gradient_is_jax_grad_of_its_loss(
     assert loss == pytest.approx(float(want_loss), rel=1e-6)
     crit, state = model.criterion(), model.init_state()
     with jax.default_matmul_precision("highest"):
-        ours = jax.grad(lambda p: crit(model.apply(
+        ours = jax.jit(jax.grad(lambda p: crit(model.apply(
             p, state, jnp.asarray(toks), training=True)[0],
-            jnp.asarray(targets)))(params)
+            jnp.asarray(targets))))(params)
     flat = jax.tree_util.tree_leaves_with_path(got)
     for (path, g), w, o in zip(flat, jax.tree_util.tree_leaves(want),
                                jax.tree_util.tree_leaves(ours)):

@@ -100,12 +100,13 @@ def test_kda_mixer_prefill_is_the_reference(length):
     kda = nn.KDA(64, 4, 16, gate_rank=16, init_std=0.125)
     p = kda.init(jax.random.PRNGKey(1))
     x = jax.random.normal(jax.random.PRNGKey(2), (1, length, 64))
-    out, cache = kda.prefill(p, x, kda.init_cache(1))
+    prefill = jax.jit(kda.prefill)
+    out, cache = prefill(p, x, kda.init_cache(1))
     want = ref._kda(p, x[0], 4, 1e-5)
     np.testing.assert_allclose(out[0], want, atol=1e-4)
     # a padded bucket: the state and the history stop at `last`
     pad = jnp.concatenate([x, jnp.ones((1, 23, 64))], axis=1)
-    out_p, cache_p = kda.prefill(p, pad, kda.init_cache(1), length - 1)
+    out_p, cache_p = prefill(p, pad, kda.init_cache(1), length - 1)
     np.testing.assert_allclose(out_p[0, :length], want, atol=1e-4)
     np.testing.assert_allclose(cache_p["s"], cache["s"], atol=1e-5)
     np.testing.assert_allclose(cache_p["conv"], cache["conv"], atol=1e-6)
@@ -116,9 +117,10 @@ def test_kda_decode_steps_continue_the_prefill():
     p = kda.init(jax.random.PRNGKey(1))
     x = jax.random.normal(jax.random.PRNGKey(2), (1, 70, 64))
     want = ref._kda(p, x[0], 4, 1e-5)
-    _, cache = kda.prefill(p, x[:, :66], kda.init_cache(1))
+    _, cache = jax.jit(kda.prefill)(p, x[:, :66], kda.init_cache(1))
+    step = jax.jit(kda.decode_step)
     for i in range(66, 70):
-        out, cache = kda.decode_step(p, x[:, i:i + 1], cache)
+        out, cache = step(p, x[:, i:i + 1], cache)
         np.testing.assert_allclose(out[0, 0], want[i], atol=1e-4)
 
 
@@ -169,7 +171,7 @@ def test_no_token_is_dropped_when_all_pick_the_same_experts():
     idx, w = layer.route(p, x)
     assert sorted(np.unique(idx).tolist()) == [4, 5, 6, 7]
     assert float(w.max()) < 1.0  # the bias chooses, the scores weigh
-    out, picked = layer.forward(p, x)
+    out, picked = jax.jit(layer.forward)(p, x)
     np.testing.assert_allclose(out, per_token_loop(layer, p, x), atol=1e-4)
     assert set(picked[:, 0].tolist()) == {0b1111}
 
@@ -182,8 +184,8 @@ def test_batching_rule_makes_one_grouped_product_of_the_slots():
     layer, p = ffn()
     x = jax.random.normal(jax.random.PRNGKey(1), (6, 1, 1, 64))
     step = jax.vmap(lambda row: layer.forward(p, row))
-    out, picked = step(x)
-    flat, flat_picked = layer.forward(p, x.reshape(6, 64))
+    out, picked = jax.jit(step)(x)
+    flat, flat_picked = jax.jit(layer.forward)(p, x.reshape(6, 64))
     np.testing.assert_allclose(out.reshape(6, 64), flat, atol=1e-6)
     assert (picked.reshape(6, 1) == flat_picked).all()
     text = str(jax.make_jaxpr(step)(x))
@@ -209,14 +211,20 @@ def test_the_shares_add_up_to_the_uncut_layer_of_the_reference():
         ref._expert(p["w13"][e], p["w2"][e], h,
                     jnp.sum(jnp.where(chosen == e, w, 0.0), axis=-1))
         for e in range(16))
-    parts = []
-    for share in range(8):
-        layer, _ = ffn(held=2, share=share)
-        mine = dict(p, w13=p["w13"][2 * share:2 * share + 2],
-                    w2=p["w2"][2 * share:2 * share + 2])
-        parts.append(layer.forward(mine, h)[0])
-    np.testing.assert_allclose(sum(parts) - 7 * shared, uncut, atol=1e-4)
-    np.testing.assert_allclose(whole.forward(p, h)[0], uncut, atol=1e-4)
+    @jax.jit
+    def parts(p, h):
+        out = []
+        for share in range(8):
+            layer, _ = ffn(held=2, share=share)
+            mine = dict(p, w13=p["w13"][2 * share:2 * share + 2],
+                        w2=p["w2"][2 * share:2 * share + 2])
+            out.append(layer.forward(mine, h)[0])
+        return out
+
+    np.testing.assert_allclose(sum(parts(p, h)) - 7 * shared, uncut,
+                               atol=1e-4)
+    np.testing.assert_allclose(jax.jit(whole.forward)(p, h)[0], uncut,
+                               atol=1e-4)
 
 
 def test_flips_between_bf16_and_f32_inputs_are_few():
@@ -239,7 +247,7 @@ def test_full_forward_is_the_reference(lm):
     model, p = lm
     toks = tokens(45)
     want = np.asarray(ref.logits(p, ARGS, toks))
-    got = np.asarray(model.logits(p, jnp.asarray([toks])))[0]
+    got = np.asarray(jax.jit(model.logits)(p, jnp.asarray([toks])))[0]
     assert np.abs(got - want).max() / np.abs(want).max() < 1e-4
     assert model.kinds == ["gqa", "kda", "kda", "kda"] * 2
 

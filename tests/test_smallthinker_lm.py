@@ -69,7 +69,7 @@ def test_full_forward_is_the_reference(lm):
     model, p, tol = lm
     toks = tokens(50)
     want = np.asarray(ref.logits(p, ARGS, toks))
-    got = model.logits(p, jnp.asarray([toks]))[0]
+    got = jax.jit(model.logits)(p, jnp.asarray([toks]))[0]
     assert rel_err(got, want) < tol
     assert model.kinds == ["global", "window", "window", "window"] * 2
     assert model.window == W and not model.recurrent_state
@@ -263,7 +263,7 @@ def test_experts_are_reglu_and_the_router_reads_what_it_is_given():
     layer, p = router()
     x = jax.random.normal(jax.random.PRNGKey(1), (30, 64))
     early = jax.random.normal(jax.random.PRNGKey(2), (30, 64))
-    out, picked = layer.forward(p, x, early)
+    out, picked = jax.jit(layer.forward)(p, x, early)
     idx, w = layer.route(p, early)
     want = np.zeros((30, 64), np.float32)
     for t in range(30):
@@ -276,7 +276,7 @@ def test_experts_are_reglu_and_the_router_reads_what_it_is_given():
     bits = [sum(1 << int(e) for e in row) for row in np.asarray(idx)]
     assert [int(lo) | int(hi) << 32 for lo, hi in picked.tolist()] == bits
     # without router_x it reads x: another choice
-    assert not (np.asarray(layer.forward(p, x)[1]) == np.asarray(
+    assert not (np.asarray(jax.jit(layer.forward)(p, x)[1]) == np.asarray(
         picked)).all()
 
 
@@ -288,8 +288,8 @@ def test_the_models_router_reads_the_layers_input():
     late = models.HybridMoELM(**dict(ARGS, router_input="ffn_norm"))
     p = early.init(jax.random.PRNGKey(3))
     want = np.asarray(ref.logits(p, ARGS, toks[0]))
-    assert rel_err(early.logits(p, toks)[0], want) < 1e-4
-    assert rel_err(late.logits(p, toks)[0], want) > 0.05
+    assert rel_err(jax.jit(early.logits)(p, toks)[0], want) < 1e-4
+    assert rel_err(jax.jit(late.logits)(p, toks)[0], want) > 0.05
 
 
 def test_long_prompts_walk_the_experts_in_row_blocks(monkeypatch):
